@@ -51,7 +51,7 @@ def _timeit(f, *args, reps=5, inner=3):
 
 
 def bench_tp(cpus, mp=4, t=256, k=1024, out=1024):
-    from paddle_tpu._compat import shard_map
+    from jax import shard_map
     from paddle_tpu.parallel import collective_matmul as cm
 
     mesh = Mesh(np.array(cpus[:mp]), ("mp",))
@@ -178,7 +178,7 @@ def bench_stage3_prefetch(cpus, dp=2, sh=4, width=256, depth=6, batch=64,
 
 
 def bench_pp(cpus, S=2, M=8, H=256):
-    from paddle_tpu._compat import shard_map
+    from jax import shard_map
     from paddle_tpu.parallel.pipeline import (last_stage_value, microbatch,
                                               pipeline_apply,
                                               stack_stage_params)

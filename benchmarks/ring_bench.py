@@ -71,7 +71,7 @@ def chunk_sweep(cpus=None, mp=4, t=512, k=512, out=512, chunks=(1, 2, 4),
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from paddle_tpu import observability as obs
-    from paddle_tpu._compat import shard_map
+    from jax import shard_map
     from paddle_tpu.parallel import collective_matmul as cm
 
     if cpus is None:

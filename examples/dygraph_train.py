@@ -10,6 +10,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import paddle_tpu as paddle
     import paddle_tpu.nn as nn
 

@@ -2,8 +2,9 @@
 fleet.init with a hybrid strategy -> fleet.distributed_model ->
 fleet.distributed_optimizer -> compiled train step over the hybrid mesh.
 
-Runs on virtual CPU devices so it works anywhere:
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 python examples/fleet_hybrid_tp.py
+Runs on 8 virtual CPU devices so it works anywhere (the mesh takes the
+default backend's devices, so the backend is pinned to the CPU here):
+  python examples/fleet_hybrid_tp.py
 """
 import os
 import sys
@@ -11,11 +12,14 @@ import sys
 import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import paddle_tpu as paddle
     import paddle_tpu.nn as nn
     import paddle_tpu.nn.functional as F

@@ -15,6 +15,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import paddle_tpu as paddle
     from paddle_tpu.distributed import rpc
     from paddle_tpu.distributed.ps import PSClient

@@ -34,12 +34,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from paddle_tpu.inference import FleetRouter, ServeConfig
     from paddle_tpu.inference import InferenceEngine, Request
     from paddle_tpu.models.llama import init_llama_params, llama_tiny
-    from paddle_tpu.ops import _common
-
-    _common.set_interpret(True)  # noqa: PTA007 -- process-lifetime: script entry point, paged pallas kernels off-TPU
 
     config = llama_tiny(vocab=96, hidden=64, layers=1, heads=4, kv_heads=2,
                         seq=512)

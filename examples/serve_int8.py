@@ -10,6 +10,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from paddle_tpu.models.llama import (greedy_generate, init_llama_params,
                                          llama_tiny, quantize_llama_int8)
     config = llama_tiny(vocab=512, hidden=64, layers=4, heads=4, kv_heads=4,

@@ -1,7 +1,8 @@
 """Train the flagship Llama on synthetic data — the compiled SPMD step.
 
 Single chip:      python examples/train_llama.py
-Virtual 8-chip:   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+Virtual 8-chip:   JAX_PLATFORMS=cpu \
+                  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
                   python examples/train_llama.py --dp 2 --mp 2 --pp 2
 """
 import argparse
@@ -16,6 +17,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dp", type=int, default=1)
     ap.add_argument("--mp", type=int, default=1)
@@ -28,14 +31,9 @@ def main():
                                          llama_tiny, make_mesh)
     parallel = ParallelConfig(dp=args.dp, mp=args.mp, pp=args.pp,
                               microbatches=2 if args.pp > 1 else 1)
-    if parallel.total > 1:
-        from paddle_tpu.ops import _common
-        _common.set_interpret(True)  # noqa: PTA007 -- process-lifetime: script entry point on virtual CPU devices
-        cpus = jax.devices("cpu")
-        jax.config.update("jax_default_device", cpus[0])  # noqa: PTA007 -- process-lifetime device pin for the script run
-        mesh = make_mesh(parallel, devices=cpus[:parallel.total])
-    else:
-        mesh = None
+    # the mesh takes the default backend's devices: the chips on a TPU
+    # host, the virtual CPU devices under JAX_PLATFORMS=cpu
+    mesh = make_mesh(parallel) if parallel.total > 1 else None
     config = llama_tiny(vocab=512, hidden=64, layers=4, heads=4, kv_heads=4,
                         inter=128, seq=64)
     step, params, opt = build_train_step(config, parallel, mesh=mesh,

@@ -26,8 +26,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import axis_size as _axis_size
 
-from ..._compat import axis_size as _axis_size
 from ...observability import trace as _obs
 
 

@@ -32,20 +32,18 @@ _AXIS_ORDER = ["dp", "pp", "sharding", "sep", "mp"]
 
 
 def _pick_devices(n: int):
-    """Choose n devices: accelerators if enough, else host CPU devices."""
+    """The first n devices of the default backend, and of no other: the
+    chip's on a TPU host, the virtual CPU devices under JAX_PLATFORMS=cpu.
+    Too few of them is an error, never a mesh built on the host's CPU
+    beside an accelerator."""
     devs = jax.devices()
-    accel = [d for d in devs if d.platform != "cpu"]
-    if len(accel) >= n:
-        return accel[:n]
-    cpus = jax.devices("cpu")
-    if len(cpus) >= n:
-        return cpus[:n]
-    if n == 1:
-        return devs[:1]
-    raise ValueError(
-        f"need {n} devices for the hybrid topology but only "
-        f"{len(accel)} accelerator / {len(cpus)} cpu devices exist "
-        "(set XLA_FLAGS=--xla_force_host_platform_device_count=N for testing)")
+    if len(devs) < n:
+        raise ValueError(
+            f"need {n} devices for the hybrid topology but the default "
+            f"backend ({devs[0].platform}) has {len(devs)} (for CPU tests "
+            "set JAX_PLATFORMS=cpu and "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=N)")
+    return devs[:n]
 
 
 class CommunicateTopology:

@@ -10,8 +10,10 @@ from .controller import LaunchConfig, launch
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="python -m paddle_tpu.distributed.launch",
-        description="Launch distributed training (one proc per host on TPU; "
-                    "--nproc_per_node>1 for CPU simulation/tests)")
+        description="Launch distributed training: ONE process per host "
+                    "drives all of its chips. --nproc_per_node>1 is the "
+                    "CPU simulation and is refused unless "
+                    "JAX_PLATFORMS=cpu")
     p.add_argument("--nproc_per_node", type=int, default=1)
     p.add_argument("--nnodes", type=int, default=1)
     p.add_argument("--node_rank", type=int, default=0)
@@ -22,7 +24,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--max_restarts", type=int, default=0,
                    help="elastic checkpoint-restart rounds on failure")
     p.add_argument("--devices", "--gpus", type=str, default=None,
-                   help="device list for parity with the reference CLI")
+                   help="accepted for parity with the reference CLI and "
+                        "ignored: nothing partitions a host's chips")
     p.add_argument("--heartbeat_interval", type=float, default=5.0)
     p.add_argument("-m", "--module", action="store_true",
                    help="run script as a module (python -m)")

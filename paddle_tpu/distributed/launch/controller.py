@@ -1,5 +1,14 @@
 """Node controller: spawn/monitor/restart per-rank processes
 (ref: python/paddle/distributed/launch/controllers/collective.py).
+
+One process for each host. The design is single-controller SPMD: ONE
+process drives every chip of its host through one jax mesh, and a chip
+belongs to one process at a time. Children inherit the whole environment
+and nothing partitions the chips among them, so several processes on a
+host that has an accelerator would each try to take all of it, and hang.
+``--nproc_per_node > 1`` is therefore the CPU simulation only: the
+controller refuses it unless the children's ``JAX_PLATFORMS`` is ``cpu``.
+The controller itself never touches jax.
 """
 from __future__ import annotations
 
@@ -26,7 +35,7 @@ class LaunchConfig:
     job_id: str = "default"
     log_dir: str = "log"
     max_restarts: int = 0
-    devices: Optional[str] = None     # parity with --gpus/--devices
+    devices: Optional[str] = None     # accepted for CLI parity, unused
     envs: dict = field(default_factory=dict)
     # run module (python -m mod) instead of a script
     run_module: bool = False
@@ -73,8 +82,6 @@ class NodeController:
             "PADDLE_ELASTIC_MAX_RESTARTS": str(self.cfg.max_restarts),
             "PADDLE_HEARTBEAT_INTERVAL": str(self.cfg.heartbeat_interval),
         })
-        if self.cfg.devices is not None:
-            env["PADDLE_SELECTED_DEVICES"] = self.cfg.devices
         return env
 
     # -- spawn ------------------------------------------------------------
@@ -147,7 +154,22 @@ class NodeController:
                 pass
 
     # -- main loop --------------------------------------------------------
+    def _check_one_process_per_host(self):
+        if self.cfg.nproc_per_node <= 1:
+            return
+        platforms = {**os.environ, **self.cfg.envs}.get("JAX_PLATFORMS")
+        if platforms != "cpu":
+            raise RuntimeError(
+                f"--nproc_per_node={self.cfg.nproc_per_node} would start "
+                f"{self.cfg.nproc_per_node} processes that each take every "
+                f"chip of this host (JAX_PLATFORMS={platforms!r}), and a "
+                "chip belongs to one process: they would hang. One process "
+                "drives all local chips (use --nproc_per_node=1 and a mesh "
+                "over jax.devices()); several processes per node are the "
+                "CPU simulation, which needs JAX_PLATFORMS=cpu.")
+
     def run(self) -> int:
+        self._check_one_process_per_host()
         host, port = self._start_master()
         restart_round = 0
         try:

@@ -365,7 +365,7 @@ class TrainStep:
 
         def island_loss_grads(train_params, frozen_params, buffers, batch,
                               rng):
-            from .._compat import shard_map
+            from jax import shard_map
             n_tot = 1
             for ax in sync_axes:
                 n_tot *= mesh.shape[ax]

@@ -228,7 +228,7 @@ def _moe_ffn(p, x_, config: ErnieMoEConfig, use_onehot=False,
         # too and moves only each destination's actual rows via the
         # ragged all-to-all — no token replication, no combine psum.
         # The one-hot einsum fallback below stays for mesh-less callers.
-        from .._compat import shard_map
+        from jax import shard_map
         from ..parallel.moe import (moe_ragged_dispatch_a2a,
                                     moe_ragged_dispatch_local,
                                     moe_slot_dispatch_local)

@@ -53,12 +53,12 @@ def _use_pallas(query) -> bool:
     if not flags.get_flag("use_pallas_kernels"):
         return False
     data = query._data if isinstance(query, Tensor) else query
-    try:
-        dev = next(iter(data.devices()))
-        return dev.platform != "cpu"
-    except Exception:
-        # tracer: no concrete device — trust the default backend
-        return jax.default_backend() == "tpu"
+    if isinstance(data, jax.core.Tracer) or not isinstance(data, jax.Array):
+        # no concrete device to ask: the kernels' own predicate decides
+        # (compiled unless the computation's devices are CPU)
+        from ...ops._common import interpret_mode
+        return not interpret_mode()
+    return next(iter(data.devices())).platform != "cpu"
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,

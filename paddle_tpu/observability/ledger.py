@@ -49,17 +49,15 @@ ENV_LEDGER_DIR = "PADDLE_TPU_LEDGER_DIR"
 # metrics.PEAK_FLOPS_TABLE). Datasheet numbers — achieved-vs-roofline
 # fractions read against these are the conventional (conservative)
 # roofline, not the measured-achievable ceiling bench.py's
-# measured_hbm_bw() reports. The 'cpu' entry is nominal so virtual-mesh
-# runs classify at all.
+# measured_hbm_bw() reports. No CPU row and no bare "v5" row, as in
+# metrics.PEAK_FLOPS_TABLE: a device without a row has no rate.
 HBM_BW_TABLE = (
     ("v6e", 1640e9), ("trillium", 1640e9),
     ("v5p", 2765e9),
     ("v5 lite", 819e9), ("v5e", 819e9), ("v5litepod", 819e9),
-    ("v5", 2765e9),
     ("v4", 1228e9),
     ("v3", 900e9),
     ("v2", 700e9),
-    ("cpu", 50e9),
 )
 
 

@@ -25,18 +25,16 @@ ENV_PEAK_FLOPS = "PADDLE_TPU_PEAK_FLOPS"
 
 # Per-chip peak FLOP/s by PJRT device_kind substring (bf16 with int8-free
 # MXU peaks, the denominators MFU papers use). Matched case-insensitively,
-# FIRST match wins, so the more specific names come first. The 'cpu' entry
-# is a nominal 100 GFLOP/s per virtual device so virtual-mesh runs report a
-# finite (clearly-labeled-estimate) MFU; override with PADDLE_TPU_PEAK_FLOPS.
+# FIRST match wins. A device with no row has no rate: there is no CPU row
+# (a CPU run reports no MFU) and no bare "v5" row (it would claim every
+# later "v5..." kind); PADDLE_TPU_PEAK_FLOPS states a rate by hand.
 PEAK_FLOPS_TABLE = (
     ("v6e", 918e12), ("trillium", 918e12),
     ("v5p", 459e12),
     ("v5 lite", 197e12), ("v5e", 197e12), ("v5litepod", 197e12),
-    ("v5", 459e12),
     ("v4", 275e12),
     ("v3", 123e12),
     ("v2", 45e12),
-    ("cpu", 100e9),
 )
 
 
@@ -63,7 +61,9 @@ def peak_flops_info(device=None):
     for key, flops in PEAK_FLOPS_TABLE:
         if key in kind:
             return flops, f"table:{key}"
-    if kind not in _PEAK_WARNED:
+    if kind != "cpu" and kind not in _PEAK_WARNED:
+        # a CPU has no rate by design (source "unknown:cpu", MFU None);
+        # an accelerator the table does not know is worth a warning
         _PEAK_WARNED.add(kind)
         import warnings
         warnings.warn(
@@ -72,6 +72,19 @@ def peak_flops_info(device=None):
             f"set PADDLE_TPU_PEAK_FLOPS or extend "
             f"observability.metrics.PEAK_FLOPS_TABLE", stacklevel=2)
     return None, f"unknown:{kind or '?'}"
+
+
+def require_peak_flops(device=None) -> float:
+    """Peak FLOP/s for a MEASUREMENT (a reported MFU or roofline share):
+    a device without a rate raises, where the telemetry path
+    (:func:`peak_flops_info`) carries ``None`` and its source."""
+    flops, source = peak_flops_info(device)
+    if flops is None:
+        raise RuntimeError(
+            f"no peak FLOP/s for this device ({source}): add its "
+            f"device_kind to observability.metrics.PEAK_FLOPS_TABLE or set "
+            f"{ENV_PEAK_FLOPS}; a rate is never assumed")
+    return flops
 
 
 def peak_flops_per_device(device=None) -> Optional[float]:

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import tpu_compiler_params as _tpu_compiler_params
 from ._common import cost_estimate as _cost_estimate
 from ._common import interpret_mode as _interpret
 from ._common import mosaic_trace_ctx as _mosaic_ctx
@@ -779,7 +778,7 @@ def _bwd_fused_call(qp, kp, vp, dop, lse3, delta3, cq2d, ck2d, ki_a, qi_a,
             jax.ShapeDtypeStruct(kp.shape, kp.dtype),
             jax.ShapeDtypeStruct(vp.shape, vp.dtype),
         ],
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_BWD_VMEM_LIMIT),
         cost_estimate=_cost_estimate(
             flops=10 * h * n_flat * block_q * block_k * d,

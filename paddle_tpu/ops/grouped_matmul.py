@@ -242,14 +242,18 @@ def _gmm_dw_call(lhs, dout, sched, tile_rows, E, dw_dtype):
     n = dout.shape[1]
     n_t = m // tile_rows
     it = jnp.dtype(lhs.dtype).itemsize
-    # acc scratch is [block_k, block_n] f32: shrink block_k, then
-    # block_n, until the accumulator fits the budget (each extra k/n
-    # block re-streams the whole token buffer, so prefer big blocks)
+    # a [block_k, block_n] block lives three times in scoped VMEM: the
+    # f32 acc scratch and the double-buffered out window. Shrink
+    # block_k, then block_n, until all three fit the budget (each extra
+    # k/n block re-streams the whole token buffer, so prefer big
+    # blocks). Pricing the scratch alone let K=2048 x N=1024 through at
+    # 25M, which v5e's compiler refuses against its 16M scoped default.
     budget = 2 * _GMM_RHS_BUDGET
+    per_elem = 4 + 2 * jnp.dtype(dw_dtype).itemsize
     block_k, block_n = k, n
-    while block_k > 128 and block_k * block_n * 4 > budget:
+    while block_k > 128 and block_k * block_n * per_elem > budget:
         block_k //= 2
-    while block_n > 128 and block_k * block_n * 4 > budget:
+    while block_n > 128 and block_k * block_n * per_elem > budget:
         block_n //= 2
     while k % block_k:
         block_k //= 2

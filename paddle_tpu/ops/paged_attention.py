@@ -61,6 +61,19 @@ _SEQ, _BLK, _START, _FIRST, _LAST, _LIVE, _POS, _COL, _UBLK = range(9)
 N_FIELDS = 9
 
 
+def _column_tile(row, block_size):
+    """One new KV column as a [KVD, bs] f32 tile carrying it in EVERY
+    lane: row [1, KVD] arrives with KVD in lanes (how XLA produces a
+    token's k/v), the pool tile wants KVD in sublanes, so broadcast the
+    row down bs sublanes and transpose — pure data movement, exact. The
+    operand stays a lane-major [.., 1, KVD] array whose (1, KVD) block
+    is the whole of its last two dims (what the Mosaic lowering asks
+    of a block shape); a [.., KVD, 1] operand would lower too but pads
+    every column to 128 lanes in HBM."""
+    row = row.astype(jnp.float32)
+    return jnp.broadcast_to(row, (block_size, row.shape[1])).T
+
+
 def paged_schedule(lengths, tables, n_steps, block_size):
     """Flat live-block schedule: [N_FIELDS, n_steps] i32.
 
@@ -231,8 +244,7 @@ def _paged_update_kernel(lp_ref, sc_ref, q_ref, nk_ref, nv_ref,
     def merged(tile_ref, new_ref):
         # minor-dim insert goes through f32 (Mosaic bf16 limitation,
         # same as decode_attention._kernel_update)
-        new32 = new_ref[0].astype(jnp.float32)[:, None]
-        return jnp.where(lane == col, new32,
+        return jnp.where(lane == col, _column_tile(new_ref[0], block_size),
                          tile_ref[0, 0].astype(jnp.float32)) \
             .astype(tile_ref.dtype)
 
@@ -330,7 +342,7 @@ def paged_attend_update(q_bd, new_k, new_v, k_pool, v_pool, tables,
         return (sc_ref[_SEQ, j], 0, 0)
 
     def new_map(j, lp_ref, sc_ref):
-        return (sc_ref[_SEQ, j], 0)
+        return (sc_ref[_SEQ, j], 0, 0)
 
     def upd_map(j, lp_ref, sc_ref):
         # constant per sequence: the block holding the new column; the
@@ -348,8 +360,8 @@ def paged_attend_update(q_bd, new_k, new_v, k_pool, v_pool, tables,
                 grid=(n_steps,),
                 in_specs=[
                     pl.BlockSpec((1, nh, kvd), q_map),
-                    pl.BlockSpec((1, kvd), new_map),
-                    pl.BlockSpec((1, kvd), new_map),
+                    pl.BlockSpec((1, 1, kvd), new_map),
+                    pl.BlockSpec((1, 1, kvd), new_map),
                     pl.BlockSpec((1, 1, kvd, bs), kv_map),
                     pl.BlockSpec((1, 1, kvd, bs), kv_map),
                 ],
@@ -379,7 +391,7 @@ def paged_attend_update(q_bd, new_k, new_v, k_pool, v_pool, tables,
                                 + 4 * b * kvd * bs * it),
                 name="paged.attend_update"),
             interpret=_interpret(),
-        )(lp, sched, q_bd, new_k, new_v, k_pool, v_pool)
+        )(lp, sched, q_bd, new_k[:, None], new_v[:, None], k_pool, v_pool)
     return out, kp, vp
 
 
@@ -606,15 +618,13 @@ def _paged_update_quant_kernel(lp_ref, sc_ref, q_ref, nk_ref, nv_ref,
         # start uninitialized); the int8 insert routes through f32 like
         # the fp16 kernel's minor-dim insert — exact for int8 values
         ko_ref[0, 0] = jnp.where(
-            lane == col, nk_ref[0].astype(jnp.float32)[:, None],
+            lane == col, _column_tile(nk_ref[0], block_size),
             k_ref[0, 0].astype(jnp.float32)).astype(jnp.int8)
         vo_ref[0, 0] = jnp.where(
-            lane == col, nv_ref[0].astype(jnp.float32)[:, None],
+            lane == col, _column_tile(nv_ref[0], block_size),
             v_ref[0, 0].astype(jnp.float32)).astype(jnp.int8)
-        kso_ref[0, 0] = jnp.where(lane_s == col, nks_ref[0][:, None],
-                                  ks_ref[0, 0])
-        vso_ref[0, 0] = jnp.where(lane_s == col, nvs_ref[0][:, None],
-                                  vs_ref[0, 0])
+        kso_ref[0, 0] = jnp.where(lane_s == col, nks_ref[0], ks_ref[0, 0])
+        vso_ref[0, 0] = jnp.where(lane_s == col, nvs_ref[0], vs_ref[0, 0])
 
     def chain(k_at, v_at, ks_at, vs_at, is_first):
         k_deq = _dequant_tile(k_at, ks_at, nkv)
@@ -705,7 +715,7 @@ def paged_attend_update_quant(q_bd, new_k, new_v, new_ks, new_vs,
         return (sc_ref[_SEQ, j], 0, 0)
 
     def new_map(j, lp_ref, sc_ref):
-        return (sc_ref[_SEQ, j], 0)
+        return (sc_ref[_SEQ, j], 0, 0)
 
     def upd_map(j, lp_ref, sc_ref):
         return (lp_ref[0], sc_ref[_UBLK, j], 0, 0)
@@ -720,10 +730,10 @@ def paged_attend_update_quant(q_bd, new_k, new_v, new_ks, new_vs,
                 grid=(n_steps,),
                 in_specs=[
                     pl.BlockSpec((1, nh, kvd_b), q_map),
-                    pl.BlockSpec((1, kvd_b), new_map),
-                    pl.BlockSpec((1, kvd_b), new_map),
-                    pl.BlockSpec((1, nkv_b), new_map),
-                    pl.BlockSpec((1, nkv_b), new_map),
+                    pl.BlockSpec((1, 1, kvd_b), new_map),
+                    pl.BlockSpec((1, 1, kvd_b), new_map),
+                    pl.BlockSpec((1, nkv_b, 1), new_map),
+                    pl.BlockSpec((1, nkv_b, 1), new_map),
                     pl.BlockSpec((1, 1, kvd_b, bs_b), kv_map),
                     pl.BlockSpec((1, 1, kvd_b, bs_b), kv_map),
                     pl.BlockSpec((1, 1, nkv_b, bs_b), kv_map),
@@ -761,7 +771,8 @@ def paged_attend_update_quant(q_bd, new_k, new_v, new_ks, new_vs,
                                 + 4 * b * (kvd + nkv) * bs * it),
                 name="paged.attend_update_quant"),
             interpret=_interpret(),
-        )(lp, sched, q_bd, new_k, new_v, new_ks, new_vs,
+        )(lp, sched, q_bd, new_k[:, None], new_v[:, None],
+          new_ks[:, :, None], new_vs[:, :, None],
           k_pool, v_pool, k_scale, v_scale)
     return out, kp, vp, ks, vs
 
@@ -1140,13 +1151,13 @@ def _paged_commit_kernel(sc_ref, nk_ref, nv_ref, k_ref, v_ref,
     j = pl.program_id(0)
     col = sc_ref[_CCOL, j]
     first = sc_ref[_CFIRST, j] == np.int32(1)
-    kvd = nk_ref.shape[3]
+    kvd = nk_ref.shape[4]
     lane = lax.broadcasted_iota(jnp.int32, (kvd, block_size), 1)
 
     def merged(base, new_ref):
-        new32 = new_ref[0, 0, 0].astype(jnp.float32)[:, None]
-        return jnp.where(lane == col, new32, base.astype(jnp.float32)) \
-            .astype(ko_ref.dtype)
+        return jnp.where(lane == col,
+                         _column_tile(new_ref[0, 0, 0], block_size),
+                         base.astype(jnp.float32)).astype(ko_ref.dtype)
 
     @pl.when(first)
     def _fresh():
@@ -1174,7 +1185,7 @@ def paged_verify_commit(new_k, new_v, k_pool, v_pool, tables, qstart,
     sched = paged_commit_schedule(qstart, commit_len, tables, L, T, bs)
 
     def new_map(j, sc_ref):
-        return (sc_ref[_CL, j], sc_ref[_CSEQ, j], sc_ref[_CT, j], 0)
+        return (sc_ref[_CL, j], sc_ref[_CSEQ, j], sc_ref[_CT, j], 0, 0)
 
     def pool_map(j, sc_ref):
         return (sc_ref[_CL, j], sc_ref[_CB, j], 0, 0)
@@ -1186,8 +1197,8 @@ def paged_verify_commit(new_k, new_v, k_pool, v_pool, tables, qstart,
                 num_scalar_prefetch=1,
                 grid=(n,),
                 in_specs=[
-                    pl.BlockSpec((1, 1, 1, kvd), new_map),
-                    pl.BlockSpec((1, 1, 1, kvd), new_map),
+                    pl.BlockSpec((1, 1, 1, 1, kvd), new_map),
+                    pl.BlockSpec((1, 1, 1, 1, kvd), new_map),
                     pl.BlockSpec((1, 1, kvd, bs), pool_map),
                     pl.BlockSpec((1, 1, kvd, bs), pool_map),
                 ],
@@ -1209,7 +1220,8 @@ def paged_verify_commit(new_k, new_v, k_pool, v_pool, tables, qstart,
                 bytes_accessed=(2 * kvd * bs * it + 2 * kvd * it) * n,
                 name="paged.verify_commit"),
             interpret=_interpret(),
-        )(sched, new_k, new_v, k_pool, v_pool)
+        )(sched, new_k[:, :, :, None], new_v[:, :, :, None],
+          k_pool, v_pool)
     return kp, vp
 
 
@@ -1225,17 +1237,17 @@ def _paged_commit_quant_kernel(sc_ref, nk_ref, nv_ref, nks_ref, nvs_ref,
     j = pl.program_id(0)
     col = sc_ref[_CCOL, j]
     first = sc_ref[_CFIRST, j] == np.int32(1)
-    kvd = nk_ref.shape[3]
+    kvd = nk_ref.shape[4]
     lane = lax.broadcasted_iota(jnp.int32, (kvd, block_size), 1)
     lane_s = lax.broadcasted_iota(jnp.int32, (nkv, block_size), 1)
 
     def merged(base, new_ref):
-        new32 = new_ref[0, 0, 0].astype(jnp.float32)[:, None]
-        return jnp.where(lane == col, new32, base.astype(jnp.float32)) \
-            .astype(jnp.int8)
+        return jnp.where(lane == col,
+                         _column_tile(new_ref[0, 0, 0], block_size),
+                         base.astype(jnp.float32)).astype(jnp.int8)
 
     def merged_s(base, new_ref):
-        return jnp.where(lane_s == col, new_ref[0, 0, 0][:, None], base)
+        return jnp.where(lane_s == col, new_ref[0, 0, 0], base)
 
     @pl.when(first)
     def _fresh():
@@ -1270,7 +1282,7 @@ def paged_verify_commit_quant(new_k, new_v, new_ks, new_vs, k_pool,
     sched = paged_commit_schedule(qstart, commit_len, tables, L, T, bs)
 
     def new_map(j, sc_ref):
-        return (sc_ref[_CL, j], sc_ref[_CSEQ, j], sc_ref[_CT, j], 0)
+        return (sc_ref[_CL, j], sc_ref[_CSEQ, j], sc_ref[_CT, j], 0, 0)
 
     def pool_map(j, sc_ref):
         return (sc_ref[_CL, j], sc_ref[_CB, j], 0, 0)
@@ -1283,10 +1295,10 @@ def paged_verify_commit_quant(new_k, new_v, new_ks, new_vs, k_pool,
                 num_scalar_prefetch=1,
                 grid=(n,),
                 in_specs=[
-                    pl.BlockSpec((1, 1, 1, kvd_b), new_map),
-                    pl.BlockSpec((1, 1, 1, kvd_b), new_map),
-                    pl.BlockSpec((1, 1, 1, nkv_b), new_map),
-                    pl.BlockSpec((1, 1, 1, nkv_b), new_map),
+                    pl.BlockSpec((1, 1, 1, 1, kvd_b), new_map),
+                    pl.BlockSpec((1, 1, 1, 1, kvd_b), new_map),
+                    pl.BlockSpec((1, 1, 1, nkv_b, 1), new_map),
+                    pl.BlockSpec((1, 1, 1, nkv_b, 1), new_map),
                     pl.BlockSpec((1, 1, kvd_b, bs_b), pool_map),
                     pl.BlockSpec((1, 1, kvd_b, bs_b), pool_map),
                     pl.BlockSpec((1, 1, nkv_b, bs_b), pool_map),
@@ -1316,6 +1328,7 @@ def paged_verify_commit_quant(new_k, new_v, new_ks, new_vs, k_pool,
                                 + 2 * (kvd + 4 * nkv) * it) * n,
                 name="paged.verify_commit_quant"),
             interpret=_interpret(),
-        )(sched, new_k, new_v, new_ks, new_vs,
+        )(sched, new_k[:, :, :, None], new_v[:, :, :, None],
+          new_ks[..., None], new_vs[..., None],
           k_pool, v_pool, k_scale, v_scale)
     return kp, vp, ks, vs

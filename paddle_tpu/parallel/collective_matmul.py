@@ -52,10 +52,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .. import envs
-from .._compat import shard_map
 from ..observability import trace as _obs
 
 ENV_OVERLAP = "PADDLE_TPU_TP_OVERLAP"

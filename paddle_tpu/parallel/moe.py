@@ -16,9 +16,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import axis_size as _axis_size
 
 from .. import envs
-from .._compat import axis_size as _axis_size
 from ..observability import trace as _obs
 
 

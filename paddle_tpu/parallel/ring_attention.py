@@ -29,8 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from .._compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 from ..ops.flash_attention import flash_block_fwd, flash_block_bwd
 

@@ -29,8 +29,8 @@ import warnings
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import axis_size as _axis_size
 
-from .._compat import axis_size as _axis_size
 from ..observability import trace as _obs
 from .. import envs
 from ..ops.flash_attention import flash_block_bwd, flash_block_fwd

@@ -11,10 +11,11 @@ Components (see csrc/ for the C++ side):
 - tracer functions — host span tracer w/ chrome-trace export
   (ref: paddle/fluid/platform/profiler/)
 
-If the shared library is missing, it is built on demand with ``make`` (cached
-thereafter).  If no toolchain is available, pure-Python fallbacks speaking the
-same TCP wire protocol keep everything functional (slower): mixed clusters of
-native and fallback processes interoperate.
+If the shared library is missing or older than its sources under csrc/, it
+is built on demand with ``make`` (cached thereafter).  If no toolchain is
+available, pure-Python fallbacks speaking the same TCP wire protocol keep
+everything functional (slower): mixed clusters of native and fallback
+processes interoperate.
 """
 from __future__ import annotations
 
@@ -40,13 +41,37 @@ _load_attempted = False
 _load_error = None
 
 
+def _stale() -> bool:
+    """True where the library is missing or older than a file under csrc/
+    it is built from: a library left over from other sources is rebuilt
+    or reported, never loaded in silence."""
+    if not os.path.exists(_LIB_PATH):
+        return True
+    built = os.path.getmtime(_LIB_PATH)
+    return any(os.path.getmtime(os.path.join(_CSRC, f)) > built
+               for f in os.listdir(_CSRC)
+               if f.endswith((".cc", ".h")) or f == "Makefile")
+
+
 def _try_build() -> bool:
+    """``make`` into a name of this process's own, then rename over the
+    library: several processes may build at once (test workers on a fresh
+    checkout) and none may load a half-written file."""
+    csrc = os.path.abspath(_CSRC)
+    tmp = os.path.join(csrc, f"libpd_runtime.build{os.getpid()}.so")
     try:
-        r = subprocess.run(["make", "-C", os.path.abspath(_CSRC)],
-                           capture_output=True, timeout=300)
-        return r.returncode == 0
-    except Exception:
+        r = subprocess.run(
+            ["make", "-C", csrc, f"OUT={os.path.basename(tmp)}"],
+            capture_output=True, timeout=300)
+        if r.returncode != 0:
+            return False
+        os.replace(tmp, _LIB_PATH)
+        return True
+    except (OSError, subprocess.SubprocessError):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def _bind(lib):
@@ -112,8 +137,10 @@ def load():
     if os.environ.get("PD_DISABLE_NATIVE", "0") == "1":
         _load_error = "disabled via PD_DISABLE_NATIVE"
         return None
-    if not os.path.exists(_LIB_PATH) and not _try_build():
-        _load_error = "libpd_runtime.so missing and build failed"
+    if (not os.environ.get("PD_RUNTIME_LIB") and _stale()
+            and not _try_build()):
+        _load_error = ("libpd_runtime.so is missing or older than csrc/ "
+                       "and the build failed")
         return None
     try:
         _lib = _bind(ctypes.CDLL(_LIB_PATH))

@@ -106,3 +106,30 @@ class OpTest:
                 atol=atol if atol is not None else self.grad_atol,
                 rtol=rtol if rtol is not None else self.grad_rtol,
                 err_msg=f"grad mismatch for input {i}")
+
+
+def max_ulps(got, want) -> float:
+    """Largest |got - want| over two pytrees, in ULPs of each leaf's
+    largest magnitude (float leaves; other leaves must be equal). The
+    unit for "the same maths in another accumulation order": a sum
+    re-associated by the backend moves by a few ULPs of its terms, which
+    for an element near zero is many ULPs of that element, so the yardstick
+    is the leaf, not the element."""
+    import jax
+    worst = 0.0
+    la = jax.tree_util.tree_leaves(got)
+    lb = jax.tree_util.tree_leaves(want)
+    assert len(la) == len(lb), (len(la), len(lb))
+    for a, b in zip(la, lb):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape, (a.shape, b.shape)
+        if a.dtype.kind != "f":
+            assert np.array_equal(a, b)
+            continue
+        diff = np.abs(a.astype(np.float64) - b.astype(np.float64)).max()
+        scale = np.asarray(np.maximum(np.abs(a).max(), np.abs(b).max()),
+                           a.dtype)
+        if diff:
+            worst = max(worst, float(diff / np.spacing(scale)))
+    return worst
+

@@ -1,0 +1,255 @@
+"""The main path's programs, compiled for the chip without the chip.
+
+The TPU's compiler is installed here and compiles for a v5e that is described,
+not attached (``jax.experimental.topologies``). Interpret mode checks neither
+the Mosaic lowering nor VMEM nor HBM, and every refusal below was found only
+by such a compile: block shapes of the fused paged update and commit kernels,
+16.02M of scoped VMEM in the flash forward inside a 7B-wide step, a
+pool-sized relayout copy in the paged prefill, Mosaic kernels outside a
+shard_map on a dp x mp mesh. Shapes are ``chip_smoke.FULL``'s: what the
+smoke runs on the chip is what is compiled here.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may hold the TPU library, and every xdist worker imports
+this file. Keep these tests in this one file for the same reason.
+"""
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import chip_smoke  # noqa: E402
+from paddle_tpu.models import llama as L  # noqa: E402
+from paddle_tpu.ops import _common, flash_attention as fa  # noqa: E402
+from paddle_tpu.ops import paged_attention as pa  # noqa: E402
+
+FULL = chip_smoke.FULL
+NH = NKV = 32
+KVD, BS = 4096, 128
+BATCH, T_FED = FULL.max_batch, FULL.draft_k + 1
+MAX_NB = FULL.max_seq_len // BS
+POOL_BLOCKS = 64         # the kernels see one block at a time
+bf16, i8, f32, i32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """v5e 2x2, with the persistent compile cache off around these
+    compiles: an entry written for a described chip cannot be read back
+    without one, and the next run would warn about it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # noqa: PTA007 -- process-lifetime: keeps the compiler's logs out of /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure to describe is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def sds(one_chip):
+    """shape, dtype -> ShapeDtypeStruct on the described chip."""
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def compile_for_chip(fn, *args):
+    """Lower and compile with the kernels' interpret predicate held off
+    (the backend here is the CPU); what the chip's compiler would raise,
+    this raises. Returns the compiled program."""
+    jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+    with _common.interpret_mode(False):
+        return jitted.lower(*args).compile()
+
+
+def gib(n):
+    return n / 2 ** 30
+
+
+# -- the eight paged kernels, the four repaired ones first --------------------
+
+@pytest.fixture(scope="module")
+def paged_args(sds):
+    """name -> (function, abstract arguments) at the 7B widths."""
+    q = sds((BATCH, NH, KVD), bf16)
+    q_fed = sds((BATCH, T_FED * NH, KVD), bf16)
+    pool = {False: sds((2, POOL_BLOCKS, KVD, BS), bf16),
+            True: sds((2, POOL_BLOCKS, KVD, BS), i8)}
+    scale = sds((2, POOL_BLOCKS, NKV, BS), f32)
+    col = {False: sds((BATCH, KVD), bf16), True: sds((BATCH, KVD), i8)}
+    col_s = sds((BATCH, NKV), f32)
+    fed = {False: sds((2, BATCH, T_FED, KVD), bf16),
+           True: sds((2, BATCH, T_FED, KVD), i8)}
+    fed_s = sds((2, BATCH, T_FED, NKV), f32)
+    tables, lens, layer = (sds((BATCH, MAX_NB), i32), sds((BATCH,), i32),
+                           sds((), i32))
+    kp, kq = pool[False], pool[True]
+    return {
+        "attend_update": (pa.paged_attend_update, (
+            q, col[False], col[False], kp, kp, tables, lens, layer)),
+        "attend_update_quant": (pa.paged_attend_update_quant, (
+            q, col[True], col[True], col_s, col_s, kq, kq, scale, scale,
+            tables, lens, layer)),
+        "verify_commit": (pa.paged_verify_commit, (
+            fed[False], fed[False], kp, kp, tables, lens, lens)),
+        "verify_commit_quant": (pa.paged_verify_commit_quant, (
+            fed[True], fed[True], fed_s, fed_s, kq, kq, scale, scale,
+            tables, lens, lens)),
+        "attention": (pa.paged_attention, (q, kp, kp, tables, lens, layer)),
+        "attention_quant": (pa.paged_attention_quant, (
+            q, kq, kq, scale, scale, tables, lens, layer)),
+        "attention_verify": (pa.paged_attention_verify, (
+            q_fed, kp, kp, tables, lens, layer)),
+        "attention_verify_quant": (pa.paged_attention_verify_quant, (
+            q_fed, kq, kq, scale, scale, tables, lens, layer)),
+    }
+
+
+@pytest.mark.parametrize("name", [
+    "attend_update", "attend_update_quant", "verify_commit",
+    "verify_commit_quant", "attention", "attention_quant",
+    "attention_verify", "attention_verify_quant"])
+def test_paged_kernel_compiles(paged_args, name):
+    fn, args = paged_args[name]
+    assert "tpu_custom_call" in compile_for_chip(fn, *args).as_text()
+
+
+# -- the engine's jitted steps at chip_smoke's serving size -------------------
+
+@pytest.fixture(scope="module")
+def serve_args(sds):
+    """(frozen config, abstract weights, pools by quant) for the server."""
+    config = dataclasses.replace(L.llama_7b(),
+                                 num_hidden_layers=FULL.serve_layers)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: L.init_llama_params(config, 0)))
+    shape = (FULL.serve_layers, FULL.num_blocks, KVD, BS)
+    scale = sds((FULL.serve_layers, FULL.num_blocks, NKV, BS), f32)
+    pools = {False: (sds(shape, bf16),) * 2,
+             True: (sds(shape, i8),) * 2 + (scale, scale)}
+    return L._freeze_config(config), params, pools
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_engine_step_compiles_with_a_pool_half_of_hbm(serve_args, sds, kind,
+                                                      quant):
+    """The donated pools must alias through the step: the prefill once
+    copied the whole pool to another layout and back (a second pool-sized
+    buffer, refused outright at this pool size)."""
+    frozen, params, pools = serve_args
+    if kind == "decode":
+        fn = (L._jitted_paged_decode_quant if quant
+              else L._jitted_paged_decode)(frozen)
+        args = (sds((BATCH, MAX_NB), i32), sds((BATCH,), i32),
+                sds((BATCH,), i32))
+    else:
+        fn = (L._jitted_paged_prefill_quant if quant
+              else L._jitted_paged_prefill)(frozen)
+        args = (sds((MAX_NB,), i32), sds((), i32),
+                sds((FULL.prefill_chunk,), i32), sds((), i32))
+    compiled = compile_for_chip(fn, params, *pools[quant], *args)
+    assert "tpu_custom_call" in compiled.as_text()
+    pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize
+                     for p in pools[quant])
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= pool_bytes
+    assert gib(ma.temp_size_in_bytes) < 1.0, (
+        f"{gib(ma.temp_size_in_bytes):.2f} GiB of temporaries beside a "
+        f"{gib(pool_bytes):.2f} GiB pool: something pool-sized is copied")
+
+
+# -- dense flash attention and the train step ---------------------------------
+
+def test_flash_forward_and_fused_flat_backward_compile(sds):
+    q = sds((FULL.train_batch, FULL.train_seq, NH, 128), bf16)
+    stats = fa.dense_bwd_schedule_stats(
+        FULL.train_batch * NH, FULL.train_seq, FULL.train_seq, 128, bf16,
+        True)
+    assert stats["path"] == "fused_flat"
+
+    def loss(q, k, v):
+        return fa.flash_attention_bshd(q, k, v, causal=True).astype(f32).sum()
+
+    fwd = compile_for_chip(
+        lambda q, k, v: fa.flash_attention_bshd(q, k, v, causal=True),
+        q, q, q)
+    bwd = compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert fwd.as_text().count("tpu_custom_call") == 1
+    assert bwd.as_text().count("tpu_custom_call") == 2    # fwd + one bwd
+
+
+def abstract_train_step(parallel, mesh=None):
+    """(jitted step, abstract params, abstract optimizer state) of
+    build_train_step at chip_smoke's training size, nothing materialised."""
+    config = dataclasses.replace(L.llama_7b(),
+                                 num_hidden_layers=FULL.train_layers)
+    made = {}
+
+    def build():
+        made["step"], params, opt = L.build_train_step(config, parallel,
+                                                       mesh=mesh, lr=3e-4)
+        return params, opt
+
+    params, opt = jax.eval_shape(build)
+    return config, made["step"].jitted, params, opt
+
+
+def test_train_step_compiles_and_fits_one_chip(sds):
+    """The 7B-wide step wanted 16.02M of scoped VMEM for the flash forward
+    against a 16M default; and FULL's depth and batch must fit 15.75 GiB."""
+    _, step, params, opt = abstract_train_step(
+        L.ParallelConfig(remat=True, use_flash=True))
+    on_chip = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: sds(a.shape, a.dtype), t)
+    ids = sds((FULL.train_batch, FULL.train_seq), i32)
+    compiled = compile_for_chip(step, on_chip(params), on_chip(opt), ids, ids)
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    ma = compiled.memory_analysis()
+    need = gib(ma.argument_size_in_bytes + ma.temp_size_in_bytes)
+    assert need < 15.0, f"{need:.2f} GiB on a 15.75 GiB chip"
+
+
+def test_dp2_mp2_train_step_compiles_on_four_chips(topo):
+    """On a mesh the GSPMD path's kernels run per shard in shard_map
+    islands: outside one, the TPU lowering refuses the step ("Mosaic
+    kernels cannot be automatically partitioned")."""
+    parallel = L.ParallelConfig(dp=2, mp=2, remat=True, use_flash=True)
+    mesh = L.make_mesh(parallel, devices=topo.devices)
+    config, step, params, opt = abstract_train_step(parallel, mesh)
+    specs = L.param_pspecs(config, parallel)
+
+    def sharded(tree):
+        return jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
+            tree, specs,
+            is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+
+    opt = {"m": sharded(opt["m"]), "v": sharded(opt["v"]),
+           "t": jax.ShapeDtypeStruct((), f32,
+                                     sharding=NamedSharding(mesh, P()))}
+    ids = jax.ShapeDtypeStruct((FULL.train_batch, FULL.train_seq), i32,
+                               sharding=NamedSharding(mesh, P("dp", None)))
+    text = compile_for_chip(step, sharded(params), opt, ids, ids).as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text

@@ -117,9 +117,18 @@ def test_peak_flops_table(monkeypatch):
         device_kind = "TPU v5p"
     assert obs.peak_flops_per_device(FakeDev()) == 459e12
 
+    # no CPU row and no bare "v5" row: a device without a row has no
+    # rate, and a measurement that needs one raises
     class Cpu:
         device_kind = "cpu"
-    assert obs.peak_flops_per_device(Cpu()) == 100e9
+    assert obs.peak_flops_per_device(Cpu()) is None
+
+    class FutureV5:
+        device_kind = "TPU v5x"
+    obs.metrics._PEAK_WARNED.add("tpu v5x")   # the warning is not under test
+    assert obs.peak_flops_per_device(FutureV5()) is None
+    with pytest.raises(RuntimeError, match="unknown:cpu"):
+        obs.metrics.require_peak_flops(Cpu())
 
 
 # -- exporters ---------------------------------------------------------------
@@ -219,7 +228,10 @@ def _tiny_step(tmp_path, mesh=None, **kw):
                      telemetry_dir=str(tmp_path), **kw)
 
 
-def test_train_step_telemetry_jsonl(tmp_path):
+def test_train_step_telemetry_jsonl(tmp_path, monkeypatch):
+    # a CPU has no row in the peak table, so a rate is stated by hand here
+    # for the MFU arithmetic; without one the records carry mfu None
+    monkeypatch.setenv(obs.metrics.ENV_PEAK_FLOPS, "1e11")
     step = _tiny_step(tmp_path)
     rng = np.random.RandomState(0)
     x = paddle.to_tensor(rng.randn(4, 8).astype(np.float32))
@@ -242,6 +254,7 @@ def test_train_step_telemetry_jsonl(tmp_path):
     assert len(recs) == m.steps
     timed = [r for r in recs if r["step_time_ms"]]
     assert timed and all(r["mfu"] > 0 for r in timed)
+    assert all(r["mfu_peak_source"] == "env" for r in timed)
     assert all(r["tokens"] == 4 for r in recs)
 
 

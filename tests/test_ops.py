@@ -92,7 +92,7 @@ def test_rope_properties():
 
 
 def test_ring_attention_matches_dense():
-    from paddle_tpu._compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from paddle_tpu.parallel.ring_attention import ring_attention
     devs = np.array(jax.devices("cpu")[:4])
@@ -112,7 +112,7 @@ def test_ring_attention_matches_dense():
 
 
 def test_ulysses_matches_dense():
-    from paddle_tpu._compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from paddle_tpu.parallel.ring_attention import ulysses_attention
     devs = np.array(jax.devices("cpu")[:4])

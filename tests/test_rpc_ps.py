@@ -148,8 +148,9 @@ def test_rpc_two_processes(tmp_path):
     script = tmp_path / "rpc_worker.py"
     script.write_text(_WORKER_SCRIPT)
     port = str(_free_port())
-    env = dict(os.environ, REPO="/root/repo",
-               PYTHONPATH="/root/repo:" + os.environ.get("PYTHONPATH", ""))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, REPO=repo,
+               PYTHONPATH=repo + os.pathsep + os.environ.get("PYTHONPATH", ""))
     procs = [subprocess.Popen([sys.executable, str(script), str(r), port],
                               env=env, stdout=subprocess.PIPE,
                               stderr=subprocess.STDOUT)
