@@ -1,0 +1,11 @@
+"""The benchmark of paddle_tpu: one command runs one cell once.
+
+    python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+Everything that belongs to one configuration, one traffic mix or one
+per-layer metric is a data file found by the name in ``BENCHMARK.json``
+(``configs/``, ``traffic/``, ``metrics/``). The yardstick (traffic
+generation, FLOP and byte counts, peaks, the trace reduction, the plain
+reference and the comparison that decides ``correct``) lives here and takes
+from the program only the system under test.
+"""

@@ -1,0 +1,8 @@
+"""Tests of the harness itself: ``python -m pytest chipbench/tests`` (CPU,
+three minutes). They are not part of the repo's ``tests/``."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
