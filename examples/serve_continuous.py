@@ -80,7 +80,10 @@ def main():
               f" -> continuation: {seq.generated}")
 
     # observability exports: open the chrome trace in Perfetto
-    # (ui.perfetto.dev) — one row per engine phase, one row per request
+    # (ui.perfetto.dev) — one row per top-level engine phase (serve.step,
+    # .admit, .prefill, .decode, .report, .submit; plan / launch / wait /
+    # commit nest on their parent's row), one row per request. Under
+    # jax.profiler.start_trace the same spans land in the device trace.
     out = tempfile.mkdtemp(prefix="paddle_tpu_serve_")
     trace = engine.tracer.export_chrome(os.path.join(out, "serve_trace.json"))
     spans = engine.tracer.export_jsonl(os.path.join(out, "serve_spans.jsonl"))

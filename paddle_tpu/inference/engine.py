@@ -27,8 +27,11 @@ Scheduling per ``step()`` iteration:
      pool runs dry.
 
 Telemetry: queue depth, batch occupancy, block-pool utilization and
-prefill-vs-decode time share per iteration through StepMetrics, with
-comm_span/counter markers on every scheduling event.
+prefill-vs-decode time share per iteration through StepMetrics, counter
+markers on every scheduling event, and a ``serve.*`` span at every phase
+boundary of an iteration (``_span``): a profiler annotation on the device
+trace's clock while a profiler session runs, and the same name in the
+``RequestTracer`` when that is on.
 
 Overload + fault contract (PR 14):
 
@@ -95,7 +98,7 @@ from ..observability.histogram import LogHistogram
 from ..observability.registry import MetricsRegistry
 from ..observability.metrics import StepMetrics
 from ..observability.request_trace import RequestTracer
-from ..observability.trace import comm_span, record_counter
+from ..observability.trace import record_counter, span
 from .journal import EngineJournal, JournalCompatError, read_journal
 from .kv_cache import (BlockPool, PrefixCache, pad_table,
                        pool_bytes_per_rank)
@@ -295,6 +298,50 @@ class _Seq:
                     and g[-1] == self.req.eos_id))
 
 
+class _Phase:
+    """One phase boundary on the engine's thread, feeding every sink from
+    one call site: the profiler annotation (``observability.span``), the
+    iteration's per-phase milliseconds (telemetry, flight recorder) and,
+    when the request tracer is on, ``RequestTracer.phase`` under the same
+    name with the enclosing phase as its parent."""
+
+    __slots__ = ("eng", "name", "ann", "parent", "t0", "t1")
+
+    def __init__(self, eng: "InferenceEngine", name: str, args: dict):
+        self.eng, self.name = eng, name
+        self.ann = span(name, **args)
+
+    def __enter__(self) -> "_Phase":
+        open_ = self.eng._open_phases
+        self.parent = open_[-1] if open_ else None
+        open_.append(self.name)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def note(self, **args) -> None:
+        """Arguments known only once the phase is under way."""
+        self.ann.set_metadata(**args)
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self.ann.__exit__(*exc)
+        eng = self.eng
+        eng._open_phases.pop()
+        ms = eng._phase_ms
+        ms[self.name] = ms.get(self.name, 0.0) + (self.t1 - self.t0) * 1e3
+        if eng.tracer is not None:
+            eng.tracer.phase(self.name, self.t0, self.t1, eng.iteration,
+                             self.parent)
+
+
+# what a phase attempted and what of it was useful, by the argument's name
+# on the span: the registry counter it adds to
+_WORK_TOTALS = {"rows": "decode_rows_total", "bucket": "decode_slots_total",
+                "n_live": "prefill_tokens_total",
+                "chunk": "prefill_slots_total"}
+
+
 class InferenceEngine:
     """Continuous-batching engine over a paged KV cache.
 
@@ -425,6 +472,13 @@ class InferenceEngine:
         self._redrives = 0
         self._recovered = 0
         self._jtoks: List[Tuple[int, int]] = []  # this iteration's tokens
+        # phase spans (_span): the open ones, and this iteration's
+        # milliseconds by phase name and work counts by argument name
+        self._open_phases: List[str] = []
+        self._phase_ms: Dict[str, float] = {}
+        self._iter_work: Dict[str, int] = {}
+        self._compiled_at = -1           # the last iteration that compiled
+        self.work_totals = dict.fromkeys(_WORK_TOTALS.values(), 0)
         # unified exposition (PR 15): the SLO histograms register by
         # reference, scheduler gauges as render-time callbacks; the
         # registration order IS the metrics_snapshot() key order
@@ -592,6 +646,18 @@ class InferenceEngine:
         r.gauge("generated_tokens",
                 fn=lambda: sum(len(s.generated) for s in self.finished),
                 help="tokens generated by finished requests")
+        # useful over attempted, counted where the phase spans are: weights
+        # stream for ``slots`` to serve ``rows``, a chunk's program runs
+        # ``slots`` positions to cache ``tokens``. Monotonic.
+        for name, what in (
+                ("decode_rows_total", "sequences advanced by decode steps"),
+                ("decode_slots_total", "batch slots (the bucket) of the "
+                                       "decode steps run"),
+                ("prefill_tokens_total", "prompt tokens cached by prefill "
+                                         "chunks"),
+                ("prefill_slots_total", "token slots (the chunk) of the "
+                                        "prefill chunks run")):
+            r.gauge(name, fn=lambda n=name: self.work_totals[n], help=what)
         # PR 16 capacity gauges, only when the cache is live: the
         # default exposition stays byte-compatible with the pre-PR-15
         # legacy dict (pinned by the metrics-registry golden test)
@@ -837,11 +903,32 @@ class InferenceEngine:
     def _mark_compiled(self, kind: str, key, t_call: float):
         if (kind, key) not in self._compiled:
             self._compiled[(kind, key)] = t_call
+            self._compiled_at = self.iteration
             record_counter(f"serve.compile.{kind}")
             if self.metrics is not None:
                 self.metrics.record_compile(compile_s=t_call)
             if self.recorder is not None:
                 self.recorder.record_compile(f"{kind}_{key}", t_call)
+
+    def _span(self, name: str, **args) -> _Phase:
+        """``with self._span("serve.decode.plan"): ...`` at a phase
+        boundary; see :class:`_Phase`."""
+        return _Phase(self, name, args)
+
+    def _launch_span(self, name: str, key: Tuple) -> _Phase:
+        """A launch phase, marked ``first_call`` when its program is new
+        to ``_compiled`` (the call then traces and compiles)."""
+        return _Phase(self, name,
+                      {} if key in self._compiled else {"first_call": 1})
+
+    def _note_work(self, sp: _Phase, **counts: int) -> None:
+        """The useful-over-attempted counts of a phase (``rows`` of
+        ``bucket``, ``n_live`` of ``chunk``): onto its span, into this
+        iteration's record and into the registry's totals."""
+        sp.note(**counts)
+        self._iter_work.update(counts)
+        for k, v in counts.items():
+            self.work_totals[_WORK_TOTALS[k]] += v
 
     # -- public API ---------------------------------------------------------
 
@@ -892,6 +979,12 @@ class InferenceEngine:
         outcome, not an exception."""
         if req.request_id is None:
             req.request_id = next(self._rid)
+        with self._span("serve.submit", rid=req.request_id) as sp:
+            adm = self._submit(req)
+            sp.note(accepted=int(adm.accepted))
+        return adm
+
+    def _submit(self, req: Request) -> Admission:
         worst = len(req.prompt) + req.max_new_tokens
         if worst > self.serve.max_seq_len:
             raise ValueError(
@@ -997,30 +1090,51 @@ class InferenceEngine:
     def step(self) -> List[_Seq]:
         """One scheduler iteration: admit, one prefill chunk, one decode
         batch. Returns sequences that finished this iteration."""
-        # the gap between step() calls is the engine's safe boundary: the
-        # previous decode already synced its tokens to the host, nothing
-        # is in flight — scheduled weight swaps land exactly here
-        if self._pending_swap is not None \
-                and self.iteration + 1 >= self._pending_swap[1]:
-            source, _ = self._pending_swap
-            self._pending_swap = None
-            self._apply_swap(source)
-        self.iteration += 1
-        self._last_tokens = 0
-        self._jtoks = []
-        t_iter = time.perf_counter()
-        if faults.fires("serve.preempt_storm"):
-            # injected pool-pressure fault: forcibly evict the youngest
-            # running sequence, as if a burst had stolen its blocks
-            self._evict_one()
-        self._shed_expired()
-        self._admit()
-        t_adm = time.perf_counter()
-        done: List[_Seq] = []
-        ran_prefill = self._prefill_chunk(done)
-        t_pre = time.perf_counter()
-        done += self._decode_batch()
-        t_dec = time.perf_counter()
+        self._phase_ms = {}
+        self._iter_work = {}
+        with self._span("serve.step", iteration=self.iteration + 1) as st:
+            with self._span("serve.admit") as sp:
+                # the gap between step() calls is the engine's safe
+                # boundary: the previous decode already synced its tokens
+                # to the host, nothing is in flight — scheduled weight
+                # swaps land exactly here
+                if self._pending_swap is not None \
+                        and self.iteration + 1 >= self._pending_swap[1]:
+                    source, _ = self._pending_swap
+                    self._pending_swap = None
+                    self._apply_swap(source)
+                self.iteration += 1
+                self._last_tokens = 0
+                self._jtoks = []
+                if faults.fires("serve.preempt_storm"):
+                    # injected pool-pressure fault: forcibly evict the
+                    # youngest running sequence, as if a burst had stolen
+                    # its blocks
+                    self._evict_one()
+                waiting = len(self.waiting)
+                self._shed_expired()
+                sp.note(waiting=waiting, admitted=self._admit())
+            done: List[_Seq] = []
+            ran_prefill = False
+            seq = next((s for s in self.active if s.state == PREFILL), None)
+            if seq is not None:
+                with self._span("serve.prefill", rid=seq.req.request_id,
+                                start=seq.n_cached) as sp:
+                    ran_prefill = self._prefill_chunk(seq, sp, done)
+            if any(s.state == RUNNING for s in self.active):
+                with self._span("serve.decode") as sp:
+                    done += (self._decode_spec_batch(sp) if self.speculative
+                             else self._decode_batch(sp))
+            with self._span("serve.report") as rp:
+                self._report(done, ran_prefill, rp.t0 - st.t0, rp.t0)
+        return done
+
+    def _report(self, done: List[_Seq], ran_prefill: bool,
+                step_time_s: float, t_report: float) -> None:
+        """The end of an iteration: event log, journal, telemetry and the
+        flight recorder, fed from the phase boundaries the iteration
+        crossed. ``step_time_s`` runs from the start of ``step()`` to the
+        start of this report, as it always has."""
         for seq in done:
             self._event("finish", seq.req.request_id, len(seq.generated))
         if self._journal is not None:
@@ -1030,43 +1144,54 @@ class InferenceEngine:
             self._journal.tokens(self.iteration, self._jtoks)
             for seq in done:
                 self._journal.finish(seq.req.request_id)
-        if self.tracer is not None:
-            self.tracer.phase("admit", t_iter, t_adm, self.iteration)
-            if ran_prefill:
-                self.tracer.phase("prefill", t_adm, t_pre, self.iteration)
-            self.tracer.phase("decode", t_pre, t_dec, self.iteration)
-        if self.metrics is not None or self.recorder is not None:
-            n_run = n_pre = 0
-            for s in self.active:
-                if s.state == RUNNING:
-                    n_run += 1
-                elif s.state == PREFILL:
-                    n_pre += 1
-            fields = dict(
-                step_time_s=t_dec - t_iter,
-                tokens=self._last_tokens,
-                queue_depth=len(self.waiting),
-                n_running=n_run,
-                n_prefill=n_pre,
-                batch_occupancy=n_run / self.serve.max_batch,
-                pool_utilization=self.pool.utilization,
-                prefill_ms=(t_pre - t_adm) * 1e3 if ran_prefill else 0.0,
-                decode_ms=(t_dec - t_pre) * 1e3,
-            )
-            if self.metrics is not None:
-                self.metrics.step(**fields)
-            if self.recorder is not None:
-                self.recorder.record(
-                    {"iteration": self.iteration, **fields})
-                self.recorder.check_step_time(t_dec - t_iter)
-        return done
+        if self.metrics is None and self.recorder is None:
+            return
+        n_run = n_pre = 0
+        for s in self.active:
+            if s.state == RUNNING:
+                n_run += 1
+            elif s.state == PREFILL:
+                n_pre += 1
+        ms = self._phase_ms
+        fields = dict(
+            step_time_s=step_time_s,
+            tokens=self._last_tokens,
+            queue_depth=len(self.waiting),
+            n_running=n_run,
+            n_prefill=n_pre,
+            batch_occupancy=n_run / self.serve.max_batch,
+            pool_utilization=self.pool.utilization,
+            prefill_ms=ms.get("serve.prefill", 0.0),
+            decode_ms=ms.get("serve.decode", 0.0),
+        )
+        if self.metrics is not None:
+            self.metrics.step(**fields)
+        if self.recorder is not None:
+            # every phase the iteration crossed, "serve.decode.wait" as
+            # decode_wait_ms; the report's own time up to here, so that a
+            # stall in the journal shows and is checked too
+            report_ms = (time.perf_counter() - t_report) * 1e3
+            self.recorder.record({
+                "iteration": self.iteration, **fields,
+                **{k[len("serve."):].replace(".", "_") + "_ms": v
+                   for k, v in ms.items()},
+                "report_ms": report_ms, **self._iter_work})
+            if self._compiled_at != self.iteration:
+                # a first call compiles: the ring has it as a compile
+                # event, and it is no spike of either kind
+                self.recorder.check_step_time(
+                    step_time_s + report_ms * 1e-3,
+                    kind="chunk" if ran_prefill else "decode")
 
     def idle(self) -> bool:
         return not self.waiting and not self.active
 
     # -- scheduler phases ---------------------------------------------------
 
-    def _admit(self):
+    def _admit(self) -> int:
+        """Admit from the head of the queue while batch and pool allow;
+        returns how many were admitted."""
+        admitted = 0
         while self.waiting and len(self.active) < self.serve.max_batch:
             seq = self.waiting[0]
             # prefix-cache hit (PR 16): the longest chain of cached
@@ -1093,6 +1218,7 @@ class InferenceEngine:
             else:
                 seq.n_cached = 0
             self.active.append(seq)
+            admitted += 1
             record_counter("serve.admit")
             self._event("admit", seq.req.request_id)
             if not seq.generated:
@@ -1103,45 +1229,48 @@ class InferenceEngine:
             if self.tracer is not None:
                 self.tracer.admit(seq.req.request_id, time.perf_counter(),
                                   seq.n_preempted)
+        return admitted
 
-    def _prefill_chunk(self, done_out: Optional[List[_Seq]] = None) -> bool:
-        seq = next((s for s in self.active if s.state == PREFILL), None)
-        if seq is None:
-            return False
+    def _prefill_chunk(self, seq: _Seq, sp: _Phase,
+                       done_out: List[_Seq]) -> bool:
+        """One chunk of ``seq``'s prompt inside its ``serve.prefill`` span
+        ``sp``: plan (blocks, inputs), launch, wait for the logits, commit.
+        False when the pool is dry and the chunk stalls."""
         rid = seq.req.request_id
-        faults.inject("serve.prefill.before", rid=rid)
         c = self.serve.prefill_chunk
-        n_live = min(c, seq.prefill_target - seq.n_cached)
-        # graceful degradation: under pool pressure, shrink this chunk's
-        # LIVE span to the headroom the pool still has (n_live is data,
-        # not shape — same compiled step) before resorting to eviction.
-        # available_blocks counts parked cache blocks: alloc() reclaims
-        # them LRU-oldest after the free list, so caching never shrinks
-        # a chunk a cache-off engine could run whole
-        headroom = ((len(seq.blocks) + self.pool.available_blocks)
-                    * self.pool.block_size - seq.n_cached)
-        if 1 <= headroom < n_live:
-            n_live = headroom
-            record_counter("serve.prefill_shrink")
-            self._event("prefill_shrink", rid, n_live)
-        if not (self._alloc_for(seq, seq.n_cached + n_live)
-                and self._cow_span(seq, seq.n_cached, n_live)):
-            # pool dry mid-prompt: steal from the youngest decoder; if
-            # there is none, stall — decode progress will free blocks
-            if not (self._evict_one(protect=seq)
-                    and self._alloc_for(seq, seq.n_cached + n_live)
+        with self._span("serve.prefill.plan"):
+            faults.inject("serve.prefill.before", rid=rid)
+            n_live = min(c, seq.prefill_target - seq.n_cached)
+            # graceful degradation: under pool pressure, shrink this
+            # chunk's LIVE span to the headroom the pool still has (n_live
+            # is data, not shape — same compiled step) before resorting to
+            # eviction. available_blocks counts parked cache blocks:
+            # alloc() reclaims them LRU-oldest after the free list, so
+            # caching never shrinks a chunk a cache-off engine could run
+            # whole
+            headroom = ((len(seq.blocks) + self.pool.available_blocks)
+                        * self.pool.block_size - seq.n_cached)
+            if 1 <= headroom < n_live:
+                n_live = headroom
+                record_counter("serve.prefill_shrink")
+                self._event("prefill_shrink", rid, n_live)
+            if not (self._alloc_for(seq, seq.n_cached + n_live)
                     and self._cow_span(seq, seq.n_cached, n_live)):
-                return False
-        ids = np.zeros((c,), np.int32)
-        ids[:n_live] = seq.tokens[seq.n_cached:seq.n_cached + n_live]
-        table = pad_table(seq.blocks, self.serve.max_nb)
+                # pool dry mid-prompt: steal from the youngest decoder; if
+                # there is none, stall — decode progress will free blocks
+                if not (self._evict_one(protect=seq)
+                        and self._alloc_for(seq, seq.n_cached + n_live)
+                        and self._cow_span(seq, seq.n_cached, n_live)):
+                    return False
+            ids = np.zeros((c,), np.int32)
+            ids[:n_live] = seq.tokens[seq.n_cached:seq.n_cached + n_live]
+            table = pad_table(seq.blocks, self.serve.max_nb)
+        self._note_work(sp, n_live=int(n_live), chunk=c)
         key = ("prefill", c)
-        t0 = time.perf_counter()
+        failure: Optional[Exception] = None
         try:
             faults.inject("serve.prefill.poison", rid=rid)
-            with comm_span("serve.prefill",
-                           nbytes=int(n_live) * 4,
-                           site="serve.prefill"):
+            with self._launch_span("serve.prefill.launch", key) as launch:
                 if self.k_scale is None:
                     fn = self._step_fn("prefill", self._frozen)
                     logits, self.k_pool, self.v_pool = fn(
@@ -1156,66 +1285,81 @@ class InferenceEngine:
                         self.k_scale, self.v_scale,
                         jnp.asarray(table), np.int32(seq.n_cached),
                         jnp.asarray(ids), np.int32(n_live))
+            with self._span("serve.prefill.wait") as wait:
                 logits = np.asarray(logits)  # noqa: PTA006 -- deliberate sync so prefill phase timing is honest
-            faults.inject("serve.prefill.logits", rid=rid, logits=logits)
-            if self._nan_check and not bool(np.isfinite(logits).all()):
-                raise PoisonError(rid, "non-finite prefill logits")
         except Exception as e:  # noqa: BLE001 -- quarantine boundary
-            if not self._pools_alive():
-                raise  # donated pools died mid-kernel: journal recovery
-            # a prefill chunk touches exactly one request, so ANY
-            # failure here is attributable: quarantine it, keep serving
-            cause = (e.cause if isinstance(e, PoisonError)
-                     else f"prefill: {e!r}")
-            self._quarantine(seq, cause)
-            return True
-        t1 = time.perf_counter()
-        self._mark_compiled(*key, t1 - t0)
-        if self.tracer is not None:
-            self.tracer.prefill_chunk(
-                rid, t0, t1, int(n_live),
-                recompute=bool(seq.generated))
-        seq.n_cached += n_live
-        if seq.n_cached == seq.prefill_target:
-            if self.cache is not None:
-                # register the prompt's FULL blocks — wholly below
-                # prefill_target, so their bytes are immutable from here
-                # on (decode writes land at >= prefill_target). A
-                # quarantined prefill never reaches this line.
-                n_reg = seq.prefill_target // self.pool.block_size
-                if n_reg:
-                    added = self.cache.register(seq.tokens, seq.blocks,
-                                                n_reg)
-                    if added:
-                        self._event("prefix_register", rid, added)
-            if not seq.generated:
-                # fresh prompt: the final chunk's logits sample the
-                # first new token (greedy)
-                seq.tokens.append(int(logits.argmax(-1)))
-                seq.first_token_t = self._now()
-                seq.token_times.append(seq.first_token_t)
-                self._last_tokens += 1
-                self._jtoks.append((rid, seq.tokens[-1]))
-                self.slo["ttft"].record(seq.first_token_t - seq.arrival)
-            if seq.done():
-                # eos/max_new on the very first token: finish here so
-                # "done() implies finished" holds at every iteration
-                # boundary (recover() relies on the invariant)
-                self._finish_seq(seq, time.perf_counter())
-                if done_out is not None:
-                    done_out.append(seq)
-            else:
-                seq.state = RUNNING
-                if self.speculative:
-                    # bring the draft's cache up to n_cached before the
-                    # first decode iteration touches this row; covers
-                    # fresh, readmitted, recovered and prefix-hit
-                    # sequences uniformly (the draft re-prefills shared
-                    # blocks with identical bytes — pure function of
-                    # the token prefix)
-                    self._draft_prefill(seq)
-        faults.inject("serve.prefill.after", rid=rid)
+            failure = e
+        with self._span("serve.prefill.commit"):
+            if failure is None:
+                try:
+                    faults.inject("serve.prefill.logits", rid=rid,
+                                  logits=logits)
+                    if self._nan_check \
+                            and not bool(np.isfinite(logits).all()):
+                        raise PoisonError(rid, "non-finite prefill logits")
+                except Exception as e:  # noqa: BLE001 -- quarantine boundary
+                    failure = e
+            if failure is not None:
+                if not self._pools_alive():
+                    # donated pools died mid-kernel: journal recovery
+                    raise failure
+                # a prefill chunk touches exactly one request, so ANY
+                # failure here is attributable: quarantine it, keep serving
+                cause = (failure.cause if isinstance(failure, PoisonError)
+                         else f"prefill: {failure!r}")
+                self._quarantine(seq, cause)
+                return True
+            self._mark_compiled(*key, wait.t1 - launch.t0)
+            if self.tracer is not None:
+                self.tracer.prefill_chunk(
+                    rid, launch.t0, wait.t1, int(n_live),
+                    recompute=bool(seq.generated))
+            seq.n_cached += n_live
+            if seq.n_cached == seq.prefill_target:
+                self._prefill_done(seq, logits, done_out)
+            faults.inject("serve.prefill.after", rid=rid)
         return True
+
+    def _prefill_done(self, seq: _Seq, logits: np.ndarray,
+                      done_out: List[_Seq]) -> None:
+        """The prompt's last chunk has landed: register its blocks,
+        sample the first token, start decoding (or finish)."""
+        rid = seq.req.request_id
+        if self.cache is not None:
+            # register the prompt's FULL blocks — wholly below
+            # prefill_target, so their bytes are immutable from here
+            # on (decode writes land at >= prefill_target). A
+            # quarantined prefill never reaches this line.
+            n_reg = seq.prefill_target // self.pool.block_size
+            if n_reg:
+                added = self.cache.register(seq.tokens, seq.blocks, n_reg)
+                if added:
+                    self._event("prefix_register", rid, added)
+        if not seq.generated:
+            # fresh prompt: the final chunk's logits sample the
+            # first new token (greedy)
+            seq.tokens.append(int(logits.argmax(-1)))
+            seq.first_token_t = self._now()
+            seq.token_times.append(seq.first_token_t)
+            self._last_tokens += 1
+            self._jtoks.append((rid, seq.tokens[-1]))
+            self.slo["ttft"].record(seq.first_token_t - seq.arrival)
+        if seq.done():
+            # eos/max_new on the very first token: finish here so
+            # "done() implies finished" holds at every iteration
+            # boundary (recover() relies on the invariant)
+            self._finish_seq(seq, time.perf_counter())
+            done_out.append(seq)
+        else:
+            seq.state = RUNNING
+            if self.speculative:
+                # bring the draft's cache up to n_cached before the
+                # first decode iteration touches this row; covers
+                # fresh, readmitted, recovered and prefix-hit
+                # sequences uniformly (the draft re-prefills shared
+                # blocks with identical bytes — pure function of
+                # the token prefix)
+                self._draft_prefill(seq)
 
     def _draft_prefill(self, seq: _Seq):
         """Chunked prefill of ``seq``'s prompt through the DRAFT model
@@ -1226,69 +1370,72 @@ class InferenceEngine:
         fn = self._step_fn("prefill", self._draft_frozen)
         table = jnp.asarray(pad_table(seq.blocks, self.serve.max_nb))
         start, target = 0, seq.n_cached
-        t0 = time.perf_counter()
-        while start < target:
-            n_live = min(c, target - start)
-            ids = np.zeros((c,), np.int32)
-            ids[:n_live] = seq.tokens[start:start + n_live]
-            _, self.k_draft, self.v_draft = fn(
-                self.draft_params, self.k_draft, self.v_draft,
-                table, np.int32(start), jnp.asarray(ids),
-                np.int32(n_live))
-            start += n_live
-        t1 = time.perf_counter()
-        self._mark_compiled("draft_prefill", c, t1 - t0)
+        with self._launch_span("serve.draft.prefill",
+                               ("draft_prefill", c)) as sp:
+            while start < target:
+                n_live = min(c, target - start)
+                ids = np.zeros((c,), np.int32)
+                ids[:n_live] = seq.tokens[start:start + n_live]
+                _, self.k_draft, self.v_draft = fn(
+                    self.draft_params, self.k_draft, self.v_draft,
+                    table, np.int32(start), jnp.asarray(ids),
+                    np.int32(n_live))
+                start += n_live
+        self._mark_compiled("draft_prefill", c, sp.t1 - sp.t0)
         seq.draft_pos = target
-        if self.tracer is not None:
-            self.tracer.phase("draft", t0, t1, self.iteration)
 
-    def _decode_batch(self) -> List[_Seq]:
-        if self.speculative:
-            return self._decode_spec_batch()
-        # grow each row across its block boundary, evicting youngest-
-        # first when the pool runs dry (an evicted row drops out of the
-        # batch by losing RUNNING state); with nothing evictable the row
-        # stalls an iteration instead — finishing rows free its blocks
-        ready: List[_Seq] = []
-        for seq in [s for s in self.active if s.state == RUNNING]:
-            if seq.state != RUNNING:
-                continue
-            ok = (self._alloc_for(seq, seq.n_cached + 1)
-                  and self._cow_span(seq, seq.n_cached, 1))
-            while not ok and self._evict_one(protect=seq):
+    def _decode_inputs(self, rows: List[_Seq]):
+        """(rids, bucket, toks, positions, tables) of one decode launch."""
+        bucket = next(b for b in self.serve.decode_buckets
+                      if b >= len(rows))
+        toks = np.zeros((bucket,), np.int32)
+        positions = np.zeros((bucket,), np.int32)
+        tables = np.zeros((bucket, self.serve.max_nb), np.int32)
+        for i, seq in enumerate(rows):
+            toks[i] = seq.tokens[-1]
+            positions[i] = seq.n_cached
+            tables[i] = pad_table(seq.blocks, self.serve.max_nb)
+        return ([s.req.request_id for s in rows], bucket, toks, positions,
+                tables)
+
+    def _decode_batch(self, sp: _Phase) -> List[_Seq]:
+        """One token for every RUNNING sequence inside the ``serve.decode``
+        span ``sp``: plan (blocks, inputs), launch, wait for the logits,
+        commit."""
+        with self._span("serve.decode.plan"):
+            # grow each row across its block boundary, evicting youngest-
+            # first when the pool runs dry (an evicted row drops out of
+            # the batch by losing RUNNING state); with nothing evictable
+            # the row stalls an iteration instead — finishing rows free
+            # its blocks
+            ready: List[_Seq] = []
+            for seq in [s for s in self.active if s.state == RUNNING]:
+                if seq.state != RUNNING:
+                    continue
                 ok = (self._alloc_for(seq, seq.n_cached + 1)
                       and self._cow_span(seq, seq.n_cached, 1))
-            if ok:
-                ready.append(seq)
-            else:
-                record_counter("serve.decode_stall")
-        rows = [s for s in ready if s.state == RUNNING]
-        if not rows:
-            return []
-        faults.inject("serve.decode.before",
-                      rids=[s.req.request_id for s in rows])
-        logits = None
+                while not ok and self._evict_one(protect=seq):
+                    ok = (self._alloc_for(seq, seq.n_cached + 1)
+                          and self._cow_span(seq, seq.n_cached, 1))
+                if ok:
+                    ready.append(seq)
+                else:
+                    record_counter("serve.decode_stall")
+            rows = [s for s in ready if s.state == RUNNING]
+            if not rows:
+                return []
+            inputs = self._decode_inputs(rows)
+        faults.inject("serve.decode.before", rids=inputs[0])
         # re-drive loop: a PoisonError attributable to one row drops that
         # row (quarantined) and re-runs the batch without it; rows are
         # independent (disjoint blocks, per-row tables), so survivors'
         # tokens are bit-identical to a batch that never held the poison
-        while rows:
-            rids = [s.req.request_id for s in rows]
-            bucket = next(b for b in self.serve.decode_buckets
-                          if b >= len(rows))
-            toks = np.zeros((bucket,), np.int32)
-            positions = np.zeros((bucket,), np.int32)
-            tables = np.zeros((bucket, self.serve.max_nb), np.int32)
-            for i, seq in enumerate(rows):
-                toks[i] = seq.tokens[-1]
-                positions[i] = seq.n_cached
-                tables[i] = pad_table(seq.blocks, self.serve.max_nb)
+        while True:
+            rids, bucket, toks, positions, tables = inputs
             key = ("decode", bucket)
-            t0 = time.perf_counter()
             try:
                 faults.inject("serve.decode.poison", rids=rids)
-                with comm_span("serve.decode", nbytes=bucket * 4,
-                               site="serve.decode"):
+                with self._launch_span("serve.decode.launch", key) as launch:
                     if self.k_scale is None:
                         fn = self._step_fn("decode", self._frozen)
                         logits, self.k_pool, self.v_pool = fn(
@@ -1304,6 +1451,7 @@ class InferenceEngine:
                             self.k_scale, self.v_scale,
                             jnp.asarray(tables), jnp.asarray(positions),
                             jnp.asarray(toks))
+                with self._span("serve.decode.wait") as wait:
                     logits = np.asarray(logits)  # noqa: PTA006 -- step boundary: sampled tokens must reach the scheduler
                 faults.inject("serve.decode.logits", rids=rids,
                               logits=logits)
@@ -1318,48 +1466,52 @@ class InferenceEngine:
                 rows = [s for s in rows if s is not bad]
                 self._redrives += 1
                 record_counter("serve.decode_redrive")
+                if not rows:
+                    return []
+                with self._span("serve.decode.plan"):
+                    inputs = self._decode_inputs(rows)
                 continue
             break
-        if not rows:
-            return []
-        t1 = time.perf_counter()
-        self._mark_compiled(*key, t1 - t0)
-        next_tok = logits.argmax(-1)
-        live = list(enumerate(rows))
-        if self._nan_check:
-            # per-row screen: quarantine rows whose logits went
-            # non-finite; the survivors' already-computed argmax stands
-            # (rows are independent)
-            finite = np.isfinite(
-                logits[:len(rows)].reshape(len(rows), -1)).all(axis=1)
-            if not bool(finite.all()):
-                for i, seq in [p for p in live if not finite[p[0]]]:
-                    self._quarantine(seq, "non-finite decode logits")
-                live = [p for p in live if finite[p[0]]]
-        if self.tracer is not None:
-            self.tracer.decode([s.req.request_id for _, s in live],
-                               t0, t1, self.iteration)
-        self._last_tokens += len(live)
-        done = []
-        now = self._now()
-        for i, seq in live:
-            seq.n_cached += 1
-            seq.tokens.append(int(next_tok[i]))
-            self._jtoks.append((seq.req.request_id, seq.tokens[-1]))
-            if seq.first_token_t is None:
-                seq.first_token_t = now
-                self.slo["ttft"].record(now - seq.arrival)
-            elif seq.token_times:
-                self.slo["tpot"].record(now - seq.token_times[-1])
-            seq.token_times.append(now)
-            if seq.done():
-                self._finish_seq(seq, t1)
-                done.append(seq)
-        faults.inject("serve.decode.after",
-                      rids=[s.req.request_id for _, s in live])
+        self._note_work(sp, rows=len(rows), bucket=bucket)
+        with self._span("serve.decode.commit"):
+            t0, t1 = launch.t0, wait.t1
+            self._mark_compiled(*key, t1 - t0)
+            next_tok = logits.argmax(-1)
+            live = list(enumerate(rows))
+            if self._nan_check:
+                # per-row screen: quarantine rows whose logits went
+                # non-finite; the survivors' already-computed argmax
+                # stands (rows are independent)
+                finite = np.isfinite(
+                    logits[:len(rows)].reshape(len(rows), -1)).all(axis=1)
+                if not bool(finite.all()):
+                    for i, seq in [p for p in live if not finite[p[0]]]:
+                        self._quarantine(seq, "non-finite decode logits")
+                    live = [p for p in live if finite[p[0]]]
+            if self.tracer is not None:
+                self.tracer.decode([s.req.request_id for _, s in live],
+                                   t0, t1, self.iteration)
+            self._last_tokens += len(live)
+            done = []
+            now = self._now()
+            for i, seq in live:
+                seq.n_cached += 1
+                seq.tokens.append(int(next_tok[i]))
+                self._jtoks.append((seq.req.request_id, seq.tokens[-1]))
+                if seq.first_token_t is None:
+                    seq.first_token_t = now
+                    self.slo["ttft"].record(now - seq.arrival)
+                elif seq.token_times:
+                    self.slo["tpot"].record(now - seq.token_times[-1])
+                seq.token_times.append(now)
+                if seq.done():
+                    self._finish_seq(seq, t1)
+                    done.append(seq)
+            faults.inject("serve.decode.after",
+                          rids=[s.req.request_id for _, s in live])
         return done
 
-    def _decode_spec_batch(self) -> List[_Seq]:
+    def _decode_spec_batch(self, sp: _Phase) -> List[_Seq]:
         """Speculative decode iteration: up to K host-chained DRAFT
         steps propose lookahead tokens per RUNNING row, then ONE batched
         base-model verification pass scores all K+1 positions through
@@ -1372,40 +1524,41 @@ class InferenceEngine:
         bit-identical to sequential decode (PARITY.md) and the journal
         only ever sees verified tokens."""
         K = self.draft_k
-        # per-row lookahead cap: never past max_new (admission's worst-
-        # case bound) or the table width; floor 1 means the degenerate
-        # row still advances one token — the verify path IS the decode
-        # path, one uniform program family
-        ready: List[_Seq] = []
-        caps: Dict[int, int] = {}
-        for seq in [s for s in self.active if s.state == RUNNING]:
-            if seq.state != RUNNING:
-                continue
-            remaining = seq.req.max_new_tokens - len(seq.generated)
-            t_cap = max(1, min(K + 1, remaining,
-                               self.serve.max_seq_len - seq.n_cached))
-            ok = (self._alloc_for(seq, seq.n_cached + t_cap)
-                  and self._cow_span(seq, seq.n_cached, t_cap))
-            # shrink the lookahead before evicting anyone: in-flight
-            # draft tokens are free to drop (they cost accept-rate,
-            # never correctness)
-            while not ok and t_cap > 1:
-                t_cap -= 1
-                record_counter("serve.spec_shrink")
+        with self._span("serve.decode.plan"):
+            # per-row lookahead cap: never past max_new (admission's worst-
+            # case bound) or the table width; floor 1 means the degenerate
+            # row still advances one token — the verify path IS the decode
+            # path, one uniform program family
+            ready: List[_Seq] = []
+            caps: Dict[int, int] = {}
+            for seq in [s for s in self.active if s.state == RUNNING]:
+                if seq.state != RUNNING:
+                    continue
+                remaining = seq.req.max_new_tokens - len(seq.generated)
+                t_cap = max(1, min(K + 1, remaining,
+                                   self.serve.max_seq_len - seq.n_cached))
                 ok = (self._alloc_for(seq, seq.n_cached + t_cap)
                       and self._cow_span(seq, seq.n_cached, t_cap))
-            while not ok and self._evict_one(protect=seq):
-                t_cap = 1
-                ok = (self._alloc_for(seq, seq.n_cached + 1)
-                      and self._cow_span(seq, seq.n_cached, 1))
-            if ok:
-                ready.append(seq)
-                caps[seq.req.request_id] = t_cap
-            else:
-                record_counter("serve.decode_stall")
-        rows = [s for s in ready if s.state == RUNNING]
-        if not rows:
-            return []
+                # shrink the lookahead before evicting anyone: in-flight
+                # draft tokens are free to drop (they cost accept-rate,
+                # never correctness)
+                while not ok and t_cap > 1:
+                    t_cap -= 1
+                    record_counter("serve.spec_shrink")
+                    ok = (self._alloc_for(seq, seq.n_cached + t_cap)
+                          and self._cow_span(seq, seq.n_cached, t_cap))
+                while not ok and self._evict_one(protect=seq):
+                    t_cap = 1
+                    ok = (self._alloc_for(seq, seq.n_cached + 1)
+                          and self._cow_span(seq, seq.n_cached, 1))
+                if ok:
+                    ready.append(seq)
+                    caps[seq.req.request_id] = t_cap
+                else:
+                    record_counter("serve.decode_stall")
+            rows = [s for s in ready if s.state == RUNNING]
+            if not rows:
+                return []
         faults.inject("serve.decode.before",
                       rids=[s.req.request_id for s in rows])
         # -- draft phase: K batched single-token steps, host-chained.
@@ -1415,161 +1568,161 @@ class InferenceEngine:
         # step for a row feeds tokens[-1] — identical to what verify
         # feeds as fed[:, 0] — so catch-up and proposal steps are the
         # same compiled program.
-        t0d = time.perf_counter()
         proposals: Dict[int, List[int]] = {}
         last_out: Dict[int, int] = {}
-        drafted = False
         bucket = next(b for b in self.serve.decode_buckets
                       if b >= len(rows))
-        for _ in range(K):
-            toks = np.zeros((bucket,), np.int32)
-            positions = np.zeros((bucket,), np.int32)
-            tables = np.zeros((bucket, self.serve.max_nb), np.int32)
-            stepping = []
-            for i, seq in enumerate(rows):
-                rid = seq.req.request_id
-                if seq.draft_pos >= seq.n_cached + caps[rid] - 1:
-                    continue  # window proposed through: padding row
-                p = seq.draft_pos
-                toks[i] = (seq.tokens[p] if p < len(seq.tokens)
-                           else last_out[rid])
-                positions[i] = p
-                tables[i] = pad_table(seq.blocks, self.serve.max_nb)
-                stepping.append((i, seq))
-            if not stepping:
-                break
-            td0 = time.perf_counter()
-            fn = self._step_fn("decode", self._draft_frozen)
-            dl, self.k_draft, self.v_draft = fn(
-                self.draft_params, self.k_draft, self.v_draft,
-                jnp.asarray(tables), jnp.asarray(positions),
-                jnp.asarray(toks))
-            dl = np.asarray(dl)  # noqa: PTA006 -- host-chained: each draft argmax feeds the next draft step
-            self._mark_compiled("draft", bucket,
-                                time.perf_counter() - td0)
-            drafted = True
-            nxt = dl.argmax(-1)
-            for i, seq in stepping:
-                rid = seq.req.request_id
-                seq.draft_pos += 1
-                last_out[rid] = int(nxt[i])
-                if seq.draft_pos > seq.n_cached:
-                    proposals.setdefault(rid, []).append(int(nxt[i]))
-        t1d = time.perf_counter()
-        if drafted and self.tracer is not None:
-            self.tracer.phase("draft", t0d, t1d, self.iteration)
+        with self._span("serve.draft", rows=len(rows), bucket=bucket, k=K):
+            for _ in range(K):
+                toks = np.zeros((bucket,), np.int32)
+                positions = np.zeros((bucket,), np.int32)
+                tables = np.zeros((bucket, self.serve.max_nb), np.int32)
+                stepping = []
+                for i, seq in enumerate(rows):
+                    rid = seq.req.request_id
+                    if seq.draft_pos >= seq.n_cached + caps[rid] - 1:
+                        continue  # window proposed through: padding row
+                    p = seq.draft_pos
+                    toks[i] = (seq.tokens[p] if p < len(seq.tokens)
+                               else last_out[rid])
+                    positions[i] = p
+                    tables[i] = pad_table(seq.blocks, self.serve.max_nb)
+                    stepping.append((i, seq))
+                if not stepping:
+                    break
+                with self._launch_span("serve.draft.launch",
+                                       ("draft", bucket)) as launch:
+                    fn = self._step_fn("decode", self._draft_frozen)
+                    dl, self.k_draft, self.v_draft = fn(
+                        self.draft_params, self.k_draft, self.v_draft,
+                        jnp.asarray(tables), jnp.asarray(positions),
+                        jnp.asarray(toks))
+                with self._span("serve.draft.wait") as wait:
+                    dl = np.asarray(dl)  # noqa: PTA006 -- host-chained: each draft argmax feeds the next draft step
+                self._mark_compiled("draft", bucket, wait.t1 - launch.t0)
+                nxt = dl.argmax(-1)
+                for i, seq in stepping:
+                    rid = seq.req.request_id
+                    seq.draft_pos += 1
+                    last_out[rid] = int(nxt[i])
+                    if seq.draft_pos > seq.n_cached:
+                        proposals.setdefault(rid, []).append(int(nxt[i]))
         # -- verify phase: one batched K+1-position base pass; the
         # re-drive loop mirrors sequential decode's (rows independent)
         T = K + 1
         out = clen = fin = None
         key = None
-        while rows:
-            rids = [s.req.request_id for s in rows]
-            bucket = next(b for b in self.serve.decode_buckets
-                          if b >= len(rows))
-            fed = np.zeros((bucket, T), np.int32)
-            qstart = np.zeros((bucket,), np.int32)
-            t_live = np.zeros((bucket,), np.int32)
-            tables = np.zeros((bucket, self.serve.max_nb), np.int32)
-            for i, seq in enumerate(rows):
+        with self._span("serve.verify", k=K) as verify:
+            while rows:
+                rids = [s.req.request_id for s in rows]
+                bucket = next(b for b in self.serve.decode_buckets
+                              if b >= len(rows))
+                fed = np.zeros((bucket, T), np.int32)
+                qstart = np.zeros((bucket,), np.int32)
+                t_live = np.zeros((bucket,), np.int32)
+                tables = np.zeros((bucket, self.serve.max_nb), np.int32)
+                for i, seq in enumerate(rows):
+                    rid = seq.req.request_id
+                    props = proposals.get(rid, [])[:caps[rid] - 1]
+                    fed[i, 0] = seq.tokens[-1]
+                    fed[i, 1:1 + len(props)] = props
+                    qstart[i] = seq.n_cached
+                    t_live[i] = 1 + len(props)
+                    tables[i] = pad_table(seq.blocks, self.serve.max_nb)
+                key = ("verify", bucket)
+                try:
+                    faults.inject("serve.decode.poison", rids=rids)
+                    with self._launch_span("serve.verify.launch",
+                                           key) as launch:
+                        if self.k_scale is None:
+                            fn = self._step_fn("verify", self._frozen)
+                            (out, clen, fin, self.k_pool,
+                             self.v_pool) = fn(
+                                self.params, self.k_pool, self.v_pool,
+                                jnp.asarray(tables), jnp.asarray(qstart),
+                                jnp.asarray(t_live), jnp.asarray(fed))
+                        else:
+                            fn = self._step_fn("verify", self._frozen,
+                                               quant=True)
+                            (out, clen, fin, self.k_pool, self.v_pool,
+                             self.k_scale, self.v_scale) = fn(
+                                self.params, self.k_pool, self.v_pool,
+                                self.k_scale, self.v_scale,
+                                jnp.asarray(tables), jnp.asarray(qstart),
+                                jnp.asarray(t_live), jnp.asarray(fed))
+                    with self._span("serve.verify.wait") as wait:
+                        out = np.asarray(out)  # noqa: PTA006 -- step boundary: verified tokens must reach the scheduler
+                        clen = np.asarray(clen)  # noqa: PTA006 -- accept lengths gate the host-side commit loop
+                        fin = np.asarray(fin)  # noqa: PTA006 -- per-row finite screen read at the step boundary
+                    faults.inject("serve.decode.logits", rids=rids,
+                                  logits=out)
+                except PoisonError as e:
+                    if not self._pools_alive():
+                        raise  # donated pools died mid-kernel: journal path
+                    bad = next((s for s in rows
+                                if s.req.request_id == e.rid), None)
+                    if bad is None:
+                        raise  # not attributable to this batch
+                    self._quarantine(bad, e.cause)
+                    rows = [s for s in rows if s is not bad]
+                    self._redrives += 1
+                    record_counter("serve.decode_redrive")
+                    continue
+                break
+            if not rows:
+                return []
+            verify.note(rows=len(rows), bucket=bucket)
+        self._note_work(sp, rows=len(rows), bucket=bucket)
+        with self._span("serve.decode.commit"):
+            t0, t1 = launch.t0, wait.t1
+            self._mark_compiled(*key, t1 - t0)
+            live = list(enumerate(rows))
+            if self._nan_check:
+                # the verify step returns tokens, not logits, so the
+                # finite screen is computed inside the jit and surfaced
+                # per row
+                finite = fin[:len(rows)]
+                if not bool(finite.all()):
+                    for i, seq in [p for p in live if not finite[p[0]]]:
+                        self._quarantine(seq, "non-finite decode logits")
+                    live = [p for p in live if finite[p[0]]]
+            if self.tracer is not None:
+                self.tracer.decode([s.req.request_id for _, s in live],
+                                   t0, t1, self.iteration)
+            done: List[_Seq] = []
+            now = self._now()
+            for i, seq in live:
                 rid = seq.req.request_id
-                props = proposals.get(rid, [])[:caps[rid] - 1]
-                fed[i, 0] = seq.tokens[-1]
-                fed[i, 1:1 + len(props)] = props
-                qstart[i] = seq.n_cached
-                t_live[i] = 1 + len(props)
-                tables[i] = pad_table(seq.blocks, self.serve.max_nb)
-            key = ("verify", bucket)
-            t0 = time.perf_counter()
-            try:
-                faults.inject("serve.decode.poison", rids=rids)
-                with comm_span("serve.verify", nbytes=bucket * T * 4,
-                               site="serve.verify"):
-                    if self.k_scale is None:
-                        fn = self._step_fn("verify", self._frozen)
-                        (out, clen, fin, self.k_pool,
-                         self.v_pool) = fn(
-                            self.params, self.k_pool, self.v_pool,
-                            jnp.asarray(tables), jnp.asarray(qstart),
-                            jnp.asarray(t_live), jnp.asarray(fed))
-                    else:
-                        fn = self._step_fn("verify", self._frozen,
-                                           quant=True)
-                        (out, clen, fin, self.k_pool, self.v_pool,
-                         self.k_scale, self.v_scale) = fn(
-                            self.params, self.k_pool, self.v_pool,
-                            self.k_scale, self.v_scale,
-                            jnp.asarray(tables), jnp.asarray(qstart),
-                            jnp.asarray(t_live), jnp.asarray(fed))
-                    out = np.asarray(out)  # noqa: PTA006 -- step boundary: verified tokens must reach the scheduler
-                    clen = np.asarray(clen)  # noqa: PTA006 -- accept lengths gate the host-side commit loop
-                    fin = np.asarray(fin)  # noqa: PTA006 -- per-row finite screen read at the step boundary
-                faults.inject("serve.decode.logits", rids=rids,
-                              logits=out)
-            except PoisonError as e:
-                if not self._pools_alive():
-                    raise  # donated pools died mid-kernel: journal path
-                bad = next((s for s in rows
-                            if s.req.request_id == e.rid), None)
-                if bad is None:
-                    raise  # not attributable to this batch
-                self._quarantine(bad, e.cause)
-                rows = [s for s in rows if s is not bad]
-                self._redrives += 1
-                record_counter("serve.decode_redrive")
-                continue
-            break
-        if not rows:
-            return []
-        t1 = time.perf_counter()
-        self._mark_compiled(*key, t1 - t0)
-        live = list(enumerate(rows))
-        if self._nan_check:
-            # the verify step returns tokens, not logits, so the finite
-            # screen is computed inside the jit and surfaced per row
-            finite = fin[:len(rows)]
-            if not bool(finite.all()):
-                for i, seq in [p for p in live if not finite[p[0]]]:
-                    self._quarantine(seq, "non-finite decode logits")
-                live = [p for p in live if finite[p[0]]]
-        if self.tracer is not None:
-            self.tracer.decode([s.req.request_id for _, s in live],
-                               t0, t1, self.iteration)
-            self.tracer.phase("verify", t0, t1, self.iteration)
-        done: List[_Seq] = []
-        now = self._now()
-        for i, seq in live:
-            rid = seq.req.request_id
-            self._spec_proposed += int(t_live[i]) - 1
-            # accepted draft credit = commit_len - 1: the +1 is the
-            # base's own correction/next token, not the draft's
-            self._spec_accepted += max(0, int(clen[i]) - 1)
-            emitted = 0
-            for j in range(int(clen[i])):
-                seq.n_cached += 1
-                seq.tokens.append(int(out[i, j]))
-                self._jtoks.append((rid, seq.tokens[-1]))
-                emitted += 1
-                if seq.first_token_t is None:
-                    seq.first_token_t = now
-                    self.slo["ttft"].record(now - seq.arrival)
-                elif seq.token_times:
-                    self.slo["tpot"].record(now - seq.token_times[-1])
-                seq.token_times.append(now)
+                self._spec_proposed += int(t_live[i]) - 1
+                # accepted draft credit = commit_len - 1: the +1 is the
+                # base's own correction/next token, not the draft's
+                self._spec_accepted += max(0, int(clen[i]) - 1)
+                emitted = 0
+                for j in range(int(clen[i])):
+                    seq.n_cached += 1
+                    seq.tokens.append(int(out[i, j]))
+                    self._jtoks.append((rid, seq.tokens[-1]))
+                    emitted += 1
+                    if seq.first_token_t is None:
+                        seq.first_token_t = now
+                        self.slo["ttft"].record(now - seq.arrival)
+                    elif seq.token_times:
+                        self.slo["tpot"].record(now - seq.token_times[-1])
+                    seq.token_times.append(now)
+                    if seq.done():
+                        # eos/max_new inside the window: later verified
+                        # tokens are exactly what sequential decode would
+                        # have produced AFTER stopping — discard them
+                        break
+                self._last_tokens += emitted
+                # roll the draft back to the last verified position: its
+                # cache past the accepted prefix reflects rejected tokens
+                seq.draft_pos = min(seq.draft_pos, seq.n_cached)
                 if seq.done():
-                    # eos/max_new inside the window: later verified
-                    # tokens are exactly what sequential decode would
-                    # have produced AFTER stopping — discard them
-                    break
-            self._last_tokens += emitted
-            # roll the draft back to the last verified position: its
-            # cache past the accepted prefix reflects rejected tokens
-            seq.draft_pos = min(seq.draft_pos, seq.n_cached)
-            if seq.done():
-                self._finish_seq(seq, t1)
-                done.append(seq)
-        faults.inject("serve.decode.after",
-                      rids=[s.req.request_id for _, s in live])
+                    self._finish_seq(seq, t1)
+                    done.append(seq)
+            faults.inject("serve.decode.after",
+                          rids=[s.req.request_id for _, s in live])
         return done
 
     # -- preemption + live weight push (PR 13) ------------------------------

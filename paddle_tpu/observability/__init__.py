@@ -3,10 +3,15 @@
 Grown from the profiler stub in the spirit of XLA's xplane/TensorBoard
 pipeline, in three layers (PR 2 + PR 12):
 
-1. **Trace attribution** — ``comm_span`` names every overlap site in the
-   HLO metadata, counters tally static structure, and ``RequestTracer``
-   gives every serving request a span tree (queue wait, prefill chunks,
-   decode iterations, evictions) exported as JSONL / Chrome trace JSON
+1. **Trace attribution** — ``span(name, **args)`` is a host span on the
+   profiler's own clock for code that runs on the host every step (the
+   serving engine's ``serve.*`` phases: a ``TraceAnnotation`` and nothing
+   else, recorded while a profiler session runs); ``comm_span`` is for
+   collective sites inside traced programs only (it names the site in
+   the HLO metadata and tallies static counters at trace time); and
+   ``RequestTracer`` gives every serving request a span tree (queue
+   wait, prefill chunks, decode iterations, evictions) beside the
+   engine's phases, exported as JSONL / Chrome trace JSON
    (``write_chrome_trace``, shared with the profiler) for Perfetto.
 2. **Streaming metrics** — ``StepMetrics`` collects wall step time,
    compile time, tokens/sec, device memory and MFU with zero host syncs
@@ -15,7 +20,8 @@ pipeline, in three layers (PR 2 + PR 12):
    ``render_prometheus`` for scraping.
 3. **Failure flight recorder** — ``FlightRecorder`` rings the last N
    iteration/step records and dumps them to ``PADDLE_TPU_TELEMETRY_DIR``
-   on exception, eviction storm, or MAD step-time spike.
+   on exception, eviction storm, or MAD step-time spike (one window per
+   kind of step: a serving iteration with a prefill chunk, without).
 4. **Fleet view** (PR 15) — ``MetricsRegistry`` is the single Prometheus
    exposition every surface registers into; ``FleetMonitor`` aggregates
    per-rank step times, per-``site=`` comm_span hop stats and all-device
@@ -55,4 +61,4 @@ from .registry import MetricsRegistry  # noqa: F401
 from .request_trace import RequestTracer  # noqa: F401
 from .trace import (ENV_TELEMETRY, ENV_TELEMETRY_DIR, comm_span,  # noqa: F401
                     counters, overlap_flags, record_counter, reset_counters,
-                    set_counter, telemetry_dir, telemetry_enabled)
+                    set_counter, span, telemetry_dir, telemetry_enabled)

@@ -12,13 +12,21 @@ when something goes wrong:
 - **eviction storm** — eviction rate over a sliding window crosses
   ``STORM_RATE`` (a thrashing pool: requests recompute more than they
   decode);
-- **step-time spike** — a step lands ``spike_mad`` robust sigmas from
-  the window median (MAD × 1.4826 ≈ σ under normality), the classic
-  sign of a recompile, host stall, or preemption hiccup.
+- **step-time spike** — a step lands ``spike_mad`` robust sigmas ABOVE
+  the median of the window of ITS KIND (MAD × 1.4826 ≈ σ under
+  normality), the classic sign of a host stall or preemption hiccup. The
+  caller names the kind: a serving iteration that ran a prefill chunk
+  takes four times as long as one that only decoded, so the engine keeps
+  the two apart and a chunk iteration is no spike among decodes. A step
+  that is quicker than its kind (a chunk iteration with no row to
+  decode) is no stall and fires nothing.
 
 Each trigger dumps at most once per recorder (a storm would otherwise
-write a file per iteration). Everything here is host-side Python over
-values already on the host — no device syncs.
+write a file per iteration), except the spike, which writes its file
+anew for each of the first ``SPIKE_DUMPS`` spikes: every anomaly carries
+the record of the iteration at fault, so the last file holds them all.
+Everything here is host-side Python over values already on the host —
+no device syncs.
 """
 from __future__ import annotations
 
@@ -33,7 +41,7 @@ from .exporters import _jsonable
 from .trace import telemetry_dir
 
 __all__ = ["FlightRecorder", "flight_recorder_enabled", "STORM_WINDOW",
-           "STORM_RATE", "MIN_SPIKE_SAMPLES"]
+           "STORM_RATE", "MIN_SPIKE_SAMPLES", "SPIKE_DUMPS"]
 
 ENV_FLIGHT_RECORDER = "PADDLE_TPU_FLIGHT_RECORDER"
 ENV_FLIGHT_RECORDER_SIZE = "PADDLE_TPU_FLIGHT_RECORDER_SIZE"
@@ -52,6 +60,9 @@ _MAD_SIGMA = 1.4826  # MAD -> sigma under normality
 # the recorder's entire per-iteration cost. A suspected spike always
 # refits fresh before firing, so stale stats never cause a false dump.
 _SPIKE_REFIT_EVERY = 16
+# A run with a handful of stalls should leave all of them on disk; one
+# that spikes all the time should not write a file per step.
+SPIKE_DUMPS = 8
 
 
 def flight_recorder_enabled(explicit: Optional[bool] = None) -> bool:
@@ -59,6 +70,34 @@ def flight_recorder_enabled(explicit: Optional[bool] = None) -> bool:
     if explicit is not None:
         return bool(explicit)
     return envs.get(ENV_FLIGHT_RECORDER)
+
+
+class _SpikeWindow:
+    """The recent step times of one kind, with their cached median/MAD."""
+
+    __slots__ = ("times", "med", "sigma", "since_refit")
+
+    def __init__(self, size: int):
+        self.times: collections.deque = collections.deque(maxlen=size)
+        self.med: Optional[float] = None
+        self.sigma = 0.0
+        self.since_refit = 0
+
+    def refit(self) -> None:
+        """Recompute the median/MAD (excluding the sample just appended,
+        so a spike never masks itself)."""
+        xs = list(self.times)
+        xs.pop()
+        self.med = _median(xs)
+        self.sigma = _MAD_SIGMA * _median([abs(x - self.med) for x in xs])
+        self.since_refit = 0
+
+    def is_spike(self, v: float, spike_mad: float) -> bool:
+        if self.sigma <= 0:
+            # degenerate window (identical times, e.g. mocked clocks):
+            # fall back to a pure multiple-of-median test
+            return v > self.med * spike_mad
+        return v - self.med > spike_mad * self.sigma
 
 
 def _median(xs: List[float]) -> float:
@@ -74,7 +113,7 @@ class FlightRecorder:
     >>> rec = FlightRecorder(source="engine")
     >>> rec.record({"iteration": i, "queue_depth": q, ...})
     >>> rec.note_eviction(iteration=i)           # on each preemption
-    >>> rec.check_step_time(step_time_s)          # MAD spike detector
+    >>> rec.check_step_time(step_time_s, kind)    # MAD spike detector
     >>> rec.dump("exception")                     # on crash, then re-raise
     """
 
@@ -88,12 +127,9 @@ class FlightRecorder:
                                else envs.get(ENV_SPIKE_MAD))
         self.out_dir = out_dir
         self.ring: collections.deque = collections.deque(maxlen=self.size)
-        self._step_times: collections.deque = collections.deque(
-            maxlen=self.size)
+        self._windows: Dict[str, _SpikeWindow] = {}   # by kind of step
         self._evictions: collections.deque = collections.deque()
-        self._spike_med: Optional[float] = None
-        self._spike_sigma = 0.0
-        self._since_refit = 0
+        self._spikes = 0
         self._iteration = 0
         self.dumped: List[str] = []          # paths written this run
         self._fired: set = set()             # one dump per trigger kind
@@ -128,50 +164,41 @@ class FlightRecorder:
             return self.dump("eviction_storm")
         return None
 
-    def _refit_spike(self) -> None:
-        """Recompute the cached window median/MAD (excluding the sample
-        just appended, so a spike never masks itself)."""
-        xs = list(self._step_times)
-        xs.pop()
-        med = _median(xs)
-        mad = _median([abs(x - med) for x in xs])
-        self._spike_med = med
-        self._spike_sigma = _MAD_SIGMA * mad
-        self._since_refit = 0
-
-    def _is_spike(self, v: float) -> bool:
-        med, sigma = self._spike_med, self._spike_sigma
-        if sigma <= 0:
-            # degenerate window (identical times, e.g. mocked clocks):
-            # fall back to a pure multiple-of-median test
-            return v > med * self.spike_mad
-        return abs(v - med) > self.spike_mad * sigma
-
-    def check_step_time(self, step_time_s: float) -> Optional[str]:
-        """MAD-based spike detector over the recent step-time window.
-        Returns the dump path when a spike fires, else None."""
-        prior = len(self._step_times)
-        self._step_times.append(float(step_time_s))
+    def check_step_time(self, step_time_s: float,
+                        kind: str = "") -> Optional[str]:
+        """MAD-based spike detector over the recent step times of one
+        ``kind`` (each kind has its own window). Returns the dump path
+        when a spike fires, else None."""
+        w = self._windows.get(kind)
+        if w is None:
+            w = self._windows[kind] = _SpikeWindow(self.size)
+        prior = len(w.times)
+        w.times.append(float(step_time_s))
         if prior < MIN_SPIKE_SAMPLES:
             return None
-        self._since_refit += 1
-        if self._spike_med is None or self._since_refit >= _SPIKE_REFIT_EVERY:
-            self._refit_spike()
-        if not self._is_spike(step_time_s):
+        w.since_refit += 1
+        if w.med is None or w.since_refit >= _SPIKE_REFIT_EVERY:
+            w.refit()
+        if not w.is_spike(step_time_s, self.spike_mad):
             return None
-        if self._since_refit:
+        if w.since_refit:
             # suspected against stale stats: refit fresh and retest before
             # committing to a dump
-            self._refit_spike()
-            if not self._is_spike(step_time_s):
+            w.refit()
+            if not w.is_spike(step_time_s, self.spike_mad):
                 return None
         self.anomalies.append({
             "kind": "step_time_spike", "iteration": self._iteration,
-            "step_time_s": float(step_time_s), "median_s": self._spike_med,
-            "mad_s": self._spike_sigma / _MAD_SIGMA,
+            "step_kind": kind,
+            "step_time_s": float(step_time_s), "median_s": w.med,
+            "mad_s": w.sigma / _MAD_SIGMA,
             "threshold_mads": self.spike_mad,
+            # the step at fault: callers record a step, then check it
+            "record": self.ring[-1] if self.ring else None,
         })
-        return self.dump("step_time_spike")
+        self._spikes += 1
+        return self.dump("step_time_spike",
+                         force=self._spikes <= SPIKE_DUMPS)
 
     # -- dumping --------------------------------------------------------------
 

@@ -4,10 +4,12 @@ Every engine request gets a trace id and a span tree — queue wait,
 admission, each chunked-prefill slice, each decode iteration it
 participated in, eviction and re-prefill recompute — recorded entirely
 host-side. The engine hands the tracer ``time.perf_counter()`` values it
-ALREADY captures at iteration boundaries (``step()``'s phase clocks), so
-tracing adds no device syncs and no new clock reads on the hot path, and
-never feeds back into scheduling: deterministic replay produces
-bit-identical tokens with tracing on or off (pinned by test).
+ALREADY captures at its phase boundaries (the same call sites that emit
+the ``serve.*`` profiler annotations, under the same names: see
+``InferenceEngine._span``), so tracing adds no device syncs and no new
+clock reads on the hot path, and never feeds back into scheduling:
+deterministic replay produces bit-identical tokens with tracing on or
+off (pinned by test).
 
 The hot path appends one tuple per event — a decode batch is a SINGLE
 tuple carrying the participating rids, expanded to per-request spans
@@ -21,8 +23,10 @@ Exports:
 - ``export_jsonl(path)`` — one span per line for programmatic analysis;
 - ``export_chrome(path)`` — Chrome trace-event JSON through the same
   writer the profiler uses (``exporters.write_chrome_trace``), laid out
-  so Perfetto renders one row per engine phase (admit/prefill/decode)
-  and one row per request, with eviction as instant markers.
+  so Perfetto renders one row per top-level engine phase (``serve.step``,
+  ``.admit``, ``.prefill``, ``.decode``, ``.report``, ``.submit``; a
+  child such as ``serve.decode.launch`` nests on its parent's row) and
+  one row per request, with eviction as instant markers.
 """
 from __future__ import annotations
 
@@ -32,8 +36,10 @@ from .exporters import JsonlWriter, write_chrome_trace
 
 __all__ = ["RequestTracer", "PHASE_TIDS", "REQUEST_TID_BASE"]
 
-# Perfetto row layout: engine phases on low tids, requests on 10+rid.
-PHASE_TIDS = {"admit": 0, "prefill": 1, "decode": 2}
+# Perfetto row layout: engine phases on low tids, requests on 10+rid. A
+# phase that has no row of its own lies on its parent's.
+PHASE_TIDS = {"serve.step": 0, "serve.admit": 1, "serve.prefill": 2,
+              "serve.decode": 3, "serve.report": 4, "serve.submit": 5}
 REQUEST_TID_BASE = 10
 
 
@@ -123,14 +129,17 @@ class RequestTracer:
         self._span(rid, "quarantine", "quarantine", t, t, {"cause": cause})
         self._chunk_idx.pop(rid, None)
 
-    def phase(self, name: str, t0: float, t1: float, iteration: int) -> None:
-        """Engine-phase span (admit/prefill/decode) for one iteration.
-        Inlined append — called up to three times per iteration."""
+    def phase(self, name: str, t0: float, t1: float, iteration: int,
+              parent: Optional[str] = None) -> None:
+        """Engine-phase span for one iteration, under the name of the
+        profiler annotation the same call site emits; ``parent`` is the
+        phase it lies inside. Inlined append — called at every phase
+        boundary of an iteration."""
         if t1 > t0:
             if self._epoch is None or t0 < self._epoch:
                 self._epoch = t0
             self._spans.append((None, name, "phase", t0, t1,
-                                {"iteration": iteration}))
+                                {"iteration": iteration, "parent": parent}))
 
     # -- materialization -------------------------------------------------------
 
@@ -220,10 +229,15 @@ class RequestTracer:
             events.append({"name": "thread_name", "ph": "M", "pid": 0,
                            "tid": REQUEST_TID_BASE + rid,
                            "args": {"name": f"request {rid}"}})
+        parents = {s[1]: s[5]["parent"] for s in self._spans
+                   if s[0] is None}
         for s in sorted(self._iter_dicts(),
                         key=lambda s: (s["t0"], s["t1"])):
             if s["rid"] is None:
-                tid = PHASE_TIDS.get(s["name"], PHASE_TIDS["decode"])
+                row = s["name"]
+                while row not in PHASE_TIDS and parents.get(row):
+                    row = parents[row]
+                tid = PHASE_TIDS.get(row, PHASE_TIDS["serve.step"])
             else:
                 tid = REQUEST_TID_BASE + s["rid"]
             ev = {"name": s["name"], "ts": self._rel(s["t0"]) * 1e6,

@@ -1,15 +1,25 @@
 """Trace attribution primitives for the hybrid-parallel hot path.
 
-Two mechanisms, both free at step time:
+Three mechanisms:
 
-- ``comm_span(name)`` — a context manager entered while a collective site is
-  being TRACED into a jitted program. It pushes a ``jax.named_scope`` (the
-  name lands in the HLO op metadata, so XLA's xplane profile attributes the
-  device time of that ppermute/psum to the span name in TensorBoard/Perfetto)
-  plus a host ``jax.profiler.TraceAnnotation`` so tracing itself shows up in
-  host timelines. No code runs per executed step.
+- ``span(name, **args)`` — a host span on the profiler's own clock, for
+  code that runs on the host EVERY step (the serving engine's scheduler
+  phases). It is a ``jax.profiler.TraceAnnotation`` and nothing else: it
+  lands in the profiler's ``.xplane.pb`` beside the device's "XLA Ops"
+  line, with ``args`` as event stats, while a profiler session runs, and
+  costs a fraction of a microsecond while none does. The session is the
+  switch; there is no other.
 
-- counters — a process-global tally the spans (and planners) bump at trace
+- ``comm_span(name)`` — for collective sites INSIDE traced programs only:
+  a context manager entered while the site is being TRACED into a jitted
+  program. It pushes a ``jax.named_scope`` (the name lands in the HLO op
+  metadata, so XLA's xplane profile attributes the device time of that
+  ppermute/psum to the span name in TensorBoard/Perfetto) plus a host
+  ``jax.profiler.TraceAnnotation`` so tracing itself shows up in host
+  timelines. No code runs per executed step; on a per-step host path its
+  locked counters and named scope are the wrong tool — use ``span``.
+
+- counters — a process-global tally ``comm_span`` (and planners) bump at trace
   time: ppermute hop counts, grad-sync bucket bytes, overlap on/off. Because
   instrumented code runs when a program is traced, counters are STATIC
   attribution of the compiled step (like HLO op counts), not execution
@@ -72,20 +82,28 @@ def reset_counters() -> None:
         _counters.clear()
 
 
+def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """A host span in the profiler's trace: ``with span("serve.step",
+    iteration=3) as sp: ...``. ``args`` become the event's stats;
+    ``sp.set_metadata(rows=3)`` adds those known only later. No named
+    scope, no counter, no lock: free to leave on a per-step path."""
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
 @contextlib.contextmanager
 def comm_span(name: str, nbytes: Optional[int] = None,
               site: Optional[str] = None):
     """Attribute a collective site: named HLO scope + host trace annotation +
-    ``{name}.calls`` / ``{name}.bytes`` counters. Safe inside jit/shard_map/
-    scan tracing (where it tallies once per trace) and in eager host code.
+    ``{name}.calls`` / ``{name}.bytes`` counters. For code being traced into
+    a jit/shard_map/scan (where it tallies once per trace); host code that
+    runs every step takes ``span``.
 
     ``site=`` is the STABLE straggler-attribution key (PR 15): unlike
     ``name`` — often per-instance, e.g. ``grad_sync.bucket07`` — the site
     label is a static string shared by every instance of one collective
     family, tallied as ``site.<site>.{calls,bytes,ms}`` counters so the
     FleetMonitor can compare the same site across ranks. The ``.ms``
-    tally is host time inside the span (trace time under jit; wall time
-    at eager sites like the serve prefill/decode dispatch)."""
+    tally is host time inside the span (trace time under jit)."""
     record_counter(name + ".calls", 1)
     if nbytes is not None:
         record_counter(name + ".bytes", int(nbytes))

@@ -276,6 +276,11 @@ def _legacy_engine_dict(eng):
         "failed_requests": len(eng.failed),
         "decode_redrives": eng._redrives,
         "generated_tokens": sum(len(s.generated) for s in eng.finished),
+        # the useful-over-attempted counters of the phase spans
+        "decode_rows_total": eng.work_totals["decode_rows_total"],
+        "decode_slots_total": eng.work_totals["decode_slots_total"],
+        "prefill_tokens_total": eng.work_totals["prefill_tokens_total"],
+        "prefill_slots_total": eng.work_totals["prefill_slots_total"],
     }
 
 

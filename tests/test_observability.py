@@ -438,6 +438,60 @@ def test_flight_recorder_spike_fires_and_dumps(tmp_path):
     assert obs.load_dump(path)["anomalies"][0]["kind"] == "step_time_spike"
 
 
+def _chat_like(n, seed=0):
+    """(kind, seconds) of a serving run: decode-only iterations of about
+    17 ms, every fifth one with a prefill chunk at about 76 ms."""
+    rng = np.random.RandomState(seed)
+    return [("chunk", 0.076 + 0.002 * rng.rand()) if i % 5 == 4
+            else ("decode", 0.017 + 0.001 * rng.rand()) for i in range(n)]
+
+
+def test_flight_recorder_one_window_per_kind_of_step(tmp_path):
+    """A bimodal series is two quiet series: a chunk iteration among
+    decodes is no spike, a decode or a chunk 110 ms over its kind is."""
+    series = _chat_like(200)
+    one = obs.FlightRecorder(source="one")
+    assert any(one.check_step_time(s) is not None or one.anomalies
+               for _, s in series), "one window: the first chunk fires"
+    rec = obs.FlightRecorder(source="t", out_dir=str(tmp_path))
+    for i, (kind, s) in enumerate(series, 1):
+        rec.record({"iteration": i, "step_time_s": s})
+        assert rec.check_step_time(s, kind=kind) is None
+    assert rec.anomalies == []
+    rec.record({"iteration": 201, "step_time_s": 0.076})
+    assert rec.check_step_time(0.076, kind="decode") is not None
+    rec.record({"iteration": 202, "step_time_s": 0.131, "decode_wait_ms": 125})
+    assert rec.check_step_time(0.131, kind="decode") is not None
+    rec.record({"iteration": 203, "step_time_s": 0.190})
+    assert rec.check_step_time(0.190, kind="chunk") is not None
+    assert rec.check_step_time(0.078, kind="chunk") is None
+    # quicker than its kind (a chunk with no row to decode) is no stall
+    assert rec.check_step_time(0.063, kind="chunk") is None
+    kinds = [(a["step_kind"], a["step_time_s"]) for a in rec.anomalies]
+    assert kinds == [("decode", 0.076), ("decode", 0.131), ("chunk", 0.190)]
+    # an anomaly carries the record of the step at fault
+    assert [a["record"]["iteration"] for a in rec.anomalies] \
+        == [201, 202, 203]
+    assert rec.anomalies[1]["record"]["decode_wait_ms"] == 125
+    assert rec.anomalies[1]["median_s"] == pytest.approx(0.0175, abs=1e-3)
+    assert rec.anomalies[2]["median_s"] == pytest.approx(0.077, abs=2e-3)
+
+
+def test_flight_recorder_spike_file_holds_the_first_few(tmp_path):
+    """The spike's file is written anew for each of the first
+    ``SPIKE_DUMPS`` spikes and then left alone."""
+    from paddle_tpu.observability.flight_recorder import SPIKE_DUMPS
+    rec = obs.FlightRecorder(source="t", out_dir=str(tmp_path))
+    for kind, s in _chat_like(100):
+        rec.check_step_time(s, kind=kind)
+    paths = [rec.check_step_time(0.131, kind="decode")
+             for _ in range(SPIKE_DUMPS + 3)]
+    assert all(paths[:SPIKE_DUMPS]) and not any(paths[SPIKE_DUMPS:])
+    assert len(set(paths[:SPIKE_DUMPS])) == 1
+    assert len(obs.load_dump(paths[0])["anomalies"]) == SPIKE_DUMPS
+    assert len(rec.anomalies) == SPIKE_DUMPS + 3
+
+
 def test_flight_recorder_eviction_storm(tmp_path):
     rec = obs.FlightRecorder(source="t", out_dir=str(tmp_path))
     paths = [rec.note_eviction(i) for i in range(1, 41)]

@@ -237,7 +237,9 @@ def test_request_tracer_export_matches_chrome_schema(tmp_path):
     tr.submit(0, 0.0)
     tr.admit(0, 0.5)
     tr.prefill_chunk(0, 0.5, 0.8, n_tokens=32, recompute=False)
-    tr.phase("prefill", 0.5, 0.8, iteration=0)
+    tr.phase("serve.prefill", 0.5, 0.8, iteration=0)
+    tr.phase("serve.prefill.wait", 0.6, 0.8, iteration=0,
+             parent="serve.prefill")
     tr.decode([0], 1.0, 1.1, iteration=1)
     tr.evict(0, 1.2, n_preempted=1)
     tr.admit(0, 1.5, n_preempted=1)
@@ -250,4 +252,10 @@ def test_request_tracer_export_matches_chrome_schema(tmp_path):
     assert {"M", "X", "i"} <= phs
     rows = {e["args"]["name"] for e in data["traceEvents"]
             if e["name"] == "thread_name"}
-    assert "request 0" in rows and "engine/prefill" in rows
+    assert "request 0" in rows and "engine/serve.prefill" in rows
+    # a phase with no row of its own lies on its parent's
+    from paddle_tpu.observability.request_trace import PHASE_TIDS
+    tids = {e["name"]: e["tid"] for e in data["traceEvents"]
+            if e["ph"] == "X" and e.get("cat") == "phase"}
+    assert tids["serve.prefill.wait"] == tids["serve.prefill"] \
+        == PHASE_TIDS["serve.prefill"]

@@ -301,8 +301,10 @@ def test_trace_exports_jsonl_and_chrome(traced_evict_run, tmp_path):
     data = json.load(open(cp))
     names = {e["args"]["name"] for e in data["traceEvents"]
              if e["name"] == "thread_name"}
-    assert {"engine/admit", "engine/prefill", "engine/decode",
-            "request 0", "request 1", "request 2"} <= names
+    assert {"engine/serve.step", "engine/serve.admit",
+            "engine/serve.prefill", "engine/serve.decode",
+            "engine/serve.report", "request 0", "request 1",
+            "request 2"} <= names
     evs = [e for e in data["traceEvents"] if e["ph"] == "X"]
     assert evs and all(e["dur"] >= 0 for e in evs)
 
