@@ -47,7 +47,8 @@ _DTYPE_SIZES = {
 FITTER_PREFIX = "_fit"
 REGISTERED_FITTERS = frozenset({"_fit_block_t", "_fit_bwd_flat_blocks",
                                "_fit_paged_kv_blocks",
-                               "_fit_paged_verify_blocks"})
+                               "_fit_paged_verify_blocks",
+                               "_fit_paged_prefill_blocks"})
 
 
 def _is_fitter(name):

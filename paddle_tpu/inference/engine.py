@@ -10,7 +10,9 @@ compiled step family per bucketed shape —
     paged-attention update kernel (ops/paged_attention.py);
   - ``paged_prefill_chunk`` at the fixed chunk bucket: one slice of
     ONE admitted prompt, interleaved with the decode batches so long
-    prompts never head-of-line-block token generation.
+    prompts never head-of-line-block token generation; its attention
+    walks the live blocks of the sequence's table only
+    (``paged_prefill_attention``).
 
 Recompiles are therefore bounded by ``len(decode_buckets) + 1`` and
 counted (``serve.compile.*`` counters + StepMetrics.record_compile).
@@ -339,7 +341,9 @@ class _Phase:
 # on the span: the registry counter it adds to
 _WORK_TOTALS = {"rows": "decode_rows_total", "bucket": "decode_slots_total",
                 "n_live": "prefill_tokens_total",
-                "chunk": "prefill_slots_total"}
+                "chunk": "prefill_slots_total",
+                "ctx_blocks": "prefill_ctx_blocks_total",
+                "table_blocks": "prefill_table_blocks_total"}
 
 
 class InferenceEngine:
@@ -648,7 +652,8 @@ class InferenceEngine:
                 help="tokens generated by finished requests")
         # useful over attempted, counted where the phase spans are: weights
         # stream for ``slots`` to serve ``rows``, a chunk's program runs
-        # ``slots`` positions to cache ``tokens``. Monotonic.
+        # ``slots`` positions to cache ``tokens``, its attention walks
+        # ``ctx_blocks`` live blocks of a table of ``table_blocks``. Monotonic.
         for name, what in (
                 ("decode_rows_total", "sequences advanced by decode steps"),
                 ("decode_slots_total", "batch slots (the bucket) of the "
@@ -656,7 +661,11 @@ class InferenceEngine:
                 ("prefill_tokens_total", "prompt tokens cached by prefill "
                                          "chunks"),
                 ("prefill_slots_total", "token slots (the chunk) of the "
-                                        "prefill chunks run")):
+                                        "prefill chunks run"),
+                ("prefill_ctx_blocks_total", "live KV blocks the prefill "
+                                             "chunks' attention walked"),
+                ("prefill_table_blocks_total", "block-table slots of the "
+                                               "prefill chunks run")):
             r.gauge(name, fn=lambda n=name: self.work_totals[n], help=what)
         # PR 16 capacity gauges, only when the cache is live: the
         # default exposition stays byte-compatible with the pre-PR-15
@@ -1265,7 +1274,11 @@ class InferenceEngine:
             ids = np.zeros((c,), np.int32)
             ids[:n_live] = seq.tokens[seq.n_cached:seq.n_cached + n_live]
             table = pad_table(seq.blocks, self.serve.max_nb)
-        self._note_work(sp, n_live=int(n_live), chunk=c)
+        # the chunk's attention walks ctx_blocks of the table's table_blocks
+        self._note_work(
+            sp, n_live=int(n_live), chunk=c,
+            ctx_blocks=self.pool.blocks_for(seq.n_cached + int(n_live)),
+            table_blocks=self.serve.max_nb)
         key = ("prefill", c)
         failure: Optional[Exception] = None
         try:

@@ -1192,20 +1192,6 @@ def _pool_write_chunk(pool, layer, bids, fresh, tiles):
     return pool
 
 
-def _pool_read_blocks(pool, layer, table_row):
-    """One sequence's blocks of one layer, contiguous along time:
-    pool [L, NP, W, bs], table_row [max_nb] -> [W, max_nb * bs]. One
-    dynamic-slice per table slot: a gather (``pool[layer][table_row]``)
-    reads the same bytes, but the layout XLA's TPU backend wants for the
-    gathered slab is pushed back onto the pool operand, so the whole pool
-    is copied to that layout first (see _pool_write_chunk)."""
-    z = jnp.int32(0)
-    return jnp.concatenate(
-        [lax.dynamic_slice(pool, (layer, table_row[i], z, z),
-                           (1, 1) + pool.shape[2:])[0, 0]
-         for i in range(table_row.shape[0])], axis=1)
-
-
 def llama_paged_prefill_chunk(params, k_pool, v_pool, table_row, start,
                               ids, n_live, config: LlamaConfig,
                               kv_scales=None, tp=None):
@@ -1213,23 +1199,25 @@ def llama_paged_prefill_chunk(params, k_pool, v_pool, table_row, start,
     to the chunk bucket, n_live (traced) real tokens, start (traced) =
     tokens already cached from earlier chunks. Scatters the chunk's KV
     into the sequence's blocks (padding tokens land in null block 0),
-    attends each chunk token over cached-prefix + chunk causally via
-    the gathered-context XLA path, and returns the logits of the LAST
-    REAL token ([vocab] f32 — only meaningful on the final chunk) plus
-    the updated pools.
+    attends each chunk token over cached-prefix + chunk causally by
+    walking the sequence's LIVE blocks through the block table
+    (ops.paged_attention.paged_prefill_attention: the pools are read back
+    after the write, so the chunk's own columns come from there too), and
+    returns the logits of the LAST REAL token ([vocab] f32 — only
+    meaningful on the final chunk) plus the updated pools.
 
     With ``kv_scales=(k_scale, v_scale)`` the pools are int8: each
     column quantizes per-kv-head via kv_quant_columns before the
     scatter (one scale per column — bytes independent of chunk
-    boundaries) and the context gather dequantizes. Returns
+    boundaries) and the attention dequantizes each block tile. Returns
     (logits, k_pool, v_pool, k_scale, v_scale) in that mode."""
-    from ..ops.paged_attention import kv_quant_columns
+    from ..ops.paged_attention import (kv_quant_columns,
+                                       paged_prefill_attention)
     c = config
     C = ids.shape[0]
     hd = c.head_dim
     bs = k_pool.shape[-1]
     max_nb = table_row.shape[0]
-    T = max_nb * bs
     if tp is None:
         h = jnp.take(params["embed"], ids, axis=0)[None].astype(c.dtype)
     else:
@@ -1290,47 +1278,24 @@ def llama_paged_prefill_chunk(params, k_pool, v_pool, table_row, start,
         kvd = nkv * hd
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        # scatter the chunk's KV columns into their blocks ([C]-indexed
-        # rows over the [NP, KVD, bs] pool slab: one scatter per layer)
+        # the chunk's KV columns land in their blocks first; the attention
+        # then reads prefix and chunk alike from the pools
         if kv_scales is None:
             kp = _pool_write_chunk(kp, layer, wbid, fresh, window(
                 k.reshape(C, kvd).astype(kp.dtype)))
             vp = _pool_write_chunk(vp, layer, wbid, fresh, window(
                 v.reshape(C, kvd).astype(vp.dtype)))
-            # gather the sequence's context (prefix + this chunk) back
-            # to a contiguous slab; dead table slots read null-block
-            # garbage that the causal mask kills
-            kctx = _pool_read_blocks(kp, layer, table_row)
-            vctx = _pool_read_blocks(vp, layer, table_row)
+            scales = None
         else:
-            nkv_ = kvd // hd
-            kq, ksq = kv_quant_columns(k.reshape(C, kvd), nkv_)
-            vq, vsq = kv_quant_columns(v.reshape(C, kvd), nkv_)
+            kq, ksq = kv_quant_columns(k.reshape(C, kvd), nkv)
+            vq, vsq = kv_quant_columns(v.reshape(C, kvd), nkv)
             kp = _pool_write_chunk(kp, layer, wbid, fresh, window(kq))
             vp = _pool_write_chunk(vp, layer, wbid, fresh, window(vq))
             ksc = _pool_write_chunk(ksc, layer, wbid, fresh, window(ksq))
             vsc = _pool_write_chunk(vsc, layer, wbid, fresh, window(vsq))
-
-            def dequant_ctx(pool, scales):
-                q = _pool_read_blocks(pool, layer, table_row)     # [KVD,T]
-                sc = _pool_read_blocks(scales, layer, table_row)  # [NKV,T]
-                return (q.astype(jnp.float32).reshape(nkv_, hd, T)
-                        * sc[:, None, :]).reshape(kvd, T).astype(c.dtype)
-
-            kctx = dequant_ctx(kp, ksc)
-            vctx = dequant_ctx(vp, vsc)
-        rep = nh // nkv
-        qg = q[0].reshape(C, nkv, rep, hd)
-        kg = kctx.reshape(nkv, hd, T)
-        vg = vctx.reshape(nkv, hd, T)
-        s = jnp.einsum("cgrd,gdt->cgrt", qg, kg,
-                       preferred_element_type=jnp.float32) / (hd ** 0.5)
-        t = jnp.arange(T, dtype=jnp.int32)
-        s = jnp.where((t[None, :] <= pidx[:, None])[:, None, None, :],
-                      s, -1e30)
-        probs = jax.nn.softmax(s, axis=-1).astype(vg.dtype)
-        attn = jnp.einsum("cgrt,gdt->cgrd", probs, vg,
-                          preferred_element_type=jnp.float32).astype(c.dtype)
+            scales = (ksc, vsc)
+        attn = paged_prefill_attention(q[0], kp, vp, table_row, start,
+                                       n_live, layer, kv_scales=scales)
         ao = attn.reshape(1, C, nh * hd)
         attn_out = (_mat(ao, p["o_proj"]) if tp is None
                     else _tp_o_proj(ao, p["o_proj"], tp))

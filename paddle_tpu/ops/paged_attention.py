@@ -27,6 +27,10 @@ so the newest block is always the sequence's last live tile, the new
 column is merged there, and that step's scores read the just-written
 tile back from the aliased out refs.
 
+paged_prefill_attention (PR 29) is the chunked prefill's read of the same
+pools: one sequence, a chunk of queries, causal, a grid of (query tile,
+table slot) bounded by the live context; see its section below.
+
 Layouts:
   q_bd    [B, NH, KVD]          pre-scaled block-diagonal queries
   pools   [L, NP, KVD, bs]      k and v block pools, time in lanes
@@ -795,6 +799,225 @@ def paged_attention_xla(q, k_pool, v_pool, tables, lengths, layer,
     s = jnp.where(t < lengths[:, None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bht,bct->bhc", p, vc.astype(jnp.float32))
+
+
+# -- chunked prefill (PR 29) --------------------------------------------------
+#
+# One sequence's prefill chunk attends its LIVE context through the block
+# table: queries [C, NH*HD] at positions start .. start + C, keys and values
+# read from the pools after the chunk's own columns have landed there. The
+# grid is (query tile, table slot). A tile of block_q query tokens walks the
+# blocks up to its own causal frontier, all kv heads a step (the k/v window
+# is the pool's whole [KVD, bs] block tile, as in the decode kernels), with
+# the rep = NH / NKV query heads of a kv head stacked into the rows of one
+# [rep * block_q, HD] x [HD, bs] product. Running max / sum / accumulator
+# stay in VMEM: no score reaches HBM. Slots past a tile's frontier re-present
+# its last live block (no DMA) and skip their compute; a tile of padding
+# queries alone (past n_live) visits nothing and writes zeros.
+
+# tile sched row indices ([2, n_tiles] i32 scalar prefetch)
+_TQ0, _TNBLK = range(2)
+
+PREFILL_BLOCK_Q = 128
+PREFILL_VMEM_LIMIT = 32 * 1024 * 1024
+
+
+def _fit_paged_prefill_blocks(c, nh, hd, nkv, bs, itemsize):
+    """Query tile of the prefill attention (PTA002 contract): the largest
+    divisor of the chunk ``c`` that is at most PREFILL_BLOCK_Q and a whole
+    number of sublane tiles (or the chunk itself), priced with its
+    double-buffered windows (q and out tiles, k/v block tiles, scale tiles)
+    and its scratch (accumulator, running max and sum for every head)
+    against the kernel's VMEM limit; fails at trace time where they could
+    not fit. Under tensor-parallel serving nh/nkv are the rank's own."""
+    tq = next((d for d in range(min(c, PREFILL_BLOCK_Q), 0, -1)
+               if c % d == 0 and d % 16 == 0), c)
+    kvd = nkv * hd
+    win = (2 * 2 * tq * nh * hd * 4               # q + out tiles (f32-priced)
+           + 2 * 2 * kvd * bs * itemsize          # k/v tiles
+           + 2 * 2 * nkv * bs * 4                 # scale tiles (quant path)
+           + nh * tq * (hd + 2 * 128) * 4)        # acc/m/l scratch
+    if win > PREFILL_VMEM_LIMIT:
+        raise ValueError(
+            f"paged prefill kernel windows need {win} B VMEM "
+            f"(> {PREFILL_VMEM_LIMIT} B): shrink prefill_chunk, block_size "
+            f"or heads")
+    return tq
+
+
+def paged_prefill_schedule(table_row, start, n_live, n_tiles, block_q,
+                           block_size):
+    """(blk [n_tiles, max_nb], tiles [2, n_tiles]) i32 for one
+    chunk: tile i holds the queries at positions q0 = start + i * block_q
+    onward and walks table slots 0 .. nblk - 1, up to the block of its last
+    live query (nblk = 0 for a tile wholly past n_live). blk[i, j] is the
+    pool block that step presents: the slot's own while j < nblk, then the
+    last live one again, so a dead step moves no data. Pure jnp on traced
+    values; the same for every layer of the chunk."""
+    max_nb = table_row.shape[0]
+    bs, tq = jnp.int32(block_size), jnp.int32(block_q)
+    start = jnp.asarray(start, jnp.int32)
+    n_live = jnp.asarray(n_live, jnp.int32)
+    i = jnp.arange(n_tiles, dtype=jnp.int32)
+    q0 = start + i * tq
+    last = jnp.minimum(q0 + tq, start + n_live) - 1
+    nblk = jnp.where(i * tq < n_live, last // bs + 1, jnp.int32(0))
+    j = jnp.arange(max_nb, dtype=jnp.int32)
+    slot = jnp.clip(jnp.minimum(j[None, :], nblk[:, None] - 1), 0,
+                    max_nb - 1)
+    return table_row.astype(jnp.int32)[slot], jnp.stack([q0, nblk])
+
+
+def _paged_prefill_kernel(lp_ref, blk_ref, tl_ref, q_ref, k_ref, v_ref,
+                          *rest, block_size, nkv, sm_scale, quant):
+    """Online-softmax chain of one (query tile, table slot) step over all
+    kv heads. int8 pools differ only in dequantising the head's tile (to the
+    queries' dtype, as the dense path's gather did)."""
+    ks_ref, vs_ref = rest[:-4] if quant else (None, None)
+    o_ref, m_s, l_s, acc_s = rest[-4:]
+    i, j = pl.program_id(0), pl.program_id(1)
+    q0 = tl_ref[_TQ0, i]
+    nblk = tl_ref[_TNBLK, i]
+    tq = q_ref.shape[0]
+    hd = k_ref.shape[2] // nkv
+    rep = q_ref.shape[1] // (nkv * hd)
+    bs = block_size
+
+    @pl.when(j == 0)
+    def _init():
+        m_s[...] = jnp.full(m_s.shape, -1e30, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    def tile(ref, s_ref, g):
+        t = ref[0, 0, g * hd:(g + 1) * hd, :]
+        if quant:
+            t = (t.astype(jnp.float32) * s_ref[0, 0, g:g + 1, :]) \
+                .astype(q_ref.dtype)
+        return t
+
+    def attend(masked):
+        if masked:
+            # row r of a head group is query r % tq of the tile
+            qpos = q0 + jnp.concatenate(
+                [lax.broadcasted_iota(jnp.int32, (tq, bs), 0)] * rep, axis=0)
+            t = j * bs + lax.broadcasted_iota(jnp.int32, (rep * tq, bs), 1)
+            keep = t <= qpos
+        for g in range(nkv):
+            qg = jnp.concatenate(
+                [q_ref[:, (g * rep + h) * hd:(g * rep + h + 1) * hd]
+                 for h in range(rep)], axis=0)             # [rep*tq, hd]
+            s = jax.lax.dot_general(
+                qg, tile(k_ref, ks_ref, g),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            if masked:
+                s = jnp.where(keep, s, jnp.float32(-1e30))
+            m_prev = m_s[g, :, :1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp2(m_prev - m_new)
+            p = jnp.exp2(s - m_new)
+            v_g = tile(v_ref, vs_ref, g)
+            m_s[g] = jnp.broadcast_to(m_new, m_s.shape[1:])
+            l_s[g] = l_s[g] * alpha + jnp.broadcast_to(
+                p.sum(axis=-1, keepdims=True), l_s.shape[1:])
+            acc_s[g] = acc_s[g] * alpha + jax.lax.dot_general(
+                p.astype(v_g.dtype), v_g, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)        # [rep*tq, hd]
+
+    live = j < nblk
+    # every column of the block is at or before the tile's first query
+    whole = (j + 1) * bs - 1 <= q0
+
+    @pl.when(jnp.logical_and(live, whole))
+    def _below():
+        attend(False)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(whole)))
+    def _frontier():
+        attend(True)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _fin():
+        for g in range(nkv):
+            o = acc_s[g] / jnp.maximum(l_s[g, :, :1], jnp.float32(1e-30))
+            for h in range(rep):
+                o_ref[:, (g * rep + h) * hd:(g * rep + h + 1) * hd] = \
+                    o[h * tq:(h + 1) * tq].astype(o_ref.dtype)
+
+
+def paged_prefill_attention(q, k_pool, v_pool, table_row, start, n_live,
+                            layer, *, kv_scales=None):
+    """Causal attention of one sequence's prefill chunk over its live
+    context, read from the pools through the block table.
+
+    q [C, NH, HD] UNSCALED, the queries of the chunk's tokens at positions
+    start .. start + C; pools [L, NP, KVD, bs] ALREADY holding the chunk's
+    own columns; table_row [max_nb] i32; start, n_live traced scalars
+    (n_live >= 1 real tokens, the rest padding). With
+    ``kv_scales=(k_scale, v_scale)`` ([L, NP, NKV, bs] f32) the pools are
+    int8. Returns [C, NH, HD] in q's dtype; rows past n_live are finite and
+    meaningless. Softmax statistics are f32, the probabilities are cast to
+    the values' dtype before PV, and the work is that of the live blocks:
+    ceil((start + n_live) / bs) at most, fewer for the earlier tiles."""
+    c, nh, hd = q.shape
+    L, NP, kvd, bs = k_pool.shape
+    max_nb = table_row.shape[0]
+    nkv = kvd // hd          # from the shapes: under TP the rank's own
+    quant = kv_scales is not None
+    it = jnp.dtype(k_pool.dtype).itemsize
+    tq = _fit_paged_prefill_blocks(c, nh, hd, nkv, bs, it)
+    n_tiles = c // tq
+    rows = (nh // nkv) * tq
+    blk, tiles = paged_prefill_schedule(table_row, start, n_live, n_tiles,
+                                        tq, bs)
+    lp = jnp.asarray([layer], jnp.int32)
+
+    def kv_map(i, j, lp_ref, blk_ref, tl_ref):
+        return (lp_ref[0], blk_ref[i, j], 0, 0)
+
+    def q_map(i, j, lp_ref, blk_ref, tl_ref):
+        return (i, 0)
+
+    pool_specs = [pl.BlockSpec((1, 1, kvd, bs), kv_map)] * 2
+    if quant:
+        pool_specs += [pl.BlockSpec((1, 1, nkv, bs), kv_map)] * 2
+    kernel = functools.partial(
+        _paged_prefill_kernel, block_size=bs, nkv=nkv,
+        sm_scale=_LOG2E / (hd ** 0.5), quant=quant)
+    steps = n_tiles * max_nb
+    with _mosaic_ctx():
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(n_tiles, max_nb),
+                in_specs=[pl.BlockSpec((tq, nh * hd), q_map)] + pool_specs,
+                out_specs=pl.BlockSpec((tq, nh * hd), q_map),
+                scratch_shapes=[
+                    pltpu.VMEM((nkv, rows, 128), jnp.float32),
+                    pltpu.VMEM((nkv, rows, 128), jnp.float32),
+                    pltpu.VMEM((nkv, rows, hd), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((c, nh * hd), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=PREFILL_VMEM_LIMIT),
+            # the whole table's steps, as the decode kernels price theirs;
+            # a chunk runs its live ones
+            cost_estimate=_cost_estimate(
+                flops=4 * nh * tq * hd * bs * steps,
+                transcendentals=nh * tq * bs * steps,
+                bytes_accessed=((2 * kvd * bs * it
+                                 + (2 * nkv * bs * 4 if quant else 0))
+                                * steps
+                                + 2 * c * nh * hd * q.dtype.itemsize),
+                name="paged.prefill_attention"),
+            interpret=_interpret(),
+        )(lp, blk, tiles, q.reshape(c, nh * hd), k_pool, v_pool,
+          *(kv_scales or ()))
+    return out.reshape(c, nh, hd)
 
 
 # -- speculative verification (PR 18) -----------------------------------------

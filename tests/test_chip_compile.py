@@ -85,7 +85,7 @@ def gib(n):
     return n / 2 ** 30
 
 
-# -- the eight paged kernels, the four repaired ones first --------------------
+# -- the ten paged kernels, the four repaired ones first ----------------------
 
 @pytest.fixture(scope="module")
 def paged_args(sds):
@@ -103,6 +103,8 @@ def paged_args(sds):
     tables, lens, layer = (sds((BATCH, MAX_NB), i32), sds((BATCH,), i32),
                            sds((), i32))
     kp, kq = pool[False], pool[True]
+    q_chunk = sds((FULL.prefill_chunk, NH, KVD // NKV), bf16)
+    chunk_at = (sds((MAX_NB,), i32), sds((), i32), sds((), i32), layer)
     return {
         "attend_update": (pa.paged_attend_update, (
             q, col[False], col[False], kp, kp, tables, lens, layer)),
@@ -121,13 +123,20 @@ def paged_args(sds):
             q_fed, kp, kp, tables, lens, layer)),
         "attention_verify_quant": (pa.paged_attention_verify_quant, (
             q_fed, kq, kq, scale, scale, tables, lens, layer)),
+        "prefill_attention": (pa.paged_prefill_attention, (
+            q_chunk, kp, kp, *chunk_at)),
+        "prefill_attention_quant": (
+            lambda q, k, v, ks, vs, *at: pa.paged_prefill_attention(
+                q, k, v, *at, kv_scales=(ks, vs)),
+            (q_chunk, kq, kq, scale, scale, *chunk_at)),
     }
 
 
 @pytest.mark.parametrize("name", [
     "attend_update", "attend_update_quant", "verify_commit",
     "verify_commit_quant", "attention", "attention_quant",
-    "attention_verify", "attention_verify_quant"])
+    "attention_verify", "attention_verify_quant", "prefill_attention",
+    "prefill_attention_quant"])
 def test_paged_kernel_compiles(paged_args, name):
     fn, args = paged_args[name]
     assert "tpu_custom_call" in compile_for_chip(fn, *args).as_text()
@@ -177,6 +186,40 @@ def test_engine_step_compiles_with_a_pool_half_of_hbm(serve_args, sds, kind,
     assert gib(ma.temp_size_in_bytes) < 1.0, (
         f"{gib(ma.temp_size_in_bytes):.2f} GiB of temporaries beside a "
         f"{gib(pool_bytes):.2f} GiB pool: something pool-sized is copied")
+    if kind == "prefill":
+        # the chunk's scores stay in VMEM: the dense path held them against
+        # every slot of the table as f32 [chunk, heads, max_seq_len] in HBM
+        scores = FULL.prefill_chunk * NH * FULL.max_seq_len * 4
+        assert ma.temp_size_in_bytes < scores / 2, (
+            f"{gib(ma.temp_size_in_bytes):.2f} GiB of temporaries: room for "
+            f"the {gib(scores):.2f} GiB of scores against the whole table")
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+def test_tp_prefill_compiles_on_four_chips(topo, quant):
+    """The tensor-parallel prefill programs run the same attention inside
+    their shard_map island, on the rank's own heads (8 of 32, 2 of 8 kv)."""
+    mesh = jax.sharding.Mesh(np.array(topo.devices).reshape(4), ("mp",))
+    config = dataclasses.replace(L.llama_7b(), num_hidden_layers=2,
+                                 num_key_value_heads=8)
+    pspecs, _ = L._tp_specs(config, mesh)
+    on = lambda shape, dtype, spec=P(): jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=NamedSharding(mesh, spec))
+    params = jax.tree_util.tree_map(
+        lambda a, s: on(a.shape, a.dtype, s),
+        jax.eval_shape(lambda: L.init_llama_params(config, 0)), pspecs,
+        is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+    pool = on((2, POOL_BLOCKS, 8 * 128, BS), i8 if quant else bf16,
+              L._TP_POOL_SPEC)
+    scale = on((2, POOL_BLOCKS, 8, BS), f32, L._TP_POOL_SPEC)
+    fn = (L._jitted_paged_prefill_quant_tp if quant
+          else L._jitted_paged_prefill_tp)(L._freeze_config(config), mesh)
+    compiled = compile_for_chip(
+        fn, params, *((pool, pool, scale, scale) if quant else (pool, pool)),
+        on((MAX_NB,), i32), on((), i32), on((FULL.prefill_chunk,), i32),
+        on((), i32))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert gib(compiled.memory_analysis().temp_size_in_bytes) < 0.1
 
 
 # -- dense flash attention and the train step ---------------------------------

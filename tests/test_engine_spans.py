@@ -180,9 +180,17 @@ def test_counts_on_the_spans_add_up_to_the_registry(runs, config):
     assert snap["prefill_tokens_total"] == sum(c["n_live"] for c in chunks) \
         == 7 + 130 + 20
     assert snap["prefill_slots_total"] == 64 * len(chunks)
+    # blocks of 128 in a table of 512 / 128: the 130-token prompt's chunks
+    # walk 1, 1 and 2 live blocks of the 4 a whole-table walk would visit
+    assert [(c["ctx_blocks"], c["table_blocks"]) for c in chunks
+            if c["rid"] == 1] == [(1, 4), (1, 4), (2, 4)]
+    assert snap["prefill_ctx_blocks_total"] \
+        == sum(c["ctx_blocks"] for c in chunks) == 1 + 4 + 1
+    assert snap["prefill_table_blocks_total"] == 4 * len(chunks)
     prom = eng.render_prometheus()
     for name in ("decode_rows_total", "decode_slots_total",
-                 "prefill_tokens_total", "prefill_slots_total"):
+                 "prefill_tokens_total", "prefill_slots_total",
+                 "prefill_ctx_blocks_total", "prefill_table_blocks_total"):
         assert f"paddle_tpu_serve_{name} {snap[name]}" in prom
 
 

@@ -281,6 +281,10 @@ def _legacy_engine_dict(eng):
         "decode_slots_total": eng.work_totals["decode_slots_total"],
         "prefill_tokens_total": eng.work_totals["prefill_tokens_total"],
         "prefill_slots_total": eng.work_totals["prefill_slots_total"],
+        "prefill_ctx_blocks_total":
+            eng.work_totals["prefill_ctx_blocks_total"],
+        "prefill_table_blocks_total":
+            eng.work_totals["prefill_table_blocks_total"],
     }
 
 
