@@ -128,6 +128,8 @@ class Tracer:
         # on the driver's clock (serving): when the trace was asked for,
         # when it ran from and to
         self.asked = self.c0 = self.c1 = None
+        # the program's own registry as the traced part began and ended
+        self.registry0 = self.registry1 = None
 
     def start(self) -> None:
         import jax

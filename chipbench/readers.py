@@ -35,7 +35,9 @@ class Facts:
     peak: dict
     events: List[trace.Event]      # the device trace, reduced to tuples
     traced_s: float                # host seconds of the traced window
-    counters: Dict[str, Any]       # the harness's own counts and spans
+    # the harness's own counts, the family's counters for its kernels and
+    # the change of the program's own registry over the traced part
+    counters: Dict[str, Any]
 
 
 def load_extensions() -> None:

@@ -1,15 +1,17 @@
-"""The plain reference: the decoder in straightforward ``jax.numpy`` and
-float32 at ``highest`` matmul precision, with no kernels, no cache, no
-paging and no batching tricks. It imports nothing of the program and takes
-nothing the program made: its weights are the benchmark's, made again from
-the seed, held in the type the configuration states (bf16) and raised to
-float32 layer by layer as they are used.
+"""The plain reference, the part every family shares: straightforward
+``jax.numpy`` in float32 at ``highest`` matmul precision, with no kernels,
+no cache, no paging and no batching tricks. It imports nothing of the
+program and takes nothing the program made: its weights are the
+benchmark's, made again from the seed, held in the type the configuration
+states (bf16) and raised to float32 as they are used. A family
+(``families/<name>.py``) holds its layers' equations and its leaf names and
+builds its forward pass and its trainer from what is here.
 
-Equations (Yi-1.5 and Mistral-7B share them): pre-norm decoder; RMSNorm
-``x * rsqrt(mean(x^2) + eps) * w``; rotary embedding in the half-rotation
-(NeoX) layout at base ``rope_theta``; grouped-query causal attention scaled
-by ``1/sqrt(head_dim)`` (query head ``h`` reads KV head ``h // (heads /
-kv_heads)``); SwiGLU ``down(silu(gate(x)) * up(x))``; untied head.
+Here: RMSNorm ``x * rsqrt(mean(x^2) + eps) * w``; rotary embedding in the
+half-rotation (NeoX) layout at base ``theta``; grouped-query causal
+attention scaled by ``1/sqrt(head_dim)`` (query head ``h`` reads KV head
+``h // (heads / kv_heads)``), query rows in blocks; the head and its loss
+in rows; AdamW.
 
 ``mode`` lowers the precision for the control: every matmul operand is
 rounded to fp8 (e4m3, scaled by the tensor's largest magnitude) or bf16
@@ -30,8 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-
-from chipbench import flops
 
 F32 = jnp.float32
 HI = lax.Precision.HIGHEST
@@ -110,74 +110,28 @@ def attention(q, k, v, mode, q_block=1024):
         b, s, nh, d)
 
 
-def attn_sublayer(p, x, m, mode):
-    """x [B, S, H] -> x + attention(norm(x))."""
-    b, s, _ = x.shape
-    d = flops.head_dim(m)
-    nh, nkv = m["num_attention_heads"], m["num_key_value_heads"]
-    pos = jnp.arange(s)
-    y = rms_norm(x, p["input_norm"], m["rms_norm_eps"])
-    q = rope(_mm(y, p["q_proj"], mode).reshape(b, s, nh, d), pos,
-             m["rope_theta"])
-    k = rope(_mm(y, p["k_proj"], mode).reshape(b, s, nkv, d), pos,
-             m["rope_theta"])
-    v = _mm(y, p["v_proj"], mode).reshape(b, s, nkv, d)
-    a = attention(q, k, v, mode).reshape(b, s, nh * d)
-    return x + _mm(a, p["o_proj"], mode)
-
-
-def mlp_sublayer(p, x, m, mode):
-    y = rms_norm(x, p["post_norm"], m["rms_norm_eps"])
-    gated = jax.nn.silu(_mm(y, p["gate_proj"], mode)) \
-        * _mm(y, p["up_proj"], mode)
-    return x + _mm(gated, p["down_proj"], mode)
-
-
-ATTN_LEAVES = ("input_norm", "q_proj", "k_proj", "v_proj", "o_proj")
-MLP_LEAVES = ("post_norm", "gate_proj", "up_proj", "down_proj")
-
-
-def _pick(p, names):
-    return {n: p[n] for n in names}
-
-
-# -- forward only (serving) ----------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("m_items", "mode", "last"))
-def _logits_jit(weights, ids, start, *, m_items, mode, last):
-    m = dict(m_items)
-    x = jnp.take(weights["embed"], ids, axis=0).astype(F32)
-
-    def layer(x, p):
-        x = attn_sublayer(_pick(p, ATTN_LEAVES), x, m, mode)
-        return mlp_sublayer(_pick(p, MLP_LEAVES), x, m, mode), None
-
-    x, _ = lax.scan(layer, x, weights["layers"])
-    x = lax.dynamic_slice_in_dim(x, start, last, axis=1)
-    x = rms_norm(x, weights["final_norm"], m["rms_norm_eps"])
-    return _mm(x, weights["lm_head"], mode)[0]
-
-
 def _hashable(m):
     return tuple(sorted((k, v) for k, v in m.items()
                         if isinstance(v, (int, float, str, bool))))
 
 
-def logits_after(weights, m, tokens, last: int, padded: int, last_max: int,
-                 mode="f32"):
+def logits_after(forward, weights, m, tokens, last: int, padded: int,
+                 last_max: int, mode="f32"):
     """[last, vocab] next-token logits after each of the ``last`` final
     tokens of ``tokens``: row j scores what follows
     ``tokens[:len(tokens) - last + 1 + j]``. One sequence, padded on the
     right to ``padded`` (causal, so padding changes nothing before it).
     ``padded`` and ``last_max`` (at least ``last``) are the compiled shape,
-    one for a whole mix."""
+    one for a whole mix. ``forward`` is the family's jitted forward pass:
+    ``forward(weights, ids [1, padded], start, m_items=, mode=, last=)`` gives
+    the logits of ``last`` rows from ``start`` on."""
     n = len(tokens)
     ids = np.zeros((1, padded), np.int32)
     ids[0, :n] = tokens
     start = max(0, min(n - last, padded - last_max))
-    rows = np.asarray(_logits_jit(weights, jnp.asarray(ids), np.int32(start),
-                                  m_items=_hashable(m), mode=mode,
-                                  last=last_max))
+    rows = np.asarray(forward(weights, jnp.asarray(ids), np.int32(start),
+                              m_items=_hashable(m), mode=mode,
+                              last=last_max))
     first = n - last - start
     return rows[first:first + last]
 
@@ -204,8 +158,12 @@ def _tree_adamw(p, g, mo, vo, t, hp):
             {k: o[2] for k, o in out.items()}, sq)
 
 
-def train_programs(m, hp, mode):
-    """The jitted pieces of one reference step, by name."""
+def train_programs(m, hp, mode, sublayers, norm_eps):
+    """The jitted pieces of one reference step, by name. ``sublayers`` is
+    the family's {name: fn(p, x, m, mode)}: each gives ``<name>_fwd`` and
+    ``<name>_bwd``, the backward with its leaves' AdamW update. The embedding
+    and the head in rows (behind a final RMSNorm of ``norm_eps``) are every
+    family's."""
     mi, mode_ = _hashable(m), mode
     hp_ = dict(hp)
 
@@ -229,9 +187,6 @@ def train_programs(m, hp, mode):
             return p, mo, vo, dx, sq
         return fwd, bwd
 
-    attn_fwd, attn_bwd = sub(attn_sublayer)
-    mlp_fwd, mlp_bwd = sub(mlp_sublayer)
-
     @functools.partial(jax.jit, static_argnums=(4,))
     def head_bwd(norm_w, head, x, labels, rows):
         """x [N, H], labels [N]: the mean loss over the labelled rows, its
@@ -241,7 +196,7 @@ def train_programs(m, hp, mode):
         count = jnp.maximum(jnp.sum(labels != -100), 1).astype(F32)
 
         def loss_sum(n32, w32, xc, lc):
-            y = rms_norm(xc, n32, dict(mi)["rms_norm_eps"])
+            y = rms_norm(xc, n32, norm_eps)
             logits = _mm(y, w32, mode_)
             valid = lc != -100
             safe = jnp.where(valid, lc, 0)
@@ -272,97 +227,8 @@ def train_programs(m, hp, mode):
         flat = dx.reshape(-1, dx.shape[-1])
         return jnp.zeros((vocab, dx.shape[-1]), F32).at[
             ids.reshape(-1)].add(flat)
-    return {"embed_fwd": embed_fwd, "attn_fwd": attn_fwd, "attn_bwd": attn_bwd,
-            "mlp_fwd": mlp_fwd, "mlp_bwd": mlp_bwd, "head_bwd": head_bwd,
-            "update": update, "embed_grad": embed_grad}
-
-
-class Trainer:
-    """The reference's three steps. ``weights`` is the benchmark's tree
-    (stacked layers); it is unstacked here so that each layer's leaves can
-    be updated, and donated, alone."""
-
-    def __init__(self, weights, m, hp, mode="f32", fault=None):
-        if fault not in (None, "half_batch"):
-            raise ValueError(f"unknown fault {fault!r}")
-        self.m, self.hp, self.mode = dict(m), dict(hp), mode
-        self.half_batch = fault == "half_batch"     # planted, for readings
-        self.top = {k: weights[k] for k in ("embed", "final_norm", "lm_head")}
-        self.layers = [{k: a[i] for k, a in weights["layers"].items()}
-                       for i in range(m["num_hidden_layers"])]
-        zeros = lambda t: jax.tree_util.tree_map(
-            lambda a: jnp.zeros(a.shape, F32), t)
-        self.top_m, self.top_v = zeros(self.top), zeros(self.top)
-        self.layers_m = [zeros(l) for l in self.layers]
-        self.layers_v = [zeros(l) for l in self.layers]
-        self.t = 0
-        for name, fn in train_programs(self.m, hp, mode).items():
-            setattr(self, name, fn)
-
-    def step(self, ids, labels, head_rows=1024):
-        """One step on host arrays ids, labels [B, S]. Returns (loss,
-        {leaf name: squared gradient norm}), the leaf names being the
-        program's stacked ones."""
-        if self.half_batch:
-            ids, labels = ids[: len(ids) // 2], labels[: len(labels) // 2]
-        self.t += 1
-        t = jnp.float32(self.t)
-        ids = jnp.asarray(ids, jnp.int32)
-        b, s = ids.shape
-        # sub-layer inputs wait on the host for the backward pass
-        x = self.embed_fwd(self.top["embed"], ids)
-        xs = [np.asarray(x)]
-        for p in self.layers:
-            for names, fwd in ((ATTN_LEAVES, self.attn_fwd),
-                               (MLP_LEAVES, self.mlp_fwd)):
-                x = fwd(_pick(p, names), x)
-                xs.append(np.asarray(x))
-        xs.pop()
-        lab = jnp.asarray(np.asarray(labels, np.int32).reshape(-1))
-        rows = min(head_rows, b * s)
-        loss, g_norm, g_head, dy = self.head_bwd(
-            self.top["final_norm"], self.top["lm_head"],
-            x.reshape(b * s, -1), lab, rows)
-        del x
-        dy = dy.reshape(b, s, -1)
-        sq = {}
-        head_p = {k: self.top[k] for k in ("final_norm", "lm_head")}
-        head_p, hm, hv, s_ = self.update(
-            head_p, {k: self.top_m[k] for k in head_p},
-            {k: self.top_v[k] for k in head_p},
-            {"final_norm": g_norm, "lm_head": g_head}, t)
-        del g_norm, g_head
-        self.top.update(head_p), self.top_m.update(hm), self.top_v.update(hv)
-        sq.update({k: float(v) for k, v in s_.items()})
-        for i in reversed(range(len(self.layers))):
-            for names, bwd in ((MLP_LEAVES, self.mlp_bwd),
-                               (ATTN_LEAVES, self.attn_bwd)):
-                x_in = jnp.asarray(xs.pop())
-                p, mo, vo, dy, s_ = bwd(
-                    _pick(self.layers[i], names),
-                    _pick(self.layers_m[i], names),
-                    _pick(self.layers_v[i], names), x_in, dy, t)
-                self.layers[i].update(p)
-                self.layers_m[i].update(mo)
-                self.layers_v[i].update(vo)
-                for k, v in s_.items():
-                    sq[k] = sq.get(k, 0.0) + float(v)
-        ge = self.embed_grad(ids, dy, self.m["vocab_size"])
-        e, em, ev, s_ = self.update(
-            {"embed": self.top["embed"]}, {"embed": self.top_m["embed"]},
-            {"embed": self.top_v["embed"]}, {"embed": ge}, t)
-        self.top.update(e), self.top_m.update(em), self.top_v.update(ev)
-        sq["embed"] = float(s_["embed"])
-        return float(loss), sq
-
-    def change_sq(self, initial):
-        """{leaf: squared norm of (parameters now - ``initial``)}, ``initial``
-        being the benchmark's tree made again from the seed."""
-        sq = jax.jit(lambda a, b: jnp.sum(jnp.square(
-            a.astype(F32) - b.astype(F32))))
-        diff = lambda a, b: float(sq(a, b))
-        out = {k: diff(self.top[k], initial[k]) for k in self.top}
-        for name in self.layers[0]:
-            out[name] = sum(diff(l[name], initial["layers"][name][i])
-                            for i, l in enumerate(self.layers))
-        return out
+    out = {"embed_fwd": embed_fwd, "head_bwd": head_bwd, "update": update,
+           "embed_grad": embed_grad}
+    for name, fn in sublayers.items():
+        out[name + "_fwd"], out[name + "_bwd"] = sub(fn)
+    return out

@@ -1,7 +1,8 @@
-"""Cells of kind ``serve_open``: the window drives
-``InferenceEngine.submit()`` and ``step()`` in the loop ``engine.run()``
-runs (wall mode), so that the harness can stamp every token itself, after
-the iteration's sync. It adds no scheduling of its own."""
+"""Cells of kind ``serve_open``: the window drives ``submit()`` and
+``step()`` of the program's own serving engine, as the configuration's
+family builds it, in the loop ``engine.run()`` runs (wall mode), so that the
+harness can stamp every token itself, after the iteration's sync. It adds no
+scheduling of its own."""
 from __future__ import annotations
 
 import gc
@@ -12,7 +13,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from chipbench import check, flops, harness, reference, stats, weights
+from chipbench import check, harness, stats, weights
 from chipbench import traffic as gen
 from chipbench.harness import annotate, now, say
 
@@ -23,18 +24,11 @@ DRAIN_S = 60.0
 class Driver:
     """The engine with the harness's clock around it."""
 
-    def __init__(self, model, t, seed):
-        from paddle_tpu.inference import InferenceEngine, ServeConfig
-        e = t["engine"]
-        self.model, self.t = model, t
-        self.weights = weights.make_weights(model, seed)
+    def __init__(self, fam, model, t, seed):
+        self.fam, self.model, self.t = fam, model, t
+        self.weights = weights.make_weights(fam.leaves(model), seed)
         harness.mark("weights")
-        self.engine = InferenceEngine(
-            self.weights, weights.llama_config(model),
-            ServeConfig(block_size=e["block_size"], num_blocks=e["num_blocks"],
-                        max_batch=e["max_batch"],
-                        prefill_chunk=e["prefill_chunk"],
-                        max_seq_len=e["max_seq_len"]))
+        self.engine = fam.serve_engine(self.weights, model, t["engine"])
         self.t0 = now()
         self.tokens: Dict[int, List[int]] = {}      # rid -> served tokens
         self.times: Dict[int, List[float]] = {}     # rid -> their stamps
@@ -137,6 +131,29 @@ class Driver:
         gc.collect()
 
 
+def registry_numbers(engine) -> dict:
+    """The program's own registry as plain numbers under its own names; a
+    summary gives ``<name>_count`` and ``<name>_sum``, as its exposition
+    does."""
+    out = {}
+    for name, v in engine.registry.snapshot().items():
+        if isinstance(v, (int, float)):
+            out[name] = v
+        elif hasattr(v, "count") and hasattr(v, "sum"):
+            out[name + "_count"], out[name + "_sum"] = v.count, v.sum
+    return out
+
+
+def registry_change(before: dict, after: dict) -> dict:
+    return {k: after[k] - before[k] for k in after if k in before}
+
+
+def stop_trace(d: Driver, tracer) -> None:
+    tracer.c1 = d.clock()
+    tracer.stop()
+    tracer.registry1 = registry_numbers(d.engine)
+
+
 def drive(d: Driver, planned, t, seconds, tracer=None):
     """The window and its drain. Returns per-request facts."""
     pending = list(planned)
@@ -155,11 +172,11 @@ def drive(d: Driver, planned, t, seconds, tracer=None):
         if tracer is not None:
             if not tracing and trace_at is not None and clock >= trace_at:
                 tracer.asked = clock
+                tracer.registry0 = registry_numbers(d.engine)
                 tracer.start()
                 tracer.c0, tracing, trace_at = d.clock(), True, None
             elif tracing and clock >= tracer.c0 + t["trace_seconds"]:
-                tracer.c1 = d.clock()
-                tracer.stop()
+                stop_trace(d, tracer)
                 tracing = False
         with annotate("chipbench.generator"):
             while not closed and pending and pending[0].due <= clock:
@@ -180,8 +197,7 @@ def drive(d: Driver, planned, t, seconds, tracer=None):
         d.step()
     gc.callbacks.remove(d.on_gc)
     if tracing:
-        tracer.c1 = d.clock()
-        tracer.stop()
+        stop_trace(d, tracer)
     if tracer is not None and tracer.t1 is None:
         raise SystemExit("chipbench: the window ended before its traced "
                          "part began: the mix ran out of requests. No result.")
@@ -258,17 +274,17 @@ def check_shape(t: dict):
     return pad_len(t["prompt_tokens"]["max"] + out_max), out_max
 
 
-def token_gaps(w, model, t, prompt, out, served_by=None):
+def token_gaps(fam, w, model, t, prompt, out, served_by=None):
     """For each served position: how far the served token's float32
     reference logit lies below the reference's best, in standard deviations
     of that position's logits. With ``served_by`` = a lower precision, the
     token read is the one that precision puts first (the control)."""
     toks = list(prompt) + list(out[:-1])
     padded, last_max = check_shape(t)
-    ref = reference.logits_after(w, model, toks, len(out), padded, last_max)
+    ref = fam.logits_after(w, model, toks, len(out), padded, last_max)
     if served_by is not None:
-        low = reference.logits_after(w, model, toks, len(out), padded,
-                                     last_max, mode=served_by)
+        low = fam.logits_after(w, model, toks, len(out), padded, last_max,
+                               mode=served_by)
         out = low.argmax(-1)
     rows = np.arange(len(out))
     return (ref.max(-1) - ref[rows, np.asarray(out)]) / ref.std(-1)
@@ -283,9 +299,9 @@ def compare(widest_gap: float, nums: dict, limits: dict) -> check.Compared:
 
 
 def run(cell, args, clock_start: float, device: dict) -> str:
-    model, t = cell.model, cell.traffic
+    fam, model, t = cell.family, cell.model, cell.traffic
     seconds = float(args.seconds)
-    d = Driver(model, t, args.seed)
+    d = Driver(fam, model, t, args.seed)
     harness.mark("engine")
     d.warm()
     harness.mark("warm-up")
@@ -312,7 +328,7 @@ def run(cell, args, clock_start: float, device: dict) -> str:
     picks = sample_for_check(d, run_, nums, args.seed, t["check_requests"])
     worst, n_tok = 0.0, 0
     for rid in picks:
-        g = token_gaps(w, model, t, run_["by_rid"][rid].prompt,
+        g = token_gaps(fam, w, model, t, run_["by_rid"][rid].prompt,
                        d.tokens[rid])
         worst, n_tok = max(worst, float(g.max())), n_tok + len(g)
     compared = compare(worst if picks else math.inf, nums, t["limits"])
@@ -324,7 +340,7 @@ def run(cell, args, clock_start: float, device: dict) -> str:
     if args.trace:
         metrics, busy, breakdown = harness.traced(
             cell, args, tracer,
-            trace_counters(d, run_, nums, tracer, model, t, args.peak))
+            trace_counters(d, run_, nums, tracer, args.peak))
         device.update(busy)
     else:
         metrics = {m["name"]: {"value": end_to_end(m["name"], nums, setup_s),
@@ -359,28 +375,30 @@ def end_to_end(name: str, nums: dict, setup_s: float) -> float:
         else math.inf
 
 
-def trace_counters(d, run_, nums, tracer, model, t, peak) -> dict:
-    """What the readers need from the harness's own record: lists over the
-    whole window, and work counted over the traced part of it."""
+def trace_counters(d, run_, nums, tracer, peak) -> dict:
+    """What the readers need: from the harness's own record, lists over the
+    whole window; over the traced part of it, the FLOPs its iterations
+    required and the family's counters for its kernels; and the change of
+    the program's own registry over it, under the registry's names."""
+    fam, model, t = d.fam, d.model, d.t
     c0, c1 = tracer.c0, tracer.c1
     its = [r for r in d.iters if c0 <= r["t0"] and r["t1"] <= c1]
     # the profiler's start and stop hold the engine's thread for seconds, so
     # the queues are read from the requests due before it was asked for
     early = {rid for rid in run_["sent"]
              if run_["by_rid"][rid].due < tracer.asked - 0.5}
-    bs, layers = t["engine"]["block_size"], model["num_hidden_layers"]
-    need, least = 0.0, 0.0
+    need = 0.0
     for r in its:
         if r["prefill"]:
             start, n, first = r["prefill"]
-            need += flops.forward_flops(
+            need += fam.forward_flops(
                 model, n, n * start + n * (n + 1) // 2, first)
         if r["decode_ctx"]:
             ctx = r["decode_ctx"]
-            need += flops.forward_flops(model, len(ctx), sum(ctx), len(ctx))
-            least += layers * flops.min_seconds(
-                *flops.paged_decode_call(model, ctx, bs), peak)
+            need += fam.forward_flops(model, len(ctx), sum(ctx), len(ctx))
     return {
+        **fam.serve_kernels(model, t["engine"], its, peak),
+        **registry_change(tracer.registry0, tracer.registry1),
         "gen_late_s": [s for rid, s in zip(run_["sent"], run_["late"])
                        if rid in early],
         "queue_wait_s": [s for rid, s in d.queue_wait.items()
@@ -390,7 +408,6 @@ def trace_counters(d, run_, nums, tracer, model, t, peak) -> dict:
         "occupancy": [len(r["decode_ctx"]) / t["engine"]["max_batch"]
                       for r in its],
         "required_flops": need,
-        "paged_decode": {"least_s": least} if least else None,
     }
 
 
@@ -399,8 +416,8 @@ def calibrate(cell, args, seed: int, what: set):
     the program's widest gap and the control's on the same requests, each
     through the run's own comparison with the mix's own limits: the control
     has to come out as not correct."""
-    model, t = cell.model, cell.traffic
-    d = Driver(model, t, seed)
+    fam, model, t = cell.family, cell.model, cell.traffic
+    d = Driver(fam, model, t, seed)
     if not getattr(calibrate, "warmed", False):
         d.warm()            # later seeds find the programs in the process
         calibrate.warmed = True
@@ -416,7 +433,7 @@ def calibrate(cell, args, seed: int, what: set):
             continue
         worst, n_tok = 0.0, 0
         for rid in picks:
-            g = token_gaps(w, model, t, run_["by_rid"][rid].prompt,
+            g = token_gaps(fam, w, model, t, run_["by_rid"][rid].prompt,
                            d.tokens[rid], served_by=served_by[reading])
             worst, n_tok = max(worst, float(g.max())), n_tok + len(g)
         c = compare(worst if picks else math.inf, nums, t["limits"])

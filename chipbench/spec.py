@@ -2,8 +2,11 @@
 from __future__ import annotations
 
 import dataclasses
+import glob
+import importlib
 import json
 import os
+from types import ModuleType
 from typing import List
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -21,6 +24,7 @@ class Cell:
     chips: int
     config_name: str
     model: dict            # the configuration file, as run
+    family: ModuleType     # families/<the configuration's "family">.py
     traffic_name: str
     traffic: dict          # the traffic file
     end_to_end: List[dict]  # the cell's end-to-end metrics (BENCHMARK.json)
@@ -36,6 +40,19 @@ def _listed(metric: dict, cell: str, reporting: set) -> bool:
     if "workloads" in metric:
         return cell in metric["workloads"]
     return metric.get("moves") is None or metric["moves"] in reporting
+
+
+def family(model: dict) -> ModuleType:
+    """The module of the model family a configuration names under
+    ``family``: ``families/<name>.py``. No default: a configuration without
+    the key, or naming a family that has no module, is an error."""
+    found = sorted(os.path.basename(p)[:-3] for p in glob.glob(
+        os.path.join(HERE, "families", "[!_]*.py")))
+    name = model.get("family")
+    if name not in found:
+        raise SystemExit(f"chipbench: the configuration names the model "
+                         f"family {name!r}; chipbench/families/ has {found}")
+    return importlib.import_module("chipbench.families." + name)
 
 
 def load_cell(name: str, root: str = ROOT) -> Cell:
@@ -56,20 +73,11 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         if _listed(m, name, reporting):
             layer.append(dict(m, file=_load(os.path.join(
                 HERE, "metrics", m["name"] + ".json"))))
-    return Cell(name, w["chips"], w["config"], model, w["traffic"], traffic,
-                e2e, layer, bench)
-
-
-TINY = {"hidden_size": 128, "intermediate_size": 256, "num_attention_heads": 4,
-        "vocab_size": 512, "num_hidden_layers": 2}
+    return Cell(name, w["chips"], w["config"], model, family(model),
+                w["traffic"], traffic, e2e, layer, bench)
 
 
 def rehearsal_model(model: dict) -> dict:
-    """The configuration at a size the CPU runs in seconds: the rehearsal
-    proves control flow, never a number. The ratio of heads to KV heads
-    stays."""
-    ratio = model["num_attention_heads"] // model["num_key_value_heads"]
-    out = dict(model, **TINY)
-    out["num_key_value_heads"] = max(1, TINY["num_attention_heads"] // ratio)
-    out.pop("head_dim", None)
-    return out
+    """The configuration at a size the CPU runs in seconds, as its family
+    cuts it: the rehearsal proves control flow, never a number."""
+    return family(model).rehearsal(model)

@@ -29,7 +29,7 @@ def main(argv=None) -> int:
     from chipbench import harness, serve, stats
     from chipbench import traffic as gen
     cell, _, _, _ = harness.open_cell(args.workload, args.rehearse)
-    d = serve.Driver(cell.model, cell.traffic, args.seed)
+    d = serve.Driver(cell.family, cell.model, cell.traffic, args.seed)
     d.warm()
     rid = 0
     with open(harness.readings_file("sweep", cell.name, args.rehearse),

@@ -60,9 +60,10 @@ def test_train_sound_run_is_correct(name):
 def test_train_control_fp8_is_not_correct(seed):
     from chipbench import train
     cell = tiny("yi9b.train.seq4k", limits=TRAIN_LIMITS)
-    ref = train.reference_readings(cell.model, cell.traffic, seed)
-    ctl = train.reference_readings(cell.model, cell.traffic, seed,
-                                   mode="fp8")
+    ref = train.reference_readings(cell.family, cell.model, cell.traffic,
+                                   seed)
+    ctl = train.reference_readings(cell.family, cell.model, cell.traffic,
+                                   seed, mode="fp8")
     assert not train.compare(ctl, ref, TRAIN_LIMITS).correct
     assert train.compare(ref, ref, TRAIN_LIMITS).correct
 
@@ -72,17 +73,14 @@ def test_train_reference_with_a_planted_fault_is_not_correct():
     batch left out."""
     from chipbench import train
     cell = tiny("yi9b.train.seq4k", limits=TRAIN_LIMITS)
-    ref = train.reference_readings(cell.model, cell.traffic, 4)
-    bad = train.reference_readings(cell.model, cell.traffic, 4,
+    ref = train.reference_readings(cell.family, cell.model, cell.traffic, 4)
+    bad = train.reference_readings(cell.family, cell.model, cell.traffic, 4,
                                    fault="half_batch")
     assert not train.compare(bad, ref, TRAIN_LIMITS).correct
 
 
-def broken_build(fault):
-    """``build_train_step`` whose step carries the fault."""
-    from paddle_tpu.models import llama
-    real_build = llama.build_train_step
-
+def broken_build(real_build, fault):
+    """A family's ``train_step`` whose step carries the fault."""
     def build(*a, **kw):
         step, params, opt = real_build(*a, **kw)
         if fault == "state_unchanged":
@@ -104,9 +102,10 @@ def broken_build(fault):
 @pytest.mark.parametrize("name", TRAIN_CELLS)
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch"])
 def test_train_fault_is_not_correct(name, fault, monkeypatch):
-    from paddle_tpu.models import llama
-    monkeypatch.setattr(llama, "build_train_step", broken_build(fault))
-    line = run_line(tiny(name, limits=TRAIN_LIMITS))
+    cell = tiny(name, limits=TRAIN_LIMITS)
+    monkeypatch.setattr(cell.family, "train_step",
+                        broken_build(cell.family.train_step, fault))
+    line = run_line(cell)
     assert line["correct"] is False
     failing = [k for k, v in line["compared"].items()
                if not v["value"] <= v["limit"]]

@@ -140,3 +140,47 @@ def test_the_cell_lists_the_nine_and_the_harness_reads_them():
                if m["name"] in names)
     train = spec.load_cell("yi9b.train.seq4k")
     assert not set(names) & {m["name"] for m in train.per_layer}
+
+
+def test_a_new_reader_reads_a_spans_arguments_and_a_registry_counter(
+        tmp_path):
+    """What a later ``readers_<x>.py`` needs and no edit: a span keeps the
+    arguments it was opened with (read here from a real trace), and the
+    counters hold the change of the program's own registry over the traced
+    part, under the registry's names."""
+    import types
+
+    import jax
+    from paddle_tpu.observability.registry import MetricsRegistry
+    from chipbench import serve
+    jax.profiler.start_trace(str(tmp_path))
+    for rows in (3, 5):
+        with jax.profiler.TraceAnnotation("serve.decode", rows=rows,
+                                          bucket=8):
+            pass
+    jax.profiler.stop_trace()
+    events = trace.load(trace.find_xplane(str(tmp_path)))
+
+    registry = MetricsRegistry(prefix="some_program")
+    routed = registry.counter("routed_tokens_total")
+    wait = registry.summary("queue_wait_seconds")
+    engine = types.SimpleNamespace(registry=registry)
+    routed.inc(10)
+    wait.hist.record(0.5)
+    before = serve.registry_numbers(engine)
+    routed.inc(32)
+    wait.hist.record(0.25)
+    change = serve.registry_change(before, serve.registry_numbers(engine))
+    assert change == {"routed_tokens_total": 32.0,
+                      "queue_wait_seconds_count": 1,
+                      "queue_wait_seconds_sum": 0.25}
+
+    def fill_pct(f, match, per):
+        spans = readers_spans.host_spans(f.events, match)
+        rows = sum(e.stats["rows"] for e in spans)
+        slots = sum(e.stats["bucket"] for e in spans)
+        return 100.0 * rows / slots, f.counters[per] / rows
+
+    f = Facts({}, {}, 1, {}, events, 1.0, change)
+    assert fill_pct(f, r"^serve\.decode$", "routed_tokens_total") \
+        == (50.0, 4.0)

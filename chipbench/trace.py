@@ -5,7 +5,9 @@ tested on hand-built input. A device plane is one whose name starts with
 ``/device:TPU:`` (``/device:`` in general, the host excluded); on it the line
 ``XLA Ops`` holds one event per executed HLO operation (fusions, custom
 calls = Pallas kernels, collectives) and ``XLA Modules`` one per executed
-program (``jit_step(...)``).
+program (``jit_step(...)``). Any other plane is the host's: its events are
+spans (``TraceAnnotation``s), and each keeps the arguments it was opened
+with as its ``stats``.
 """
 from __future__ import annotations
 
@@ -13,10 +15,12 @@ import dataclasses
 import glob
 import os
 import re
-from typing import Iterable, List, Sequence, Tuple
+import types
+from typing import Any, Iterable, List, Mapping, Sequence, Tuple
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+NO_STATS: Mapping[str, Any] = types.MappingProxyType({})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -26,6 +30,10 @@ class Event:
     name: str
     start: float        # seconds
     dur: float          # seconds
+    # a host span's arguments (``serve.decode``: ``rows``, ``bucket``); a
+    # device event's are not kept, there are a dozen to each of 100,000
+    stats: Mapping[str, Any] = dataclasses.field(default=NO_STATS,
+                                                 compare=False)
 
     @property
     def end(self) -> float:
@@ -44,10 +52,12 @@ def load(path: str) -> List[Event]:
     from jax.profiler import ProfileData
     out = []
     for plane in ProfileData.from_file(path).planes:
+        host = not plane.name.startswith("/device:")
         for line in plane.lines:
             for ev in line.events:
                 out.append(Event(plane.name, line.name, ev.name,
-                                 ev.start_ns * 1e-9, ev.duration_ns * 1e-9))
+                                 ev.start_ns * 1e-9, ev.duration_ns * 1e-9,
+                                 dict(ev.stats) if host else NO_STATS))
     return out
 
 

@@ -1,12 +1,13 @@
-"""Cells of kind ``train``: the window drives ``build_train_step``'s
-``step_fn``, fed seeded token ids drawn anew for every step."""
+"""Cells of kind ``train``: the window drives the ``step_fn`` of the
+program's own train step, as the configuration's family builds it, fed
+seeded token ids drawn anew for every step."""
 from __future__ import annotations
 
 import gc
 
 import numpy as np
 
-from chipbench import check, flops, harness, reference, weights
+from chipbench import check, harness, weights
 from chipbench.harness import annotate, now, say
 
 
@@ -26,38 +27,36 @@ class Feed:
         return ids, labels
 
 
-def flat_names(tree: dict) -> dict:
-    """The program's tree to {leaf name: value}; layer leaves by their own
-    name (they are stacked, one leaf for all layers)."""
-    out = {k: v for k, v in tree.items() if k != "layers"}
-    out.update(tree["layers"])
-    return out
+def flat_names(tree) -> dict:
+    """A tree to {leaf's path: value}: ``layers/q_proj``, ``experts/0/up``.
+    However deep, and whether its leaves stack on a layer axis or not."""
+    import jax
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {jax.tree_util.keystr(path, simple=True, separator="/"): v
+            for path, v in flat}
 
 
 class Program:
     """The compiled step with its state: one object, driven from the seed
     through its first steps in set-up and handed to the window."""
 
-    def __init__(self, model, t, seed):
+    def __init__(self, fam, model, t, seed):
         import jax
         import jax.numpy as jnp
-        from paddle_tpu.models.llama import build_train_step
-        self.model, self.t = model, t
-        self.step_fn, params, self.opt = build_train_step(
-            weights.llama_config(model), weights.parallel_config(t),
-            lr=t["lr"], seed=0)
+        self.fam, self.model, self.t = fam, model, t
+        self.step_fn, params, self.opt = fam.train_step(model, t)
         shardings = jax.tree_util.tree_map(lambda a: a.sharding, params)
         del params          # the benchmark's weights take their place
-        self.params = weights.make_weights(model, seed, shardings=shardings)
+        leaves = fam.leaves(model)
+        self.params = weights.make_weights(leaves, seed, shardings=shardings)
         self.feed = Feed(weights.fold_seed(seed), t["batch"], t["seq"],
                          model["vocab_size"])
         sq = lambda a: jnp.sum(jnp.square(a.astype(jnp.float32)))
         self._norms_sq = jax.jit(
             lambda tree: jax.tree_util.tree_map(sq, tree))
-        shapes = weights.leaf_shapes(model)
         self._change_sq = jax.jit(lambda p, key: jax.tree_util.tree_map(
             lambda a, b: sq(a.astype(jnp.float32) - b.astype(jnp.float32)),
-            p, weights._make(key, shapes, jnp.bfloat16)))
+            p, weights._make(key, leaves, jnp.bfloat16)))
         self._key = jax.random.PRNGKey(weights.fold_seed(seed))
         self.loss = None
         self.steps = 0
@@ -79,7 +78,7 @@ class Program:
         b1 = self.t["adamw"]["beta1"]
         losses = [float(self.step())]
         grad = {k: float(v) ** 0.5 / (1 - b1) for k, v in flat_names(
-            self._norms_sq(self.opt["m"])).items()}
+            self._norms_sq(self.fam.first_moment(self.opt))).items()}
         losses += [float(self.step()) for _ in range(n - 1)]
         change = {k: float(v) ** 0.5 for k, v in flat_names(
             self._change_sq(self.params, self._key)).items()}
@@ -111,11 +110,13 @@ class Program:
         gc.collect()
 
 
-def reference_readings(model, t, seed, mode="f32", fault=None) -> dict:
-    """What ``Program.first_steps`` reads, from the plain reference."""
-    w = weights.make_weights(model, seed)
+def reference_readings(fam, model, t, seed, mode="f32", fault=None) -> dict:
+    """What ``Program.first_steps`` reads, from the family's plain
+    reference."""
+    leaves = fam.leaves(model)
+    w = weights.make_weights(leaves, seed)
     hp = dict(t["adamw"], lr=t["lr"])
-    ref = reference.Trainer(w, model, hp, mode=mode, fault=fault)
+    ref = fam.Trainer(w, model, hp, mode=mode, fault=fault)
     del w
     feed = Feed(weights.fold_seed(seed), t["batch"], t["seq"],
                 model["vocab_size"])
@@ -126,7 +127,7 @@ def reference_readings(model, t, seed, mode="f32", fault=None) -> dict:
         if grad is None:
             grad = {k: v ** 0.5 for k, v in sq.items()}
     change = {k: v ** 0.5 for k, v in ref.change_sq(
-        weights.make_weights(model, seed)).items()}
+        weights.make_weights(leaves, seed)).items()}
     return {"loss": losses, "grad": grad, "change": change}
 
 
@@ -148,18 +149,18 @@ def compare(prog: dict, ref: dict, limits: dict) -> check.Compared:
     return c
 
 
-def kernel_counters(model, t, peak) -> dict:
-    """The least seconds one call of each flash kernel could take."""
-    fwd = flops.flash_fwd_call(model, t["batch"], t["seq"])
-    bwd = flops.flash_bwd_call(model, t["batch"], t["seq"])
-    return {"flash_fwd": {"per_call_least_s": flops.min_seconds(*fwd, peak)},
-            "flash_bwd": {"per_call_least_s": flops.min_seconds(*bwd, peak)}}
+def kernel_counters(fam, model, t, peak, traced_steps: int) -> dict:
+    """What the readers need of a traced part of ``traced_steps`` steps: the
+    family's counters for its kernels, and the FLOPs its steps required."""
+    return {**fam.train_kernels(model, t, peak),
+            "required_flops": traced_steps * t["batch"] * t["seq"]
+            * fam.train_flops_per_token(model, t["seq"])}
 
 
 def run(cell, args, clock_start: float, device: dict) -> str:
-    model, t = cell.model, cell.traffic
+    fam, model, t = cell.family, cell.model, cell.traffic
     seconds = float(args.seconds)
-    prog = Program(model, t, args.seed)
+    prog = Program(fam, model, t, args.seed)
     harness.mark("step built, weights")
     readings = prog.first_steps()
     say(f"first steps: losses {readings['loss']}")
@@ -189,7 +190,7 @@ def run(cell, args, clock_start: float, device: dict) -> str:
     prog.free()
 
     t_ref = now()
-    ref = reference_readings(model, t, args.seed)
+    ref = reference_readings(fam, model, t, args.seed)
     say(f"the reference's three steps took {now() - t_ref:.1f} s")
     compared = compare(readings, ref, t["limits"])
     device = dict(device, memory_peak_bytes=peak_bytes)
@@ -198,12 +199,9 @@ def run(cell, args, clock_start: float, device: dict) -> str:
         f"{setup_s:.2f} s: {harness.phases(clock_start)}")
     breakdown = None
     if args.trace:
-        counters = kernel_counters(model, t, args.peak)
-        counters["required_flops"] = (traced_steps * tokens_per_step
-                                      * flops.train_flops_per_token(
-                                          model, t["seq"]))
-        metrics, busy, breakdown = harness.traced(cell, args, tracer,
-                                                  counters)
+        metrics, busy, breakdown = harness.traced(
+            cell, args, tracer,
+            kernel_counters(fam, model, t, args.peak, traced_steps))
         device.update(busy)
     else:
         metrics = {
@@ -221,13 +219,13 @@ def calibrate(cell, args, seed: int, what: set):
     """Readings for the limits (``chipbench/calibrate.py``), each through
     the run's own comparison with the mix's own limits: the control and the
     planted fault have to come out as not correct."""
-    model, t = cell.model, cell.traffic
+    fam, model, t = cell.family, cell.model, cell.traffic
     readings = None
     if "program" in what:       # before the reference takes the chip
-        prog = Program(model, t, seed)
+        prog = Program(fam, model, t, seed)
         readings = prog.first_steps()
         prog.free()
-    ref = reference_readings(model, t, seed)
+    ref = reference_readings(fam, model, t, seed)
 
     def row(name, readings):
         c = compare(readings, ref, t["limits"])
@@ -239,7 +237,7 @@ def calibrate(cell, args, seed: int, what: set):
         yield row("program", readings)
     if "control" in what:
         yield row("control:" + t["control_mode"], reference_readings(
-            model, t, seed, mode=t["control_mode"]))
+            fam, model, t, seed, mode=t["control_mode"]))
     if "faults" in what:
         yield row("fault:half_batch", reference_readings(
-            model, t, seed, fault="half_batch"))
+            fam, model, t, seed, fault="half_batch"))
