@@ -346,6 +346,61 @@ _WORK_TOTALS = {"rows": "decode_rows_total", "bucket": "decode_slots_total",
                 "table_blocks": "prefill_table_blocks_total"}
 
 
+class _LlamaServing:
+    """What the engine asks of a model, chosen by the config's type
+    (:func:`_serving_for`): the frozen config its jitted programs are keyed
+    by, the cache arrays (a tuple, each indexed by block id on axis 1), and
+    the jitted prefill-chunk, decode and verify programs
+    ``fn(params, *cache, ...) -> (..., *cache[, counts])``. This one is
+    Llama's: its builders unchanged (plain, int8, tensor-parallel, verify);
+    an int8 cache is (k, v, k_scale, v_scale). ``work`` names the registry
+    counters a model adds to ``_WORK_TOTALS``' (none here)."""
+
+    work: Dict[str, str] = {}
+    # (kind, int8 cache): (the plain builder, its mp-sharded twin: the same
+    # argument lists and output tuples)
+    _BUILDERS = {
+        ("prefill", False): (_jitted_paged_prefill,
+                             _jitted_paged_prefill_tp),
+        ("prefill", True): (_jitted_paged_prefill_quant,
+                            _jitted_paged_prefill_quant_tp),
+        ("decode", False): (_jitted_paged_decode, _jitted_paged_decode_tp),
+        ("decode", True): (_jitted_paged_decode_quant,
+                           _jitted_paged_decode_quant_tp),
+        ("verify", False): (_jitted_paged_verify, _jitted_paged_verify_tp),
+        ("verify", True): (_jitted_paged_verify_quant,
+                           _jitted_paged_verify_quant_tp),
+    }
+
+    @staticmethod
+    def refuse(**features) -> None:
+        """Raise for a serving feature this model cannot run: none."""
+
+    freeze = staticmethod(_freeze_config)
+
+    @staticmethod
+    def init_cache(config, num_blocks: int, block_size: int,
+                   kv_dtype: str) -> Tuple:
+        kv = init_paged_kv_pool(config, num_blocks, block_size,
+                                kv_dtype=kv_dtype)
+        if kv_dtype == "int8":
+            kv += init_paged_kv_scales(config, num_blocks, block_size)
+        return kv
+
+    @classmethod
+    def step_fn(cls, kind: str, frozen, quant: bool, mesh):
+        plain, tp = cls._BUILDERS[(kind, quant)]
+        return plain(frozen) if mesh is None else tp(frozen, mesh)
+
+
+def _serving_for(config):
+    """The serving side of ``config``'s model (see :class:`_LlamaServing`)."""
+    from ..models import deepseek
+    if isinstance(config, deepseek.DeepSeekConfig):
+        return deepseek.DeepSeekServing
+    return _LlamaServing
+
+
 class InferenceEngine:
     """Continuous-batching engine over a paged KV cache.
 
@@ -390,13 +445,23 @@ class InferenceEngine:
             raise ValueError(
                 f"ServeConfig.kv_dtype must be 'auto' or 'int8', "
                 f"got {self.kv_dtype!r}")
-        self.k_pool, self.v_pool = init_paged_kv_pool(
+        spec = (self.serve.speculative
+                if self.serve.speculative is not None
+                else envs.get(ENV_SERVE_SPEC))
+        # what the model brings (see _LlamaServing); it refuses loudly, here,
+        # what it cannot run
+        self.model = _serving_for(config)
+        self.model.refuse(mp=self.mp, kv_dtype=self.kv_dtype,
+                          speculative=bool(spec),
+                          draft=draft_params is not None)
+        # the cache: a tuple of arrays, each indexed by block id on axis 1.
+        # Copy-on-write, the liveness check and the pool's bytes treat it so;
+        # only the model's own programs know what an array holds (Llama:
+        # (k, v[, k_scale, v_scale]); latent attention: one pool)
+        self.kv: Tuple = self.model.init_cache(
             config, self.serve.num_blocks, self.serve.block_size,
-            kv_dtype=self.kv_dtype)
-        self.k_scale = self.v_scale = None
-        if self.kv_dtype == "int8":
-            self.k_scale, self.v_scale = init_paged_kv_scales(
-                config, self.serve.num_blocks, self.serve.block_size)
+            self.kv_dtype)
+        self.kv_draft: Tuple = ()
         # COW prefix cache: full prompt blocks stay indexed after
         # release and later identical prompts share them ref-counted
         prefix_on = (self.serve.prefix_cache
@@ -411,9 +476,6 @@ class InferenceEngine:
         # model's greedy argmax, so streams are bit-identical to
         # sequential decode regardless of draft quality (PARITY.md) —
         # the draft only moves latency.
-        spec = (self.serve.speculative
-                if self.serve.speculative is not None
-                else envs.get(ENV_SERVE_SPEC))
         self.speculative = bool(spec)
         self.draft_k = int(self.serve.draft_k
                            if self.serve.draft_k is not None
@@ -423,7 +485,6 @@ class InferenceEngine:
         self.draft_params: Optional[Dict[str, Any]] = None
         self.draft_config: Optional[LlamaConfig] = None
         self._draft_frozen: Optional[Tuple] = None
-        self.k_draft = self.v_draft = None
         self._spec_proposed = 0
         self._spec_accepted = 0
         if self.speculative:
@@ -441,7 +502,7 @@ class InferenceEngine:
             # shared block table per sequence) but always store the
             # model dtype: draft KV only shapes proposals, never output
             # bytes, so int8 buys nothing there
-            self.k_draft, self.v_draft = init_paged_kv_pool(
+            self.kv_draft = init_paged_kv_pool(
                 draft_config, self.serve.num_blocks, self.serve.block_size)
         if self.mp > 1:
             self._shard_tp()
@@ -482,7 +543,8 @@ class InferenceEngine:
         self._phase_ms: Dict[str, float] = {}
         self._iter_work: Dict[str, int] = {}
         self._compiled_at = -1           # the last iteration that compiled
-        self.work_totals = dict.fromkeys(_WORK_TOTALS.values(), 0)
+        self._work_names = dict(_WORK_TOTALS, **self.model.work)
+        self.work_totals = dict.fromkeys(self._work_names.values(), 0)
         # unified exposition (PR 15): the SLO histograms register by
         # reference, scheduler gauges as render-time callbacks; the
         # registration order IS the metrics_snapshot() key order
@@ -514,7 +576,7 @@ class InferenceEngine:
                 meta=self._journal_meta())
         self._rid = itertools.count()
         self._seqno = itertools.count()
-        self._frozen = _freeze_config(config)
+        self._frozen = self.model.freeze(config)
         self._compiled: Dict[Tuple, float] = {}
         self._clock = 0.0
         # preemption + live weight push (PR 13)
@@ -530,26 +592,25 @@ class InferenceEngine:
         # router's rolling swap both flip this)
         self._draining = False
 
-    # jitted step families, keyed (kind, quant): the mp-sharded twins
-    # are drop-in — same argument lists, same output tuples — so every
-    # scheduler call site dispatches through _step_fn and nothing else
-    # about the engine changes with mp.
-    _STEP_BUILDERS = {
-        ("prefill", False): (_jitted_paged_prefill,
-                             _jitted_paged_prefill_tp),
-        ("prefill", True): (_jitted_paged_prefill_quant,
-                            _jitted_paged_prefill_quant_tp),
-        ("decode", False): (_jitted_paged_decode, _jitted_paged_decode_tp),
-        ("decode", True): (_jitted_paged_decode_quant,
-                           _jitted_paged_decode_quant_tp),
-        ("verify", False): (_jitted_paged_verify, _jitted_paged_verify_tp),
-        ("verify", True): (_jitted_paged_verify_quant,
-                           _jitted_paged_verify_quant_tp),
-    }
+    def _step_fn(self, kind: str, frozen, quant: Optional[bool] = None):
+        """The model's jitted program of one kind (prefill, decode, verify)
+        for the cache this engine holds (``quant=False``: for the draft's,
+        which is never int8); every scheduler call site dispatches through
+        here, and nothing else changes with mp or the cache's type."""
+        if quant is None:
+            quant = self.kv_dtype == "int8"
+        return self.model.step_fn(kind, frozen, quant, self.mesh)
 
-    def _step_fn(self, kind: str, frozen, quant: bool = False):
-        plain, tp = self._STEP_BUILDERS[(kind, bool(quant))]
-        return tp(frozen, self.mesh) if self.mp > 1 else plain(frozen)
+    # the cache's arrays under the names they had while Llama's (k, v) was
+    # the only cache
+    k_pool = property(lambda self: self.kv[0])
+    v_pool = property(lambda self: self.kv[1])
+    k_scale = property(lambda self: self.kv[2] if len(self.kv) > 2 else None)
+    v_scale = property(lambda self: self.kv[3] if len(self.kv) > 2 else None)
+    k_draft = property(
+        lambda self: self.kv_draft[0] if self.kv_draft else None)
+    v_draft = property(
+        lambda self: self.kv_draft[1] if self.kv_draft else None)
 
     def _shard_tp(self) -> None:
         """Build the serving mesh and place weights + pools for mp > 1.
@@ -598,15 +659,11 @@ class InferenceEngine:
 
         self.params = put(self.params, c)
         pool_sh = NamedSharding(self.mesh, P(None, None, "mp", None))
-        self.k_pool = jax.device_put(self.k_pool, pool_sh)
-        self.v_pool = jax.device_put(self.v_pool, pool_sh)
-        if self.k_scale is not None:
-            self.k_scale = jax.device_put(self.k_scale, pool_sh)
-            self.v_scale = jax.device_put(self.v_scale, pool_sh)
+        self.kv = tuple(jax.device_put(a, pool_sh) for a in self.kv)
         if self.speculative:
             self.draft_params = put(self.draft_params, self.draft_config)
-            self.k_draft = jax.device_put(self.k_draft, pool_sh)
-            self.v_draft = jax.device_put(self.v_draft, pool_sh)
+            self.kv_draft = tuple(jax.device_put(a, pool_sh)
+                                  for a in self.kv_draft)
 
     def _register_metrics(self) -> None:
         """Register every engine metric into the unified registry: the
@@ -667,6 +724,9 @@ class InferenceEngine:
                 ("prefill_table_blocks_total", "block-table slots of the "
                                                "prefill chunks run")):
             r.gauge(name, fn=lambda n=name: self.work_totals[n], help=what)
+        for name in self.model.work.values():       # the model's own counts
+            r.gauge(name, fn=lambda n=name: self.work_totals[n],
+                    help="counted by the model's jitted steps")
         # PR 16 capacity gauges, only when the cache is live: the
         # default exposition stays byte-compatible with the pre-PR-15
         # legacy dict (pinned by the metrics-registry golden test)
@@ -754,20 +814,11 @@ class InferenceEngine:
                 nb = got[0]
                 # device-side blit of the shared block's slabs (host
                 # decision, one copy — never a cache reshape/compact)
-                self.k_pool = self.k_pool.at[:, nb].set(self.k_pool[:, b])
-                self.v_pool = self.v_pool.at[:, nb].set(self.v_pool[:, b])
-                if self.k_scale is not None:
-                    self.k_scale = self.k_scale.at[:, nb].set(
-                        self.k_scale[:, b])
-                    self.v_scale = self.v_scale.at[:, nb].set(
-                        self.v_scale[:, b])
-                if self.k_draft is not None:
-                    # draft pools share the block table, so the draft's
-                    # slab must move with the base's copy
-                    self.k_draft = self.k_draft.at[:, nb].set(
-                        self.k_draft[:, b])
-                    self.v_draft = self.v_draft.at[:, nb].set(
-                        self.v_draft[:, b])
+                # every array of the cache; the draft pools share the
+                # block table, so the draft's slab moves with the base's
+                self.kv = tuple(a.at[:, nb].set(a[:, b]) for a in self.kv)
+                self.kv_draft = tuple(a.at[:, nb].set(a[:, b])
+                                      for a in self.kv_draft)
                 self.pool.free([b])
                 seq.blocks[bi] = nb
                 self._cow_copies += 1
@@ -898,12 +949,7 @@ class InferenceEngine:
         """False when an exception killed a kernel AFTER its donated
         k/v pool buffers were invalidated — unrecoverable in-process
         (the journal recovery path owns that failure mode)."""
-        pools = [self.k_pool, self.v_pool]
-        if self.k_scale is not None:
-            pools += [self.k_scale, self.v_scale]
-        if self.k_draft is not None:
-            pools += [self.k_draft, self.v_draft]
-        for pool in pools:
+        for pool in self.kv + self.kv_draft:
             deleted = getattr(pool, "is_deleted", None)
             if deleted is not None and deleted():
                 return False
@@ -937,7 +983,7 @@ class InferenceEngine:
         sp.note(**counts)
         self._iter_work.update(counts)
         for k, v in counts.items():
-            self.work_totals[_WORK_TOTALS[k]] += v
+            self.work_totals[self._work_names[k]] += v
 
     # -- public API ---------------------------------------------------------
 
@@ -1284,22 +1330,20 @@ class InferenceEngine:
         try:
             faults.inject("serve.prefill.poison", rid=rid)
             with self._launch_span("serve.prefill.launch", key) as launch:
-                if self.k_scale is None:
-                    fn = self._step_fn("prefill", self._frozen)
-                    logits, self.k_pool, self.v_pool = fn(
-                        self.params, self.k_pool, self.v_pool,
-                        jnp.asarray(table), np.int32(seq.n_cached),
-                        jnp.asarray(ids), np.int32(n_live))
-                else:
-                    fn = self._step_fn("prefill", self._frozen, quant=True)
-                    (logits, self.k_pool, self.v_pool, self.k_scale,
-                     self.v_scale) = fn(
-                        self.params, self.k_pool, self.v_pool,
-                        self.k_scale, self.v_scale,
-                        jnp.asarray(table), np.int32(seq.n_cached),
-                        jnp.asarray(ids), np.int32(n_live))
+                fn = self._step_fn("prefill", self._frozen)
+                out = fn(
+                    self.params, *self.kv,
+                    jnp.asarray(table), np.int32(seq.n_cached),
+                    jnp.asarray(ids), np.int32(n_live))
+                n_kv = len(self.kv)
+                logits, self.kv, counts = out[0], out[1:1 + n_kv], \
+                    out[1 + n_kv:]
             with self._span("serve.prefill.wait") as wait:
                 logits = np.asarray(logits)  # noqa: PTA006 -- deliberate sync so prefill phase timing is honest
+                if counts:
+                    # a model's own work counts ride the sync just paid
+                    self._note_work(sp, **self.model.counted(
+                        "prefill", counts, [seq.n_cached + int(n_live)]))
         except Exception as e:  # noqa: BLE001 -- quarantine boundary
             failure = e
         with self._span("serve.prefill.commit"):
@@ -1380,7 +1424,7 @@ class InferenceEngine:
         derived — never journaled, never recovered — so a crash here
         costs nothing but the re-prefill on readmission."""
         c = self.serve.prefill_chunk
-        fn = self._step_fn("prefill", self._draft_frozen)
+        fn = self._step_fn("prefill", self._draft_frozen, quant=False)
         table = jnp.asarray(pad_table(seq.blocks, self.serve.max_nb))
         start, target = 0, seq.n_cached
         with self._launch_span("serve.draft.prefill",
@@ -1389,10 +1433,10 @@ class InferenceEngine:
                 n_live = min(c, target - start)
                 ids = np.zeros((c,), np.int32)
                 ids[:n_live] = seq.tokens[start:start + n_live]
-                _, self.k_draft, self.v_draft = fn(
-                    self.draft_params, self.k_draft, self.v_draft,
+                self.kv_draft = fn(
+                    self.draft_params, *self.kv_draft,
                     table, np.int32(start), jnp.asarray(ids),
-                    np.int32(n_live))
+                    np.int32(n_live))[1:]
                 start += n_live
         self._mark_compiled("draft_prefill", c, sp.t1 - sp.t0)
         seq.draft_pos = target
@@ -1449,23 +1493,19 @@ class InferenceEngine:
             try:
                 faults.inject("serve.decode.poison", rids=rids)
                 with self._launch_span("serve.decode.launch", key) as launch:
-                    if self.k_scale is None:
-                        fn = self._step_fn("decode", self._frozen)
-                        logits, self.k_pool, self.v_pool = fn(
-                            self.params, self.k_pool, self.v_pool,
-                            jnp.asarray(tables), jnp.asarray(positions),
-                            jnp.asarray(toks))
-                    else:
-                        fn = self._step_fn("decode", self._frozen,
-                                           quant=True)
-                        (logits, self.k_pool, self.v_pool, self.k_scale,
-                         self.v_scale) = fn(
-                            self.params, self.k_pool, self.v_pool,
-                            self.k_scale, self.v_scale,
-                            jnp.asarray(tables), jnp.asarray(positions),
-                            jnp.asarray(toks))
+                    fn = self._step_fn("decode", self._frozen)
+                    out = fn(
+                        self.params, *self.kv,
+                        jnp.asarray(tables), jnp.asarray(positions),
+                        jnp.asarray(toks))
+                    n_kv = len(self.kv)
+                    logits, self.kv, counts = out[0], out[1:1 + n_kv], \
+                        out[1 + n_kv:]
                 with self._span("serve.decode.wait") as wait:
                     logits = np.asarray(logits)  # noqa: PTA006 -- step boundary: sampled tokens must reach the scheduler
+                    if counts:
+                        self._note_work(sp, **self.model.counted(
+                            "decode", counts, [s.n_cached + 1 for s in rows]))
                 faults.inject("serve.decode.logits", rids=rids,
                               logits=logits)
             except PoisonError as e:
@@ -1605,11 +1645,13 @@ class InferenceEngine:
                     break
                 with self._launch_span("serve.draft.launch",
                                        ("draft", bucket)) as launch:
-                    fn = self._step_fn("decode", self._draft_frozen)
-                    dl, self.k_draft, self.v_draft = fn(
-                        self.draft_params, self.k_draft, self.v_draft,
+                    fn = self._step_fn("decode", self._draft_frozen,
+                                       quant=False)
+                    res = fn(
+                        self.draft_params, *self.kv_draft,
                         jnp.asarray(tables), jnp.asarray(positions),
                         jnp.asarray(toks))
+                    dl, self.kv_draft = res[0], res[1:]
                 with self._span("serve.draft.wait") as wait:
                     dl = np.asarray(dl)  # noqa: PTA006 -- host-chained: each draft argmax feeds the next draft step
                 self._mark_compiled("draft", bucket, wait.t1 - launch.t0)
@@ -1647,22 +1689,12 @@ class InferenceEngine:
                     faults.inject("serve.decode.poison", rids=rids)
                     with self._launch_span("serve.verify.launch",
                                            key) as launch:
-                        if self.k_scale is None:
-                            fn = self._step_fn("verify", self._frozen)
-                            (out, clen, fin, self.k_pool,
-                             self.v_pool) = fn(
-                                self.params, self.k_pool, self.v_pool,
-                                jnp.asarray(tables), jnp.asarray(qstart),
-                                jnp.asarray(t_live), jnp.asarray(fed))
-                        else:
-                            fn = self._step_fn("verify", self._frozen,
-                                               quant=True)
-                            (out, clen, fin, self.k_pool, self.v_pool,
-                             self.k_scale, self.v_scale) = fn(
-                                self.params, self.k_pool, self.v_pool,
-                                self.k_scale, self.v_scale,
-                                jnp.asarray(tables), jnp.asarray(qstart),
-                                jnp.asarray(t_live), jnp.asarray(fed))
+                        fn = self._step_fn("verify", self._frozen)
+                        res = fn(
+                            self.params, *self.kv,
+                            jnp.asarray(tables), jnp.asarray(qstart),
+                            jnp.asarray(t_live), jnp.asarray(fed))
+                        (out, clen, fin), self.kv = res[:3], res[3:]
                     with self._span("serve.verify.wait") as wait:
                         out = np.asarray(out)  # noqa: PTA006 -- step boundary: verified tokens must reach the scheduler
                         clen = np.asarray(clen)  # noqa: PTA006 -- accept lengths gate the host-side commit loop
@@ -2156,8 +2188,7 @@ class InferenceEngine:
             "pool_blocks": self.serve.num_blocks - 1,
             "mp": self.mp,
             "pool_bytes_per_rank": pool_bytes_per_rank(
-                (self.k_pool, self.v_pool, self.k_scale, self.v_scale,
-                 self.k_draft, self.v_draft), self.mp),
+                self.kv + self.kv_draft, self.mp),
             "rejected": len(self.rejected),
             "shed": len(self.shed),
             "failed": len(self.failed),

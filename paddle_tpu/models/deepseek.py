@@ -1,0 +1,562 @@
+"""DeepSeek-V3's decoder on the serving path: multi-head latent attention
+(MLA) over ONE paged latent pool, one leading dense SwiGLU layer, expert
+layers with sigmoid group-limited routing that hold a stated share of the
+routed experts, YaRN rope. Serving only (``InferenceEngine``): one chip,
+bf16, greedy, chunked prefill and batched decode.
+
+Equations (pre-norm, RMSNorm, untied head; per layer x <- x + Attn(norm(x)),
+x <- x + FFN(norm(x))):
+
+MLA. c_q = RMSNorm(y W_qa); [q_nope | q_pe] = c_q W_qb per head;
+[c_kv | k_pe] = y W_kva; c_kv <- RMSNorm(c_kv); k_pe, q_pe <- RoPE (one key
+for all heads). The cache holds, per token and layer, c_kv after its norm and
+k_pe after its rope: ``kv_lora_rank + qk_rope_head_dim`` values, nothing
+else. The program attends in the ABSORBED form: q' = q_nope W_kvb^K (per
+head, nope -> rank), score = (q' . c_kv + q_pe . k_pe) * scale, o_lat =
+P c_kv, o = o_lat W_kvb^V, the same function as expanding [k_nope | v] =
+c_kv W_kvb first (a test says so). scale = (nope + rope)^-1/2 * mscale^2,
+mscale = 0.1 * mscale_all_dim * ln(factor) + 1.
+
+RoPE is YaRN on the rope dimensions: inverse frequencies blended between
+interpolated (1 / (factor * base^(2i/d))) and original by the linear ramp
+over the correction range of (beta_fast, beta_slow); cos and sin carry
+mscale / mscale_all_dim. DEPARTURE: the rope dimensions pair in the
+half-rotation layout (i with i + d/2) where the published code pairs adjacent
+elements; with seeded weights the two are one model up to a permutation of
+W_qb's and W_kva's rope columns.
+
+Expert layer. s = sigmoid(y W_g) in float32 over ALL ``n_routed_experts``;
+selection on s' = s + bias: a group's score is the sum of its two largest
+s', the ``topk_group`` best groups stay, the ``num_experts_per_tok`` largest
+s' inside them are taken (DEPARTURE: the others are masked to -inf, where the
+published code fills 0.0; they differ only if a kept s' is negative); weights
+w_i = s_i / sum_j s_j * routed_scaling_factor over all selected (the s
+without the bias). FFN(y) = sum_i w_i E_i(y) + E_shared(y). THIS CHIP'S
+SHARE: the sum runs over the selected experts that are held here
+(``expert_offset`` .. ``expert_offset + n_local_experts``) plus the shared
+expert; what the absent experts would add is left out and the partial result
+goes on. No code stands in for the absent chips or their exchange.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from ..ops.grouped_matmul import grouped_matmul_live, tile_schedule
+from ..ops.rms_norm import fused_rms_norm
+from ..ops.rope import apply_rope
+from .llama import _chunk_window, _pin_pool_layout, _pool_write_chunk
+
+_LOG2E = 1.4426950408889634
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepSeekConfig:
+    vocab_size: int = 129280
+    hidden_size: int = 7168
+    intermediate_size: int = 18432          # the dense layers' SwiGLU
+    moe_intermediate_size: int = 2048       # one expert's
+    num_hidden_layers: int = 61
+    first_k_dense_replace: int = 3
+    num_attention_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 256             # what the router scores
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    routed_scaling_factor: float = 2.5
+    # this chip's share of the routed experts: ids offset .. offset + local
+    expert_offset: int = 0
+    n_local_experts: int = 256
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_factor: float = 40.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    dtype: Any = jnp.bfloat16
+
+    @property
+    def latent_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def softmax_scale(self) -> float:
+        m = 0.1 * self.rope_mscale_all_dim * math.log(self.rope_factor) + 1.0 \
+            if self.rope_factor > 1 else 1.0
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def deepseek_tiny(**over) -> DeepSeekConfig:
+    """A size the CPU runs in seconds, every kind of layer present: one
+    dense and two expert layers, 8 of 32 experts held, 4 groups."""
+    base = dict(vocab_size=256, hidden_size=64, intermediate_size=128,
+                moe_intermediate_size=32, num_hidden_layers=3,
+                first_k_dense_replace=1, num_attention_heads=4,
+                q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=32,
+                num_experts_per_tok=4, n_group=4, topk_group=2,
+                expert_offset=0, n_local_experts=8, rope_original_max=64)
+    return DeepSeekConfig(**dict(base, **over))
+
+
+def attn_shapes(c: DeepSeekConfig) -> Dict[str, tuple]:
+    nh, h = c.num_attention_heads, c.hidden_size
+    return {
+        "input_norm": (h,), "q_a": (h, c.q_lora_rank),
+        "q_a_norm": (c.q_lora_rank,),
+        "q_b": (c.q_lora_rank,
+                nh * (c.qk_nope_head_dim + c.qk_rope_head_dim)),
+        "kv_a": (h, c.latent_width), "kv_a_norm": (c.kv_lora_rank,),
+        "kv_b": (c.kv_lora_rank, nh * (c.qk_nope_head_dim + c.v_head_dim)),
+        "o_proj": (nh * c.v_head_dim, h), "post_norm": (h,),
+    }
+
+
+def param_shapes(c: DeepSeekConfig) -> Dict[str, Any]:
+    """The tree ``InferenceEngine`` takes: the leading dense layers as a
+    list, the expert layers stacked on axis 0 (two stacks: they do not fit
+    one ``(n, ...)`` leaf). Norm scales end in ``norm``; ``router_bias`` is
+    the selection's correction bias."""
+    h, i, e = c.hidden_size, c.intermediate_size, c.moe_intermediate_size
+    n, el = c.n_moe_layers, c.n_local_experts
+    attn = attn_shapes(c)
+    sh = c.n_shared_experts * e
+    return {
+        "embed": (c.vocab_size, h),
+        "dense": [dict(attn, gate_proj=(h, i), up_proj=(h, i),
+                       down_proj=(i, h))
+                  for _ in range(c.first_k_dense_replace)],
+        "moe": dict(
+            {k: (n,) + s for k, s in attn.items()},
+            router=(n, h, c.n_routed_experts),
+            router_bias=(n, c.n_routed_experts),
+            experts={"gate": (n, el, h, e), "up": (n, el, h, e),
+                     "down": (n, el, e, h)},
+            shared={"gate": (n, h, sh), "up": (n, h, sh),
+                    "down": (n, sh, h)}),
+        "final_norm": (h,),
+        "lm_head": (h, c.vocab_size),
+    }
+
+
+def init_deepseek_params(c: DeepSeekConfig, seed: int = 0, std: float = 0.02):
+    """Seeded weights in the tree of :func:`param_shapes`: matrices and the
+    correction bias normal x ``std``, norm scales one."""
+    shapes = param_shapes(c)
+    flat, tree = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(flat))
+    out = []
+    for k, (path, shape) in zip(keys, flat):
+        name = str(getattr(path[-1], "key", ""))
+        out.append(jnp.ones(shape, c.dtype) if name.endswith("norm")
+                   else (jax.random.normal(k, shape, jnp.float32)
+                         * std).astype(c.dtype))
+    return jax.tree_util.tree_unflatten(tree, out)
+
+
+def init_latent_pool(c: DeepSeekConfig, num_blocks: int, block_size: int):
+    """The whole cache: ONE latent pool [L, NP, kv_lora_rank +
+    qk_rope_head_dim, block_size], time in lanes, block 0 the null block."""
+    return jnp.zeros((c.num_hidden_layers, num_blocks, c.latent_width,
+                      block_size), c.dtype)
+
+
+# -- rope ------------------------------------------------------------------------
+
+def yarn_inv_freq(c: DeepSeekConfig):
+    """[rope/2] inverse frequencies: interpolated below the correction
+    range, original above it, a linear ramp between."""
+    d, base = c.qk_rope_head_dim, c.rope_theta
+    pos_freqs = base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    extra, inter = 1.0 / pos_freqs, 1.0 / (c.rope_factor * pos_freqs)
+    if c.rope_factor <= 1:
+        return extra
+
+    def correction_dim(rotations):
+        return d * math.log(c.rope_original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+    low = max(math.floor(correction_dim(c.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(c.rope_beta_slow)), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def yarn_cos_sin(c: DeepSeekConfig, positions):
+    """cos, sin [..., rope/2] f32 at integer ``positions``."""
+    ang = positions.astype(jnp.float32)[..., None] * yarn_inv_freq(c)
+
+    def get_mscale(m):
+        return 0.1 * m * math.log(c.rope_factor) + 1.0 \
+            if c.rope_factor > 1 else 1.0
+    ms = get_mscale(c.rope_mscale) / get_mscale(c.rope_mscale_all_dim)
+    return jnp.cos(ang) * ms, jnp.sin(ang) * ms
+
+
+# -- attention -------------------------------------------------------------------
+
+def mla_project(p, x, cos, sin, c: DeepSeekConfig):
+    """x [T, H] (normed) at the positions of cos/sin [T, rope/2] ->
+    (q_nope [T, NH, nope], q_pe [T, NH, rope] roped, latent [T, W]: the
+    normed compressed KV, then the roped shared key)."""
+    t = x.shape[0]
+    nh, dn, dr = c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
+    cq = fused_rms_norm(x @ p["q_a"], p["q_a_norm"], c.rms_norm_eps)
+    q = (cq @ p["q_b"]).reshape(t, nh, dn + dr)
+    q_nope, q_pe = q[..., :dn], q[..., dn:]
+    kv = x @ p["kv_a"]
+    ckv = fused_rms_norm(kv[:, :c.kv_lora_rank], p["kv_a_norm"],
+                         c.rms_norm_eps)
+    k_pe = apply_rope(kv[None, :, None, c.kv_lora_rank:], cos, sin)[0, :, 0]
+    q_pe = apply_rope(q_pe[None], cos, sin)[0]
+    return q_nope, q_pe, jnp.concatenate([ckv, k_pe], axis=-1)
+
+
+def _kv_b_heads(p, c: DeepSeekConfig):
+    """W_kvb by head: (W^K [rank, NH, nope], W^V [rank, NH, v])."""
+    w = p["kv_b"].reshape(c.kv_lora_rank, c.num_attention_heads,
+                          c.qk_nope_head_dim + c.v_head_dim)
+    return w[..., :c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
+
+
+def absorbed_queries(p, q_nope, q_pe, c: DeepSeekConfig):
+    """[T, NH, W] queries against the latent: q_nope through W_kvb^K, then
+    q_pe; PRE-SCALED by scale * log2(e) for the kernels' exp2 softmax."""
+    wk, _ = _kv_b_heads(p, c)
+    q_abs = jnp.einsum("thd,chd->thc", q_nope, wk,
+                       preferred_element_type=jnp.float32)
+    q = jnp.concatenate([q_abs, q_pe.astype(jnp.float32)], axis=-1)
+    return (q * (c.softmax_scale * _LOG2E)).astype(c.dtype)
+
+
+def latent_out(p, o_lat, c: DeepSeekConfig):
+    """o_lat [T, NH, rank] -> the attention's output [T, H] f32: through
+    W_kvb^V per head, then W_o."""
+    _, wv = _kv_b_heads(p, c)
+    o = jnp.einsum("thc,chd->thd", o_lat.astype(c.dtype), wv,
+                   preferred_element_type=jnp.float32).astype(c.dtype)
+    return jnp.dot(o.reshape(o.shape[0], -1), p["o_proj"],
+                   preferred_element_type=jnp.float32)
+
+
+def mla_expanded(p, x, cos, sin, c: DeepSeekConfig):
+    """The EXPANDED form over one whole sequence x [T, H] (normed), causal,
+    in plain jnp: what the absorbed kernels must equal. Not on the serving
+    path (tests and the builder's measurement only)."""
+    t = x.shape[0]
+    q_nope, q_pe, lat = mla_project(p, x, cos, sin, c)
+    wk, wv = _kv_b_heads(p, c)
+    ckv, k_pe = lat[:, :c.kv_lora_rank], lat[:, c.kv_lora_rank:]
+    k_nope = jnp.einsum("tc,chd->thd", ckv, wk)
+    v = jnp.einsum("tc,chd->thd", ckv, wv)
+    s = (jnp.einsum("qhd,khd->hqk", q_nope, k_nope,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("qhd,kd->hqk", q_pe, k_pe,
+                      preferred_element_type=jnp.float32)) * c.softmax_scale
+    mask = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+    pr = jax.nn.softmax(jnp.where(mask[None], s, -jnp.inf), axis=-1)
+    o = jnp.einsum("hqk,khd->qhd", pr.astype(c.dtype), v,
+                   preferred_element_type=jnp.float32).astype(c.dtype)
+    return jnp.dot(o.reshape(t, -1), p["o_proj"],
+                   preferred_element_type=jnp.float32)
+
+
+# -- feed-forward ----------------------------------------------------------------
+
+def rms_norm(x, w, eps):
+    """RMSNorm of the float32 residual stream, in float32 (plain jnp: XLA
+    fuses it; ``fused_rms_norm``'s windows are sized for bf16 rows)."""
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
+        * w.astype(jnp.float32)
+
+
+def swiglu(x, gate, up, down):
+    """x [T, H] in the weights' dtype -> [T, H] f32 (the last product's
+    accumulator, unrounded)."""
+    return jnp.dot(jax.nn.silu(x @ gate) * (x @ up), down,
+                   preferred_element_type=jnp.float32)
+
+
+def route(y, router, bias, c: DeepSeekConfig):
+    """y [T, H] -> (idx [T, k] i32 over ALL routed experts, w [T, k] f32):
+    sigmoid scores in float32, selection on score + bias limited to the
+    best groups, weights from the scores without the bias, normalised over
+    all selected and scaled."""
+    s = jax.nn.sigmoid(jnp.dot(y.astype(jnp.float32),
+                               router.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST))
+    sel = s + bias.astype(jnp.float32)
+    t, e = sel.shape
+    g = sel.reshape(t, c.n_group, e // c.n_group)
+    group_score = lax.top_k(g, 2)[0].sum(-1)                   # [T, G]
+    _, best = lax.top_k(group_score, c.topk_group)
+    keep = jnp.zeros((t, c.n_group), bool).at[
+        jnp.arange(t)[:, None], best].set(True)
+    masked = jnp.where(keep[:, :, None], g, -jnp.inf).reshape(t, e)
+    _, idx = lax.top_k(masked, c.num_experts_per_tok)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * c.routed_scaling_factor
+    return idx.astype(jnp.int32), w
+
+
+def _expert_tile(t: int) -> int:
+    """Row tile of the grouped matmul: the MXU's for a chunk, one packed
+    bf16 sublane tile for a decode batch (an expert's few rows pad to it)."""
+    return 128 if t >= 128 else 16
+
+
+def moe_ffn(y, live, p, experts, slot, c: DeepSeekConfig):
+    """y [T, H] f32, the normed residual stream: the router reads it as it
+    is, the experts after rounding to the weights' dtype; live [T] bool
+    (padding tokens route nowhere); p the layer's
+    router, bias and shared expert; ``experts`` the stacked routed experts
+    of ALL expert layers ({gate, up: [n, El, H, I], down: [n, El, I, H]})
+    and ``slot`` this layer's index in them (the stack goes to the grouped
+    matmul whole, so no layer's experts are sliced out and copied).
+    Returns (FFN(y) [T, H] f32, counts [4] i32: (live token, expert) pairs,
+    those routed to experts held here, held experts with at least one row,
+    rows of the busiest)."""
+    t, h = y.shape
+    k, el = c.num_experts_per_tok, c.n_local_experts
+    tile = _expert_tile(t)
+    with jax.named_scope("moe.route"):
+        idx, w = route(y, p["router"], p["router_bias"], c)
+        y = y.astype(c.dtype)
+        local = idx - c.expert_offset
+        held = (local >= 0) & (local < el) & live[:, None]     # [T, k]
+        e_flat = jnp.where(held, local, el).reshape(-1)         # [P]
+        onehot = (e_flat[:, None] == jnp.arange(el)[None, :]).astype(
+            jnp.int32)                                          # [P, El]
+        counts = onehot.sum(0)
+        m = -(-(t * k) // tile) * tile + el * tile
+        n_tiles = m // tile
+        tile_e, live_t, first, last, offsets = tile_schedule(
+            counts, n_tiles, tile)
+        e_safe = jnp.minimum(e_flat, el - 1)
+        within = jnp.take_along_axis(jnp.cumsum(onehot, axis=0),
+                                     e_safe[:, None], axis=1)[:, 0] - 1
+        dest = jnp.where(held.reshape(-1), offsets[e_safe] + within, m)
+        row_tok = jnp.zeros((m,), jnp.int32).at[dest].set(
+            jnp.repeat(jnp.arange(t, dtype=jnp.int32), k), mode="drop")
+        sched = (tile_e + slot * el, live_t, first, last)
+        n_live = offsets[el] // tile
+    with jax.named_scope("moe.experts"):
+        stack = lambda a: a.reshape((-1,) + a.shape[2:])
+        x = jnp.take(y, row_tok, axis=0)
+        g = grouped_matmul_live(x, stack(experts["gate"]), sched, n_live,
+                                tile)
+        u = grouped_matmul_live(x, stack(experts["up"]), sched, n_live, tile)
+        d = grouped_matmul_live((jax.nn.silu(g) * u).astype(y.dtype),
+                                stack(experts["down"]), sched, n_live, tile)
+        # rows no tile wrote are garbage: select, never multiply by zero
+        rows = jnp.take(d, jnp.minimum(dest, m - 1), axis=0).reshape(t, k, h)
+        out = jnp.sum(jnp.where(held[:, :, None],
+                                rows.astype(jnp.float32) * w[:, :, None],
+                                0.0), axis=1)
+    with jax.named_scope("moe.shared"):
+        sh = p["shared"]
+        shared = swiglu(y, sh["gate"], sh["up"], sh["down"])
+    stats = jnp.stack([live.sum() * k, counts.sum(), (counts > 0).sum(),
+                       counts.max()])
+    return out + shared, stats.astype(jnp.int32)
+
+
+# -- the paged steps -------------------------------------------------------------
+
+def _layers(params, c: DeepSeekConfig, attend, h, pool, live):
+    """Every layer over the residual stream h [T, H], held in FLOAT32 (a
+    sub-layer's inputs are rounded to the weights' dtype, its last product's
+    float32 accumulator is added unrounded: a bf16 stream's rounding decides
+    the router's near-ties the other way more often than the float32
+    reference's, and with a share of the experts held a flipped choice is not
+    made up by the others), with the pool as a carry: the leading dense
+    layers one by one, the expert layers in a scan. ``attend(p, x, pool,
+    layer) -> (attention output [T, H], pool)``. Returns (h, pool, counts
+    [n_moe, 4] i32)."""
+    def attn(p, h, pool, layer):
+        x = rms_norm(h, p["input_norm"], c.rms_norm_eps).astype(c.dtype)
+        a, pool = attend(p, x, pool, layer)
+        h = h + a
+        return h, rms_norm(h, p["post_norm"], c.rms_norm_eps), pool
+
+    for i, p in enumerate(params["dense"]):
+        h, y, pool = attn(p, h, pool, jnp.int32(i))
+        with jax.named_scope("ffn.dense"):
+            h = h + swiglu(y.astype(c.dtype), p["gate_proj"], p["up_proj"],
+                           p["down_proj"])
+    n_dense = len(params["dense"])
+    moe = params["moe"]
+    experts = moe["experts"]
+    scanned = {k: v for k, v in moe.items() if k != "experts"}
+
+    def moe_layer(carry, xs):
+        h, pool = carry
+        p, slot = xs
+        h, y, pool = attn(p, h, pool, slot + n_dense)
+        f, stats = moe_ffn(y, live, p, experts, slot, c)
+        return (h + f, pool), stats
+
+    n = experts["gate"].shape[0]
+    (h, pool), counts = lax.scan(
+        moe_layer, (h, pool), (scanned, jnp.arange(n, dtype=jnp.int32)))
+    return h, pool, counts
+
+
+def _logits(params, h, c: DeepSeekConfig):
+    with jax.named_scope("lm_head"):
+        x = rms_norm(h, params["final_norm"], c.rms_norm_eps).astype(c.dtype)
+        return jnp.dot(x, params["lm_head"],
+                       preferred_element_type=jnp.float32)
+
+
+def deepseek_paged_decode_step(params, pool, tables, positions, ids,
+                               c: DeepSeekConfig):
+    """One decode step over the paged latent pool: ids [B], tables
+    [B, max_nb], positions [B] = the slot each row's new token takes.
+    Padding rows point their tables at the null block 0 (position 0) and
+    route to no expert. Returns (logits [B, vocab] f32, pool, counts)."""
+    from ..ops.paged_attention import mla_paged_decode
+    h = jnp.take(params["embed"], ids, axis=0).astype(jnp.float32)
+    cos, sin = yarn_cos_sin(c, positions)
+    live = tables[:, 0] > 0
+
+    def attend(p, x, pool, layer):
+        with jax.named_scope("mla.project"):
+            q_nope, q_pe, lat = mla_project(p, x, cos, sin, c)
+            q = absorbed_queries(p, q_nope, q_pe, c)
+        with jax.named_scope("mla.attend"):
+            o_lat, pool = mla_paged_decode(
+                q, lat.astype(pool.dtype), pool, tables, positions, layer,
+                rank=c.kv_lora_rank)
+        with jax.named_scope("mla.project"):
+            return latent_out(p, o_lat, c), pool
+
+    h, pool, counts = _layers(params, c, attend, h, pool, live)
+    return _logits(params, h, c), pool, counts
+
+
+def deepseek_paged_prefill_chunk(params, pool, table_row, start, ids, n_live,
+                                 c: DeepSeekConfig):
+    """One chunked-prefill slice of ONE sequence: ids [C] padded to the
+    chunk, ``n_live`` real tokens, ``start`` tokens already cached. Writes
+    the chunk's latent columns into the sequence's blocks (padding lands in
+    the null block), attends the live context through the block table and
+    returns (logits [vocab] f32 of the last real token, pool, counts)."""
+    from ..ops.paged_attention import mla_paged_prefill
+    C = ids.shape[0]
+    h = jnp.take(params["embed"], ids, axis=0).astype(jnp.float32)
+    cos, sin = yarn_cos_sin(c, start + jnp.arange(C, dtype=jnp.int32))
+    live = jnp.arange(C) < n_live
+    wbid, fresh, window = _chunk_window(table_row, start, n_live, C,
+                                        pool.shape[-1])
+
+    def attend(p, x, pool, layer):
+        with jax.named_scope("mla.project"):
+            q_nope, q_pe, lat = mla_project(p, x, cos, sin, c)
+            q = absorbed_queries(p, q_nope, q_pe, c)
+        with jax.named_scope("mla.attend"):
+            pool = _pool_write_chunk(_pin_pool_layout(pool), layer, wbid,
+                                     fresh, window(lat.astype(pool.dtype)))
+            o_lat = mla_paged_prefill(q, pool, table_row, start, n_live,
+                                      layer, rank=c.kv_lora_rank)
+        with jax.named_scope("mla.project"):
+            return latent_out(p, o_lat, c), pool
+
+    h, pool, counts = _layers(params, c, attend, h, pool, live)
+    h_last = lax.dynamic_slice_in_dim(h, n_live - 1, 1, 0)
+    return _logits(params, h_last, c)[0], pool, counts
+
+
+# -- what InferenceEngine asks of a model ----------------------------------------
+
+@functools.lru_cache(maxsize=8)
+def _jitted_paged_decode(c: DeepSeekConfig):
+    def paged_decode_fn(params, pool, tables, positions, ids):
+        return deepseek_paged_decode_step(params, pool, tables, positions,
+                                          ids, c)
+    paged_decode_fn.__name__ = "paged_decode_step_mla"
+    return jax.jit(paged_decode_fn, donate_argnums=(1,))
+
+
+@functools.lru_cache(maxsize=8)
+def _jitted_paged_prefill(c: DeepSeekConfig):
+    def paged_prefill_fn(params, pool, table_row, start, ids, n_live):
+        return deepseek_paged_prefill_chunk(params, pool, table_row, start,
+                                            ids, n_live, c)
+    paged_prefill_fn.__name__ = "paged_prefill_chunk_mla"
+    return jax.jit(paged_prefill_fn, donate_argnums=(1,))
+
+
+class DeepSeekServing:
+    """What ``InferenceEngine`` asks of a model (``engine._LlamaServing`` is
+    Llama's): the frozen config, the cache (ONE latent pool), the jitted
+    programs, which return ``counts`` after the cache, and the registry
+    counters those feed."""
+
+    # span argument -> registry counter (paddle_tpu_serve_<name>)
+    work = {"pairs": "moe_pairs_total",
+            "local_pairs": "moe_local_pairs_total",
+            "experts_hit": "moe_expert_hits_total",
+            "busiest_rows": "moe_busiest_rows_total",
+            "mla_decode_ctx": "mla_decode_ctx_tokens_total",
+            "mla_prefill_ctx": "mla_prefill_ctx_tokens_total"}
+
+    @staticmethod
+    def refuse(*, mp, kv_dtype, speculative, draft) -> None:
+        """Out of scope for this model, refused rather than half-done."""
+        for on, what in ((mp > 1, "ServeConfig.mp > 1 (tensor-parallel "
+                                   "serving shards kv heads; the latent "
+                                   "pool has none)"),
+                         (kv_dtype != "auto", "kv_dtype='int8' (no int8 "
+                                              "latent pool)"),
+                         (speculative or draft, "speculative decoding and "
+                                                "a draft model")):
+            if on:
+                raise NotImplementedError(
+                    f"DeepSeek serving does not support {what}")
+
+    @staticmethod
+    def freeze(config: DeepSeekConfig) -> DeepSeekConfig:
+        return config           # frozen and hashable as it is
+
+    @staticmethod
+    def init_cache(config, num_blocks, block_size, kv_dtype):
+        return (init_latent_pool(config, num_blocks, block_size),)
+
+    @staticmethod
+    def step_fn(kind, frozen, quant, mesh):
+        return {"prefill": _jitted_paged_prefill,
+                "decode": _jitted_paged_decode}[kind](frozen)
+
+    @staticmethod
+    def counted(kind, counts, ctx):
+        """The span arguments of one step: ``counts`` as the jitted step
+        returned them ([n_moe, 4] i32: (live token, expert) pairs, those
+        routed to experts held here, held experts hit, the busiest's rows;
+        summed here over the expert layers) and ``ctx``, the latent
+        columns each sequence's attention had to read, per layer."""
+        c = np.asarray(counts[0])  # noqa: PTA006 -- read inside the wait the step's logits already pay
+        pairs, local, hit, busiest = (int(x) for x in c.sum(axis=0))
+        return {"pairs": pairs, "local_pairs": local, "experts_hit": hit,
+                "busiest_rows": busiest, f"mla_{kind}_ctx": int(sum(ctx))}

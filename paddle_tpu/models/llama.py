@@ -1192,6 +1192,36 @@ def _pool_write_chunk(pool, layer, bids, fresh, tiles):
     return pool
 
 
+def _chunk_window(table_row, start, n_live, C, bs):
+    """Where a prefill chunk's columns land, for ``_pool_write_chunk``: in at
+    most n_slot consecutive table slots, written a whole [W, bs] block tile
+    at a time. Slot j of the window is table slot s0 + j, the chunk's token
+    0 sits `off` lanes into the window. Returns (wbid [n_slot] the pool
+    blocks, slots past the live span writing the null block 0 back onto
+    itself; fresh [n_slot, 1, bs] the lanes real tokens fill; window(cols):
+    [C, W] chunk columns -> [n_slot, W, bs] block tiles, time in lanes, the
+    other lanes zero and masked out by `fresh`)."""
+    max_nb = table_row.shape[0]
+    n_slot = -(-C // bs) + 1
+    s0 = start // bs
+    off = start - s0 * bs
+    slot = s0 + jnp.arange(n_slot, dtype=jnp.int32)
+    slot_live = (slot * bs < start + n_live) & (slot < max_nb)
+    wbid = jnp.where(slot_live, table_row[jnp.clip(slot, 0, max_nb - 1)],
+                     0).astype(jnp.int32)
+    lane = jnp.arange(n_slot * bs, dtype=jnp.int32).reshape(n_slot, 1, bs)
+    fresh = ((lane >= off) & (lane < off + n_live)
+             & slot_live[:, None, None])
+
+    def window(cols):
+        w = cols.shape[1]
+        win = lax.dynamic_update_slice(
+            jnp.zeros((w, n_slot * bs), cols.dtype), cols.T,
+            (jnp.int32(0), off))
+        return win.reshape(w, n_slot, bs).transpose(1, 0, 2)
+    return wbid, fresh, window
+
+
 def llama_paged_prefill_chunk(params, k_pool, v_pool, table_row, start,
                               ids, n_live, config: LlamaConfig,
                               kv_scales=None, tp=None):
@@ -1217,7 +1247,6 @@ def llama_paged_prefill_chunk(params, k_pool, v_pool, table_row, start,
     C = ids.shape[0]
     hd = c.head_dim
     bs = k_pool.shape[-1]
-    max_nb = table_row.shape[0]
     if tp is None:
         h = jnp.take(params["embed"], ids, axis=0)[None].astype(c.dtype)
     else:
@@ -1225,31 +1254,7 @@ def llama_paged_prefill_chunk(params, k_pool, v_pool, table_row, start,
     pidx = start + jnp.arange(C, dtype=jnp.int32)          # [C] positions
     cos, sin = build_rope_cache(C, hd, base=c.rope_theta,
                                 position_ids=pidx)         # [C, hd/2]
-    # The chunk's columns land in at most n_slot consecutive table slots,
-    # written a whole [KVD, bs] block tile at a time (see _pool_write_chunk):
-    # slot j of the window is table slot s0 + j, the chunk's token 0 sits
-    # `off` lanes into the window, `fresh` marks the lanes real tokens fill.
-    # Slots past the live span write the null block 0 back onto itself.
-    n_slot = -(-C // bs) + 1
-    s0 = start // bs
-    off = start - s0 * bs
-    slot = s0 + jnp.arange(n_slot, dtype=jnp.int32)
-    slot_live = (slot * bs < start + n_live) & (slot < max_nb)
-    wbid = jnp.where(slot_live, table_row[jnp.clip(slot, 0, max_nb - 1)],
-                     0).astype(jnp.int32)
-    lane = jnp.arange(n_slot * bs, dtype=jnp.int32).reshape(n_slot, 1, bs)
-    fresh = ((lane >= off) & (lane < off + n_live)
-             & slot_live[:, None, None])
-
-    def window(cols):
-        """[C, W] chunk columns -> [n_slot, W, bs] block tiles (time in
-        lanes), the chunk placed `off` lanes in; other lanes are zero and
-        masked out by `fresh`."""
-        w = cols.shape[1]
-        win = lax.dynamic_update_slice(
-            jnp.zeros((w, n_slot * bs), cols.dtype), cols.T,
-            (jnp.int32(0), off))
-        return win.reshape(w, n_slot, bs).transpose(1, 0, 2)
+    wbid, fresh, window = _chunk_window(table_row, start, n_live, C, bs)
 
     def layer_step(carry, xs):
         if kv_scales is None:

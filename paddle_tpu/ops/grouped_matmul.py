@@ -325,3 +325,44 @@ def _grouped_matmul_bwd(tile_rows, res, g):
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def grouped_matmul_live(lhs, rhs, sched, n_live, tile_rows=TILE_ROWS):
+    """Forward-only ``grouped_matmul`` over the LIVE tiles alone: the tile
+    axis of the grid is the traced ``n_live`` (the schedule's live tiles come
+    first), so dead tiles fetch neither rows nor expert weights, an expert
+    with no row costs no weight traffic, and a call with no live tile moves
+    nothing. Rows past the last live tile are left UNWRITTEN: the caller
+    must never read them (serving combines by gathering live rows only)."""
+    m, k = lhs.shape
+    E, _, n = rhs.shape
+    assert m % tile_rows == 0, (lhs.shape, tile_rows)
+    out_dtype = jnp.promote_types(lhs.dtype, rhs.dtype)
+    block_n = _fit_block(n, jnp.dtype(rhs.dtype).itemsize, k)
+    it = jnp.dtype(lhs.dtype).itemsize
+    kernel = functools.partial(_gmm_kernel, out_dtype=out_dtype)
+    with _mosaic_ctx():
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4,
+                grid=(n // block_n, jnp.asarray(n_live, jnp.int32)),
+                in_specs=[
+                    pl.BlockSpec((tile_rows, k),
+                                 lambda nb, t, e, lv, f, l: (t, 0)),
+                    pl.BlockSpec((1, k, block_n),
+                                 lambda nb, t, e, lv, f, l: (e[t], 0, nb)),
+                ],
+                out_specs=pl.BlockSpec(
+                    (tile_rows, block_n),
+                    lambda nb, t, e, lv, f, l: (t, nb)),
+            ),
+            out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+            # priced at every tile live, as the static grid is
+            cost_estimate=_cost_estimate(
+                flops=2 * m * k * n,
+                bytes_accessed=(m * k + E * k * n) * it
+                + m * n * jnp.dtype(out_dtype).itemsize,
+                name="gmm.fwd_live"),
+            interpret=_interpret(),
+        )(*_sched_i32(sched), lhs, rhs)

@@ -1555,3 +1555,327 @@ def paged_verify_commit_quant(new_k, new_v, new_ks, new_vs, k_pool,
           new_ks[..., None], new_vs[..., None],
           k_pool, v_pool, k_scale, v_scale)
     return kp, vp, ks, vs
+
+
+# -- latent attention (MLA) over one latent pool ------------------------------
+#
+# Multi-head latent attention keeps ONE pool [L, NP, W, bs]: per cached token
+# and layer the compressed KV after its norm (rows 0 .. rank - 1) and the one
+# rope key all heads share (rows rank .. W - 1). In the absorbed form every
+# head's query is [W] wide (q_nope folded through W_kvb^K, then q_pe), the
+# keys are the block tile's W rows and the values its first ``rank`` rows:
+# one tile read serves both products, and the output is the latent [rank]
+# vector that W_kvb^V expands outside. W = 576 is no multiple of 128 and so
+# lies on the sublane axis, time in lanes as in the K/V pools.
+#
+# Both kernels run a DYNAMIC grid: the decode's flat schedule has exactly the
+# live (sequence, block) steps, the prefill chunk's table axis ends at the
+# chunk's last live block, so a long table costs nothing where the context is
+# short. Softmax is the online recurrence in exp2 (queries arrive pre-scaled
+# by scale * log2(e)); statistics f32, probabilities cast to the pool's dtype
+# before PV.
+
+MLA_VMEM_LIMIT = 64 * 1024 * 1024
+MLA_PREFILL_BUDGET = 32 * 1024 * 1024
+MLA_PREFILL_SLOTS = 4          # table slots a grid step of the prefill walks
+
+
+def _online_step(s, tile, m_s, l_s, acc_s, rank):
+    """One block's scores ``s`` [R, bs] f32 (masked) into the running max,
+    sum and latent accumulator [R, rank]."""
+    m_prev = m_s[:, :1]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    alpha = jnp.exp2(m_prev - m_new)
+    p = jnp.exp2(s - m_new)
+    m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
+    l_s[...] = l_s[...] * alpha + jnp.broadcast_to(
+        p.sum(axis=-1, keepdims=True), l_s.shape)
+    acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+        p.astype(tile.dtype), tile[:rank, :], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _mla_decode_kernel(lp_ref, sc_ref, q_ref, new_ref, c_ref, o_ref, co_ref,
+                       m_s, l_s, acc_s, *, block_size, rank):
+    j = pl.program_id(0)
+    pos = sc_ref[_POS, j]
+    start = sc_ref[_START, j]
+    col = sc_ref[_COL, j]
+    upd = sc_ref[_LAST, j] == np.int32(1)   # the new token's block IS the last
+    w = q_ref.shape[2]
+
+    @pl.when(sc_ref[_FIRST, j] == np.int32(1))
+    def _init():
+        m_s[...] = jnp.full(m_s.shape, -1e30, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    def chain(tile):
+        s = jax.lax.dot_general(
+            q_ref[0], tile, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [NH, bs]
+        t = start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        _online_step(jnp.where(t <= pos, s, jnp.float32(-1e30)), tile,
+                     m_s, l_s, acc_s, rank)
+
+    @pl.when(upd)
+    def _updated():
+        # the new column arrives lane-major and padded to whole lane tiles
+        # (see _column_tile); the full tile is written: the aliased out
+        # window starts uninitialized
+        lane = lax.broadcasted_iota(jnp.int32, (w, block_size), 1)
+        tile = jnp.where(lane == col,
+                         _column_tile(new_ref[0], block_size)[:w],
+                         c_ref[0, 0].astype(jnp.float32)).astype(co_ref.dtype)
+        co_ref[0, 0] = tile
+        chain(tile)
+        o_ref[0] = acc_s[...] / jnp.maximum(l_s[:, :1], jnp.float32(1e-30))
+
+    @pl.when(jnp.logical_not(upd))
+    def _raw():
+        chain(c_ref[0, 0])
+
+
+def mla_paged_decode(q, new_col, pool, tables, positions, layer, *, rank):
+    """Fused pool-update + absorbed latent attention for one decode layer.
+
+    q [B, NH, W] PRE-SCALED by scale*log2(e) (W = rank + rope dims: the
+    absorbed nope query, then the roped query); new_col [B, W] the new
+    token's latent column (normed compressed KV, then the roped shared key);
+    pool [L, NP, W, bs]; tables [B, max_nb] i32; positions [B] i32 = the NEW
+    token's position per row (its block must already be in the table; padding
+    rows point at the null block 0 with position 0). Every row writes its
+    column IN PLACE (the pool aliases through the call) and attends over its
+    prefix including it. Returns (o_lat [B, NH, rank] f32, pool)."""
+    b, nh, w = q.shape
+    L, NP, _, bs = pool.shape
+    B, max_nb = tables.shape
+    n_steps = B * max_nb
+    it = jnp.dtype(pool.dtype).itemsize
+    sched = paged_schedule(positions + 1, tables, n_steps, bs)
+    # the grid: the live steps alone (int32: the package turns x64 on)
+    total = jnp.sum(sched[_LIVE], dtype=jnp.int32)
+    lp = jnp.asarray([layer], jnp.int32)
+    wp = -(-w // 128) * 128
+    new = jnp.pad(new_col, ((0, 0), (0, wp - w)))[:, None]
+
+    def c_map(j, lp_ref, sc_ref):
+        return (lp_ref[0], sc_ref[_BLK, j], 0, 0)
+
+    def q_map(j, lp_ref, sc_ref):
+        return (sc_ref[_SEQ, j], 0, 0)
+
+    def upd_map(j, lp_ref, sc_ref):
+        return (lp_ref[0], sc_ref[_UBLK, j], 0, 0)
+
+    kernel = functools.partial(_mla_decode_kernel, block_size=bs, rank=rank)
+    with _mosaic_ctx():
+        out, pool = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(total,),
+                in_specs=[
+                    pl.BlockSpec((1, nh, w), q_map),
+                    pl.BlockSpec((1, 1, wp), q_map),
+                    pl.BlockSpec((1, 1, w, bs), c_map),
+                ],
+                out_specs=[
+                    pl.BlockSpec((1, nh, rank), q_map),
+                    pl.BlockSpec((1, 1, w, bs), upd_map),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((nh, 128), jnp.float32),
+                    pltpu.VMEM((nh, 128), jnp.float32),
+                    pltpu.VMEM((nh, rank), jnp.float32),
+                ],
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((b, nh, rank), jnp.float32),
+                jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+            ],
+            # operands count scalar prefetch first: 0=lp, 1=sched, 2=q,
+            # 3=new, 4=pool
+            input_output_aliases={4: 1},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=MLA_VMEM_LIMIT),
+            # priced at the whole table, as the other paged kernels are; a
+            # step runs its live blocks
+            cost_estimate=_cost_estimate(
+                flops=2 * nh * (w + rank) * bs * n_steps,
+                transcendentals=nh * bs * n_steps,
+                bytes_accessed=(w * bs * it * n_steps
+                                + 2 * b * w * bs * it),
+                name="paged.mla_decode"),
+            interpret=_interpret(),
+        )(lp, sched, q, new, pool)
+    return out, pool
+
+
+def _fit_mla_prefill_tile(c, nh, w, rank, bs, itemsize):
+    """Query tokens a tile of the latent prefill attention holds (PTA002
+    contract): the largest divisor of the chunk ``c``, at most
+    PREFILL_BLOCK_Q, whose rows (tokens x heads) are whole sublane tiles and
+    whose windows (q and out tiles double-buffered, the latent tiles, the
+    scores of a step's MLA_PREFILL_SLOTS blocks) and scratch (accumulator,
+    running max and sum) fit the budget."""
+    def need(tq):
+        rows = tq * nh
+        return (2 * rows * w * itemsize + 2 * rows * rank * itemsize
+                + rows * rank * 4 + 2 * rows * 128 * 4
+                + 2 * rows * MLA_PREFILL_SLOTS * bs * 4
+                + 2 * MLA_PREFILL_SLOTS * w * bs * itemsize)
+    for tq in range(min(c, PREFILL_BLOCK_Q), 0, -1):
+        if c % tq == 0 and ((tq * nh) % 16 == 0 or tq == c) \
+                and need(tq) <= MLA_PREFILL_BUDGET:
+            return tq
+    raise ValueError(
+        f"latent prefill kernel windows need {need(1)} B VMEM a query token "
+        f"(> {MLA_PREFILL_BUDGET} B): fewer heads or a smaller latent")
+
+
+def _mla_prefill_kernel(lp_ref, blk_ref, tl_ref, q_ref, *rest, block_size,
+                        rank, nh, slots):
+    """One (query tile, group of ``slots`` table slots) step: rows are
+    (token, head) pairs, token-major, against the group's latent tiles (the
+    same pool presented once a slot). Max, sum and accumulator are rescaled
+    once a group, not once a block. A slot past the tile's frontier
+    re-presents a live block and is masked whole by its nominal positions."""
+    c_refs, (o_ref, m_s, l_s, acc_s) = rest[:slots], rest[slots:]
+    i, j = pl.program_id(0), pl.program_id(1)
+    q0 = tl_ref[_TQ0, i]
+    nblk = tl_ref[_TNBLK, i]
+    bs = block_size
+
+    @pl.when(j == 0)
+    def _init():
+        m_s[...] = jnp.full(m_s.shape, -1e30, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    def attend(masked):
+        tiles = [c[0, 0] for c in c_refs]
+        s = jnp.concatenate([jax.lax.dot_general(
+            q_ref[...], t, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) for t in tiles],
+            axis=1)                                    # [rows, slots * bs]
+        if masked:
+            qpos = q0 + lax.broadcasted_iota(jnp.int32, s.shape, 0) // nh
+            t = j * (slots * bs) + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(t <= qpos, s, jnp.float32(-1e30))
+        m_prev = m_s[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp2(m_prev - m_new)
+        p = jnp.exp2(s - m_new)
+        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
+        l_s[...] = l_s[...] * alpha + jnp.broadcast_to(
+            p.sum(axis=-1, keepdims=True), l_s.shape)
+        pv = None
+        for g, tile in enumerate(tiles):
+            d = jax.lax.dot_general(
+                p[:, g * bs:(g + 1) * bs].astype(tile.dtype), tile[:rank, :],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            pv = d if pv is None else pv + d
+        acc_s[...] = acc_s[...] * alpha + pv
+
+    live = j * slots < nblk
+    # every column of the group at or before the tile's first query
+    whole = (j + 1) * (slots * bs) - 1 <= q0
+
+    @pl.when(jnp.logical_and(live, whole))
+    def _below():
+        attend(False)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(whole)))
+    def _frontier():
+        attend(True)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _fin():
+        o_ref[...] = (acc_s[...] / jnp.maximum(
+            l_s[:, :1], jnp.float32(1e-30))).astype(o_ref.dtype)
+
+
+def mla_paged_prefill(q, pool, table_row, start, n_live, layer, *, rank):
+    """Absorbed latent attention of one sequence's prefill chunk over its
+    live context, read from the latent pool through the block table.
+
+    q [C, NH, W] PRE-SCALED by scale*log2(e), the absorbed queries of the
+    chunk's tokens at positions start .. start + C; pool [L, NP, W, bs]
+    ALREADY holding the chunk's own columns; table_row [max_nb] i32; start,
+    n_live traced scalars (n_live >= 1). Returns o_lat [C, NH, rank] in q's
+    dtype; rows past n_live are zero. The table axis of the grid walks
+    MLA_PREFILL_SLOTS slots a step and ends at the chunk's last live block."""
+    c, nh, w = q.shape
+    L, NP, _, bs = pool.shape
+    g = MLA_PREFILL_SLOTS
+    max_nb = table_row.shape[0]
+    it = jnp.dtype(pool.dtype).itemsize
+    tq = _fit_mla_prefill_tile(c, nh, w, rank, bs, it)
+    n_tiles, rows = c // tq, tq * nh
+    # the table padded to whole groups: a slot past a tile's frontier
+    # presents the tile's last live block whatever the table holds there
+    blk, tiles = paged_prefill_schedule(
+        jnp.pad(table_row, (0, -max_nb % g)), start, n_live, n_tiles, tq, bs)
+    n_blocks = jnp.clip((jnp.asarray(start, jnp.int32)
+                         + jnp.asarray(n_live, jnp.int32) + bs - 1) // bs,
+                        1, max_nb)
+    n_groups = ((n_blocks + g - 1) // g).astype(jnp.int32)
+    lp = jnp.asarray([layer], jnp.int32)
+
+    def c_map(slot):
+        return lambda i, j, lp_ref, blk_ref, tl_ref: (
+            lp_ref[0], blk_ref[i, j * g + slot], 0, 0)
+
+    def q_map(i, j, lp_ref, blk_ref, tl_ref):
+        return (i, 0)
+
+    kernel = functools.partial(_mla_prefill_kernel, block_size=bs,
+                               rank=rank, nh=nh, slots=g)
+    steps = n_tiles * max_nb
+    with _mosaic_ctx():
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(n_tiles, n_groups),
+                in_specs=[pl.BlockSpec((rows, w), q_map)] + [
+                    pl.BlockSpec((1, 1, w, bs), c_map(slot))
+                    for slot in range(g)],
+                out_specs=pl.BlockSpec((rows, rank), q_map),
+                scratch_shapes=[
+                    pltpu.VMEM((rows, 128), jnp.float32),
+                    pltpu.VMEM((rows, 128), jnp.float32),
+                    pltpu.VMEM((rows, rank), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((c * nh, rank), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=MLA_VMEM_LIMIT),
+            cost_estimate=_cost_estimate(
+                flops=2 * rows * (w + rank) * bs * steps,
+                transcendentals=rows * bs * steps,
+                bytes_accessed=(w * bs * it * steps
+                                + c * nh * (w + rank) * q.dtype.itemsize),
+                name="paged.mla_prefill"),
+            interpret=_interpret(),
+        )(lp, blk, tiles, q.reshape(c * nh, w), *([pool] * g))
+    return out.reshape(c, nh, rank)
+
+
+def mla_paged_attention_xla(q, pool, tables, lengths, layer, scale, rank):
+    """Plain-XLA oracle of both latent kernels: q [B, NH, W] UNSCALED, row b
+    attends the first lengths[b] columns of its table's blocks (a prefill
+    chunk is B = C rows over one table, lengths = position + 1). Standard
+    e-base softmax in f32; returns [B, NH, rank] f32."""
+    B, max_nb = tables.shape
+    w, bs = pool.shape[2], pool.shape[3]
+    cc = jnp.transpose(pool[layer][tables], (0, 2, 1, 3)) \
+        .reshape(B, w, max_nb * bs).astype(jnp.float32)
+    s = jnp.einsum("bhw,bwt->bht", q.astype(jnp.float32), cc) * scale
+    t = jnp.arange(max_nb * bs)[None, None, :]
+    s = jnp.where(t < lengths[:, None, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bht,bct->bhc", p, cc[:, :rank])

@@ -296,3 +296,72 @@ def test_dp2_mp2_train_step_compiles_on_four_chips(topo):
                                sharding=NamedSharding(mesh, P("dp", None)))
     text = compile_for_chip(step, sharded(params), opt, ids, ids).as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+
+
+# -- DeepSeek-V3 at published widths (PR 31) -----------------------------------
+
+MLA_NH, MLA_RANK, MLA_W = 128, 512, 576
+MLA_MAX_NB = 134                 # 17152 tokens of 128
+MLA_POOL = (6, 2048, MLA_W, BS)  # the cell's own pool: 1.81 GB
+
+
+@pytest.mark.parametrize("batch", [1, 64])
+def test_mla_decode_kernel_compiles(sds, batch):
+    """576 rows on the sublane axis, the new column padded to 640 lanes and
+    transposed in the kernel, a dynamic grid over the live blocks, the pool
+    aliased through the call."""
+    out = compile_for_chip(
+        jax.jit(lambda q, new, pool, tables, pos: pa.mla_paged_decode(
+            q, new, pool, tables, pos, 2, rank=MLA_RANK), donate_argnums=(2,)),
+        sds((batch, MLA_NH, MLA_W), bf16), sds((batch, MLA_W), bf16),
+        sds(MLA_POOL, bf16), sds((batch, MLA_MAX_NB), i32),
+        sds((batch,), i32))
+    assert "tpu_custom_call" in out.as_text()
+    # no second pool: the update is in place
+    assert out.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_mla_prefill_kernel_compiles(sds):
+    """A tile of 16 tokens x 128 heads against four table slots a step."""
+    assert pa._fit_mla_prefill_tile(512, MLA_NH, MLA_W, MLA_RANK, BS, 2) == 16
+    out = compile_for_chip(
+        lambda q, pool, table, start, n: pa.mla_paged_prefill(
+            q, pool, table, start, n, 2, rank=MLA_RANK),
+        sds((512, MLA_NH, MLA_W), bf16), sds(MLA_POOL, bf16),
+        sds((MLA_MAX_NB,), i32), sds((), i32), sds((), i32))
+    assert "tpu_custom_call" in out.as_text()
+
+
+@pytest.mark.parametrize("tile, rows", [(128, 6144), (16, 768)])
+def test_live_grouped_matmul_compiles_at_expert_widths(sds, tile, rows):
+    """80 stacked experts of 7168 x 2048, a chunk's and a decode batch's
+    row buffers, the tile axis of the grid a traced count."""
+    from paddle_tpu.ops.grouped_matmul import grouped_matmul_live
+    n_t = rows // tile
+    sched = tuple(sds((n_t,), i32) for _ in range(4))
+    for k, n in ((7168, 2048), (2048, 7168)):
+        out = compile_for_chip(
+            lambda x, w, e, lv, f, l, n_live: grouped_matmul_live(
+                x, w, (e, lv, f, l), n_live, tile),
+            sds((rows, k), bf16), sds((80, k, n), bf16), *sched,
+            sds((), i32))
+        assert "tpu_custom_call" in out.as_text()
+
+
+def test_deepseek_steps_compile_and_fit_one_chip(one_chip):
+    """The cell's own programs (``chipbench/families/deepseek.py``
+    ``aot_programs``): decode at the smallest and largest bucket and the
+    512-token chunk, 11.0 GB of weights and the 1.81 GB pool on one chip."""
+    from chipbench import spec
+    cell = spec.load_cell("dsv3.serve.docqa")
+    names = []
+    for name, compile_ in cell.family.aot_programs(
+            cell.model, cell.traffic, one_chip, False):
+        ma = compile_().memory_analysis()
+        held = ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes
+        assert gib(held) < 15.0, (name, gib(held))
+        assert 11.8 < gib(ma.argument_size_in_bytes) < 12.1, name
+        names.append(name)
+    assert names == ["decode, batch 1", "decode, batch 64",
+                     "prefill chunk 512"]
