@@ -12,9 +12,16 @@ compiled step family per bucketed shape —
     ONE admitted prompt, interleaved with the decode batches so long
     prompts never head-of-line-block token generation; its attention
     walks the live blocks of the sequence's table only
-    (``paged_prefill_attention``).
+    (``paged_prefill_attention``);
+  - ``paged_prefill_chunk_with_decode`` at (chunk bucket, max_batch):
+    the chunk AND the decode batch in one layer scan, for an iteration
+    that has both, so that the weights stream once for it and the host
+    launches and waits once (piggybacked decodes: Sarathi-Serve). Only
+    where the model's serving object offers it (``_LlamaServing``: the
+    plain cache on one chip); without it such an iteration runs the two
+    programs above one after the other.
 
-Recompiles are therefore bounded by ``len(decode_buckets) + 1`` and
+Recompiles are therefore bounded by ``len(decode_buckets) + 2`` and
 counted (``serve.compile.*`` counters + StepMetrics.record_compile).
 
 Scheduling per ``step()`` iteration:
@@ -26,7 +33,9 @@ Scheduling per ``step()`` iteration:
      sequence's next block as it crosses a block boundary and
      PREEMPTING-BY-EVICTION (youngest RUNNING sequence back to the
      waiting queue, blocks freed, recompute-on-readmission) when the
-     pool runs dry.
+     pool runs dry. With the program that carries the batch, 2 and 3
+     are planned together, launched once and committed in this order;
+     a prompt whose last chunk ran so decodes from the next iteration.
 
 Telemetry: queue depth, batch occupancy, block-pool utilization and
 prefill-vs-decode time share per iteration through StepMetrics, counter
@@ -88,6 +97,7 @@ from ..models.llama import (LlamaConfig, ParallelConfig, _freeze_config,
                             _jitted_paged_prefill_quant,
                             _jitted_paged_prefill_quant_tp,
                             _jitted_paged_prefill_tp,
+                            _jitted_paged_prefill_with_decode,
                             _jitted_paged_verify,
                             _jitted_paged_verify_quant,
                             _jitted_paged_verify_quant_tp,
@@ -350,11 +360,17 @@ class _LlamaServing:
     """What the engine asks of a model, chosen by the config's type
     (:func:`_serving_for`): the frozen config its jitted programs are keyed
     by, the cache arrays (a tuple, each indexed by block id on axis 1), and
-    the jitted prefill-chunk, decode and verify programs
-    ``fn(params, *cache, ...) -> (..., *cache[, counts])``. This one is
-    Llama's: its builders unchanged (plain, int8, tensor-parallel, verify);
-    an int8 cache is (k, v, k_scale, v_scale). ``work`` names the registry
-    counters a model adds to ``_WORK_TOTALS``' (none here)."""
+    the jitted programs ``fn(params, *cache, ...) -> (..., *cache[, counts])``
+    by ``kind``: ``prefill`` (one chunk of one prompt), ``decode`` (one token
+    a running row), ``verify`` (speculation) and, where the model offers it,
+    ``prefill+decode`` (a chunk with the decode batch riding it:
+    ``fn(params, *cache, <the chunk's inputs>, <the batch's>) -> (chunk
+    logits, row logits, *cache)``). ``step_fn`` returns None for a kind it
+    does not offer, and the engine then runs the iteration with the
+    programs it has. This one is Llama's: plain, int8, tensor-parallel and
+    verify builders; the chunk that carries the batch for the plain cache on
+    one chip alone; an int8 cache is (k, v, k_scale, v_scale). ``work`` names
+    the registry counters a model adds to ``_WORK_TOTALS``' (none here)."""
 
     work: Dict[str, str] = {}
     # (kind, int8 cache): (the plain builder, its mp-sharded twin: the same
@@ -370,6 +386,7 @@ class _LlamaServing:
         ("verify", False): (_jitted_paged_verify, _jitted_paged_verify_tp),
         ("verify", True): (_jitted_paged_verify_quant,
                            _jitted_paged_verify_quant_tp),
+        ("prefill+decode", False): (_jitted_paged_prefill_with_decode, None),
     }
 
     @staticmethod
@@ -389,8 +406,10 @@ class _LlamaServing:
 
     @classmethod
     def step_fn(cls, kind: str, frozen, quant: bool, mesh):
-        plain, tp = cls._BUILDERS[(kind, quant)]
-        return plain(frozen) if mesh is None else tp(frozen, mesh)
+        plain, tp = cls._BUILDERS.get((kind, quant), (None, None))
+        if mesh is None:
+            return plain and plain(frozen)
+        return tp and tp(frozen, mesh)
 
 
 def _serving_for(config):
@@ -544,7 +563,9 @@ class InferenceEngine:
         self._iter_work: Dict[str, int] = {}
         self._compiled_at = -1           # the last iteration that compiled
         self._work_names = dict(_WORK_TOTALS, **self.model.work)
-        self.work_totals = dict.fromkeys(self._work_names.values(), 0)
+        self.work_totals = dict.fromkeys(
+            [*self._work_names.values(), "prefill_chunks_total",
+             "prefill_chunks_with_decode_total"], 0)
         # unified exposition (PR 15): the SLO histograms register by
         # reference, scheduler gauges as render-time callbacks; the
         # registration order IS the metrics_snapshot() key order
@@ -578,6 +599,12 @@ class InferenceEngine:
         self._seqno = itertools.count()
         self._frozen = self.model.freeze(config)
         self._compiled: Dict[Tuple, float] = {}
+        # an iteration with a chunk and running rows is one program where
+        # the model offers it for this cache (speculation decodes through
+        # the verify step, which no chunk carries)
+        self._chunk_carries = (
+            not self.speculative
+            and self._step_fn("prefill+decode", self._frozen) is not None)
         self._clock = 0.0
         # preemption + live weight push (PR 13)
         self._preempt = threading.Event()
@@ -593,10 +620,12 @@ class InferenceEngine:
         self._draining = False
 
     def _step_fn(self, kind: str, frozen, quant: Optional[bool] = None):
-        """The model's jitted program of one kind (prefill, decode, verify)
-        for the cache this engine holds (``quant=False``: for the draft's,
-        which is never int8); every scheduler call site dispatches through
-        here, and nothing else changes with mp or the cache's type."""
+        """The model's jitted program of one kind (prefill, decode, verify,
+        prefill+decode) for the cache this engine holds (``quant=False``:
+        for the draft's, which is never int8), or None where the model
+        offers none of that kind; every scheduler call site dispatches
+        through here, and nothing else changes with mp or the cache's
+        type."""
         if quant is None:
             quant = self.kv_dtype == "int8"
         return self.model.step_fn(kind, frozen, quant, self.mesh)
@@ -722,7 +751,10 @@ class InferenceEngine:
                 ("prefill_ctx_blocks_total", "live KV blocks the prefill "
                                              "chunks' attention walked"),
                 ("prefill_table_blocks_total", "block-table slots of the "
-                                               "prefill chunks run")):
+                                               "prefill chunks run"),
+                ("prefill_chunks_total", "prefill chunks run"),
+                ("prefill_chunks_with_decode_total",
+                 "prefill chunks whose program carried the decode batch")):
             r.gauge(name, fn=lambda n=name: self.work_totals[n], help=what)
         for name in self.model.work.values():       # the model's own counts
             r.gauge(name, fn=lambda n=name: self.work_totals[n],
@@ -955,15 +987,17 @@ class InferenceEngine:
                 return False
         return True
 
-    def _mark_compiled(self, kind: str, key, t_call: float):
-        if (kind, key) not in self._compiled:
-            self._compiled[(kind, key)] = t_call
+    def _mark_compiled(self, key: Tuple, t_call: float):
+        """``key``: the program's kind, then its shape (``("decode", 8)``,
+        ``("prefill+decode", chunk, rows)``)."""
+        if key not in self._compiled:
+            self._compiled[key] = t_call
             self._compiled_at = self.iteration
-            record_counter(f"serve.compile.{kind}")
+            record_counter(f"serve.compile.{key[0]}")
             if self.metrics is not None:
                 self.metrics.record_compile(compile_s=t_call)
             if self.recorder is not None:
-                self.recorder.record_compile(f"{kind}_{key}", t_call)
+                self.recorder.record_compile("_".join(map(str, key)), t_call)
 
     def _span(self, name: str, **args) -> _Phase:
         """``with self._span("serve.decode.plan"): ...`` at a phase
@@ -1144,7 +1178,9 @@ class InferenceEngine:
 
     def step(self) -> List[_Seq]:
         """One scheduler iteration: admit, one prefill chunk, one decode
-        batch. Returns sequences that finished this iteration."""
+        batch; where there is work of both kinds and the model offers the
+        program, the batch rides the chunk's program and the iteration
+        launches once. Returns sequences that finished this iteration."""
         self._phase_ms = {}
         self._iter_work = {}
         with self._span("serve.step", iteration=self.iteration + 1) as st:
@@ -1170,16 +1206,21 @@ class InferenceEngine:
                 self._shed_expired()
                 sp.note(waiting=waiting, admitted=self._admit())
             done: List[_Seq] = []
-            ran_prefill = False
+            ran_prefill, owed = False, None
             seq = next((s for s in self.active if s.state == PREFILL), None)
             if seq is not None:
                 with self._span("serve.prefill", rid=seq.req.request_id,
                                 start=seq.n_cached) as sp:
-                    ran_prefill = self._prefill_chunk(seq, sp, done)
-            if any(s.state == RUNNING for s in self.active):
+                    ran_prefill, owed = self._prefill_chunk(
+                        seq, sp, done, carry=self._chunk_carries and any(
+                            s.state == RUNNING for s in self.active))
+            # a prompt whose last chunk carried the batch decodes from the
+            # next iteration on; after a chunk alone, in this one
+            if (any(s.state == RUNNING for s in self.active) if owed is None
+                    else owed):
                 with self._span("serve.decode") as sp:
                     done += (self._decode_spec_batch(sp) if self.speculative
-                             else self._decode_batch(sp))
+                             else self._decode_batch(sp, redrive=owed))
             with self._span("serve.report") as rp:
                 self._report(done, ran_prefill, rp.t0 - st.t0, rp.t0)
         return done
@@ -1286,11 +1327,19 @@ class InferenceEngine:
                                   seq.n_preempted)
         return admitted
 
-    def _prefill_chunk(self, seq: _Seq, sp: _Phase,
-                       done_out: List[_Seq]) -> bool:
+    def _prefill_chunk(self, seq: _Seq, sp: _Phase, done_out: List[_Seq],
+                       carry: bool
+                       ) -> Tuple[bool, Optional[List[_Seq]]]:
         """One chunk of ``seq``'s prompt inside its ``serve.prefill`` span
         ``sp``: plan (blocks, inputs), launch, wait for the logits, commit.
-        False when the pool is dry and the chunk stalls."""
+        With ``carry`` the running rows are planned beside it and ride the
+        chunk's program (``prefill+decode``, always ``max_batch`` rows
+        wide): one launch and one wait for both, then the chunk's commit
+        and the rows'. Returns (the chunk ran: False when the pool is dry
+        and it stalls; the carried rows still owed a token by the decode
+        program in this iteration: none once they are committed, the
+        survivors of a poisoned row's batch, None where no row was carried
+        and the decode batch runs as after a chunk alone)."""
         rid = seq.req.request_id
         c = self.serve.prefill_chunk
         with self._span("serve.prefill.plan"):
@@ -1316,36 +1365,50 @@ class InferenceEngine:
                 if not (self._evict_one(protect=seq)
                         and self._alloc_for(seq, seq.n_cached + n_live)
                         and self._cow_span(seq, seq.n_cached, n_live)):
-                    return False
+                    return False, None
             ids = np.zeros((c,), np.int32)
             ids[:n_live] = seq.tokens[seq.n_cached:seq.n_cached + n_live]
             table = pad_table(seq.blocks, self.serve.max_nb)
+            rows = self._plan_rows() if carry else []
+            if rows:
+                rids, n_rows, toks, positions, tables = self._decode_inputs(
+                    rows, self.serve.max_batch)
         # the chunk's attention walks ctx_blocks of the table's table_blocks
         self._note_work(
             sp, n_live=int(n_live), chunk=c,
             ctx_blocks=self.pool.blocks_for(seq.n_cached + int(n_live)),
             table_blocks=self.serve.max_nb)
-        key = ("prefill", c)
+        self.work_totals["prefill_chunks_total"] += 1
+        key = ("prefill+decode", c, n_rows) if rows else ("prefill", c)
         failure: Optional[Exception] = None
+        row_logits = None
         try:
             faults.inject("serve.prefill.poison", rid=rid)
             with self._launch_span("serve.prefill.launch", key) as launch:
-                fn = self._step_fn("prefill", self._frozen)
-                out = fn(
-                    self.params, *self.kv,
-                    jnp.asarray(table), np.int32(seq.n_cached),
-                    jnp.asarray(ids), np.int32(n_live))
+                chunk_in = (jnp.asarray(table), np.int32(seq.n_cached),
+                            jnp.asarray(ids), np.int32(n_live))
+                if rows:
+                    out = self._step_fn("prefill+decode", self._frozen)(
+                        self.params, *self.kv, *chunk_in,
+                        jnp.asarray(tables), jnp.asarray(positions),
+                        jnp.asarray(toks))
+                    logits, row_logits, out = out[0], out[1], out[2:]
+                else:
+                    out = self._step_fn("prefill", self._frozen)(
+                        self.params, *self.kv, *chunk_in)
+                    logits, out = out[0], out[1:]
                 n_kv = len(self.kv)
-                logits, self.kv, counts = out[0], out[1:1 + n_kv], \
-                    out[1 + n_kv:]
+                self.kv, counts = out[:n_kv], out[n_kv:]
             with self._span("serve.prefill.wait") as wait:
                 logits = np.asarray(logits)  # noqa: PTA006 -- deliberate sync so prefill phase timing is honest
+                if rows:
+                    row_logits = np.asarray(row_logits)  # noqa: PTA006 -- step boundary: sampled tokens must reach the scheduler
                 if counts:
                     # a model's own work counts ride the sync just paid
                     self._note_work(sp, **self.model.counted(
                         "prefill", counts, [seq.n_cached + int(n_live)]))
         except Exception as e:  # noqa: BLE001 -- quarantine boundary
-            failure = e
+            failure, row_logits = e, None
         with self._span("serve.prefill.commit"):
             if failure is None:
                 try:
@@ -1360,22 +1423,43 @@ class InferenceEngine:
                 if not self._pools_alive():
                     # donated pools died mid-kernel: journal recovery
                     raise failure
-                # a prefill chunk touches exactly one request, so ANY
-                # failure here is attributable: quarantine it, keep serving
+                # the chunk's side touches exactly one request, so ANY
+                # failure here is laid at its door: quarantine it, keep
+                # serving. Rows that rode a program which never returned
+                # go through the decode program, which knows whom to blame
                 cause = (failure.cause if isinstance(failure, PoisonError)
                          else f"prefill: {failure!r}")
                 self._quarantine(seq, cause)
-                return True
-            self._mark_compiled(*key, wait.t1 - launch.t0)
-            if self.tracer is not None:
-                self.tracer.prefill_chunk(
-                    rid, launch.t0, wait.t1, int(n_live),
-                    recompute=bool(seq.generated))
-            seq.n_cached += n_live
-            if seq.n_cached == seq.prefill_target:
-                self._prefill_done(seq, logits, done_out)
-            faults.inject("serve.prefill.after", rid=rid)
-        return True
+            else:
+                self._mark_compiled(key, wait.t1 - launch.t0)
+                if self.tracer is not None:
+                    self.tracer.prefill_chunk(
+                        rid, launch.t0, wait.t1, int(n_live),
+                        recompute=bool(seq.generated))
+                seq.n_cached += n_live
+                if seq.n_cached == seq.prefill_target:
+                    self._prefill_done(seq, logits, done_out)
+                faults.inject("serve.prefill.after", rid=rid)
+            if row_logits is None:
+                return True, None
+            # the rows' side, behind the decode batch's hooks in their
+            # order (``serve.decode.before`` too: here it fires after the
+            # launch, the chunk's commit between them as ever); a poisoned
+            # row is quarantined and the others are owed a re-drive through
+            # the decode program in this iteration. The rows count, and the
+            # chunk counts as having carried them, once they commit
+            faults.inject("serve.decode.before", rids=rids)
+            try:
+                faults.inject("serve.decode.poison", rids=rids)
+                faults.inject("serve.decode.logits", rids=rids,
+                              logits=row_logits)
+            except PoisonError as e:
+                return True, self._drop_poisoned(rows, e)
+            self._note_work(sp, rows=len(rows), bucket=n_rows)
+            self.work_totals["prefill_chunks_with_decode_total"] += 1
+            done_out += self._commit_rows(rows, row_logits, launch.t0,
+                                          wait.t1)
+        return True, []
 
     def _prefill_done(self, seq: _Seq, logits: np.ndarray,
                       done_out: List[_Seq]) -> None:
@@ -1438,13 +1522,37 @@ class InferenceEngine:
                     table, np.int32(start), jnp.asarray(ids),
                     np.int32(n_live))[1:]
                 start += n_live
-        self._mark_compiled("draft_prefill", c, sp.t1 - sp.t0)
+        self._mark_compiled(("draft_prefill", c), sp.t1 - sp.t0)
         seq.draft_pos = target
 
-    def _decode_inputs(self, rows: List[_Seq]):
-        """(rids, bucket, toks, positions, tables) of one decode launch."""
-        bucket = next(b for b in self.serve.decode_buckets
-                      if b >= len(rows))
+    def _plan_rows(self) -> List[_Seq]:
+        """The RUNNING rows that can take one more token, each grown
+        across its block boundary, evicting youngest-first when the pool
+        runs dry (an evicted row drops out of the batch by losing RUNNING
+        state); with nothing evictable the row stalls an iteration instead
+        — finishing rows free its blocks."""
+        ready: List[_Seq] = []
+        for seq in [s for s in self.active if s.state == RUNNING]:
+            if seq.state != RUNNING:
+                continue
+            ok = (self._alloc_for(seq, seq.n_cached + 1)
+                  and self._cow_span(seq, seq.n_cached, 1))
+            while not ok and self._evict_one(protect=seq):
+                ok = (self._alloc_for(seq, seq.n_cached + 1)
+                      and self._cow_span(seq, seq.n_cached, 1))
+            if ok:
+                ready.append(seq)
+            else:
+                record_counter("serve.decode_stall")
+        return [s for s in ready if s.state == RUNNING]
+
+    def _decode_inputs(self, rows: List[_Seq], bucket: Optional[int] = None):
+        """(rids, bucket, toks, positions, tables) of one decode launch,
+        padded to ``bucket`` (default: the smallest that holds the rows)
+        with rows at null block 0, position 0."""
+        if bucket is None:
+            bucket = next(b for b in self.serve.decode_buckets
+                          if b >= len(rows))
         toks = np.zeros((bucket,), np.int32)
         positions = np.zeros((bucket,), np.int32)
         tables = np.zeros((bucket, self.serve.max_nb), np.int32)
@@ -1455,38 +1563,37 @@ class InferenceEngine:
         return ([s.req.request_id for s in rows], bucket, toks, positions,
                 tables)
 
-    def _decode_batch(self, sp: _Phase) -> List[_Seq]:
+    def _drop_poisoned(self, rows: List[_Seq], e: PoisonError) -> List[_Seq]:
+        """Quarantine the row ``e`` names and count the re-drive its
+        batchmates are owed; returns them. Rows are independent (disjoint
+        blocks, per-row tables), so survivors' tokens are bit-identical to
+        a batch that never held the poison."""
+        if not self._pools_alive():
+            raise e  # donated pools died mid-kernel: journal path
+        bad = next((s for s in rows if s.req.request_id == e.rid), None)
+        if bad is None:
+            raise e  # not attributable to this batch
+        self._quarantine(bad, e.cause)
+        self._redrives += 1
+        record_counter("serve.decode_redrive")
+        return [s for s in rows if s is not bad]
+
+    def _decode_batch(self, sp: _Phase,
+                      redrive: Optional[List[_Seq]] = None) -> List[_Seq]:
         """One token for every RUNNING sequence inside the ``serve.decode``
         span ``sp``: plan (blocks, inputs), launch, wait for the logits,
-        commit."""
+        commit. ``redrive``: the rows a chunk's program carried beside a
+        poisoned one, in place of every RUNNING sequence; their blocks are
+        planned and ``serve.decode.before`` has fired for them."""
         with self._span("serve.decode.plan"):
-            # grow each row across its block boundary, evicting youngest-
-            # first when the pool runs dry (an evicted row drops out of
-            # the batch by losing RUNNING state); with nothing evictable
-            # the row stalls an iteration instead — finishing rows free
-            # its blocks
-            ready: List[_Seq] = []
-            for seq in [s for s in self.active if s.state == RUNNING]:
-                if seq.state != RUNNING:
-                    continue
-                ok = (self._alloc_for(seq, seq.n_cached + 1)
-                      and self._cow_span(seq, seq.n_cached, 1))
-                while not ok and self._evict_one(protect=seq):
-                    ok = (self._alloc_for(seq, seq.n_cached + 1)
-                          and self._cow_span(seq, seq.n_cached, 1))
-                if ok:
-                    ready.append(seq)
-                else:
-                    record_counter("serve.decode_stall")
-            rows = [s for s in ready if s.state == RUNNING]
+            rows = self._plan_rows() if redrive is None else redrive
             if not rows:
                 return []
             inputs = self._decode_inputs(rows)
-        faults.inject("serve.decode.before", rids=inputs[0])
+        if redrive is None:
+            faults.inject("serve.decode.before", rids=inputs[0])
         # re-drive loop: a PoisonError attributable to one row drops that
-        # row (quarantined) and re-runs the batch without it; rows are
-        # independent (disjoint blocks, per-row tables), so survivors'
-        # tokens are bit-identical to a batch that never held the poison
+        # row (quarantined) and re-runs the batch without it
         while True:
             rids, bucket, toks, positions, tables = inputs
             key = ("decode", bucket)
@@ -1509,16 +1616,7 @@ class InferenceEngine:
                 faults.inject("serve.decode.logits", rids=rids,
                               logits=logits)
             except PoisonError as e:
-                if not self._pools_alive():
-                    raise  # donated pools died mid-kernel: journal path
-                bad = next((s for s in rows
-                            if s.req.request_id == e.rid), None)
-                if bad is None:
-                    raise  # not attributable to this batch
-                self._quarantine(bad, e.cause)
-                rows = [s for s in rows if s is not bad]
-                self._redrives += 1
-                record_counter("serve.decode_redrive")
+                rows = self._drop_poisoned(rows, e)
                 if not rows:
                     return []
                 with self._span("serve.decode.plan"):
@@ -1527,41 +1625,48 @@ class InferenceEngine:
             break
         self._note_work(sp, rows=len(rows), bucket=bucket)
         with self._span("serve.decode.commit"):
-            t0, t1 = launch.t0, wait.t1
-            self._mark_compiled(*key, t1 - t0)
-            next_tok = logits.argmax(-1)
-            live = list(enumerate(rows))
-            if self._nan_check:
-                # per-row screen: quarantine rows whose logits went
-                # non-finite; the survivors' already-computed argmax
-                # stands (rows are independent)
-                finite = np.isfinite(
-                    logits[:len(rows)].reshape(len(rows), -1)).all(axis=1)
-                if not bool(finite.all()):
-                    for i, seq in [p for p in live if not finite[p[0]]]:
-                        self._quarantine(seq, "non-finite decode logits")
-                    live = [p for p in live if finite[p[0]]]
-            if self.tracer is not None:
-                self.tracer.decode([s.req.request_id for _, s in live],
-                                   t0, t1, self.iteration)
-            self._last_tokens += len(live)
-            done = []
-            now = self._now()
-            for i, seq in live:
-                seq.n_cached += 1
-                seq.tokens.append(int(next_tok[i]))
-                self._jtoks.append((seq.req.request_id, seq.tokens[-1]))
-                if seq.first_token_t is None:
-                    seq.first_token_t = now
-                    self.slo["ttft"].record(now - seq.arrival)
-                elif seq.token_times:
-                    self.slo["tpot"].record(now - seq.token_times[-1])
-                seq.token_times.append(now)
-                if seq.done():
-                    self._finish_seq(seq, t1)
-                    done.append(seq)
-            faults.inject("serve.decode.after",
-                          rids=[s.req.request_id for _, s in live])
+            self._mark_compiled(key, wait.t1 - launch.t0)
+            return self._commit_rows(rows, logits, launch.t0, wait.t1)
+
+    def _commit_rows(self, rows: List[_Seq], logits: np.ndarray,
+                     t0: float, t1: float) -> List[_Seq]:
+        """The decoded rows' bookkeeping from their host logits (row i of
+        ``logits`` is ``rows[i]``'s; rows past them are padding): greedy
+        token, NaN screen, stamps, journal pairs, finish. Returns the rows
+        that finished."""
+        next_tok = logits.argmax(-1)
+        live = list(enumerate(rows))
+        if self._nan_check:
+            # per-row screen: quarantine rows whose logits went
+            # non-finite; the survivors' already-computed argmax
+            # stands (rows are independent)
+            finite = np.isfinite(
+                logits[:len(rows)].reshape(len(rows), -1)).all(axis=1)
+            if not bool(finite.all()):
+                for i, seq in [p for p in live if not finite[p[0]]]:
+                    self._quarantine(seq, "non-finite decode logits")
+                live = [p for p in live if finite[p[0]]]
+        if self.tracer is not None:
+            self.tracer.decode([s.req.request_id for _, s in live],
+                               t0, t1, self.iteration)
+        self._last_tokens += len(live)
+        done = []
+        now = self._now()
+        for i, seq in live:
+            seq.n_cached += 1
+            seq.tokens.append(int(next_tok[i]))
+            self._jtoks.append((seq.req.request_id, seq.tokens[-1]))
+            if seq.first_token_t is None:
+                seq.first_token_t = now
+                self.slo["ttft"].record(now - seq.arrival)
+            elif seq.token_times:
+                self.slo["tpot"].record(now - seq.token_times[-1])
+            seq.token_times.append(now)
+            if seq.done():
+                self._finish_seq(seq, t1)
+                done.append(seq)
+        faults.inject("serve.decode.after",
+                      rids=[s.req.request_id for _, s in live])
         return done
 
     def _decode_spec_batch(self, sp: _Phase) -> List[_Seq]:
@@ -1654,7 +1759,7 @@ class InferenceEngine:
                     dl, self.kv_draft = res[0], res[1:]
                 with self._span("serve.draft.wait") as wait:
                     dl = np.asarray(dl)  # noqa: PTA006 -- host-chained: each draft argmax feeds the next draft step
-                self._mark_compiled("draft", bucket, wait.t1 - launch.t0)
+                self._mark_compiled(("draft", bucket), wait.t1 - launch.t0)
                 nxt = dl.argmax(-1)
                 for i, seq in stepping:
                     rid = seq.req.request_id
@@ -1720,7 +1825,7 @@ class InferenceEngine:
         self._note_work(sp, rows=len(rows), bucket=bucket)
         with self._span("serve.decode.commit"):
             t0, t1 = launch.t0, wait.t1
-            self._mark_compiled(*key, t1 - t0)
+            self._mark_compiled(key, t1 - t0)
             live = list(enumerate(rows))
             if self._nan_check:
                 # the verify step returns tokens, not logits, so the
@@ -2183,8 +2288,8 @@ class InferenceEngine:
             "preempted": self._was_preempted,
             "weight_swaps": self.swaps,
             "iterations": self.iteration,
-            "compiles": {f"{k}_{v}": round(t, 3)
-                         for (k, v), t in sorted(self._compiled.items())},
+            "compiles": {"_".join(map(str, key)): round(t, 3)
+                         for key, t in sorted(self._compiled.items())},
             "pool_blocks": self.serve.num_blocks - 1,
             "mp": self.mp,
             "pool_bytes_per_rank": pool_bytes_per_rank(

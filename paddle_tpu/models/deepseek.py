@@ -546,8 +546,12 @@ class DeepSeekServing:
 
     @staticmethod
     def step_fn(kind, frozen, quant, mesh):
-        return {"prefill": _jitted_paged_prefill,
-                "decode": _jitted_paged_decode}[kind](frozen)
+        """The jitted program of ``kind``; None for a kind not offered
+        (``prefill+decode``: a chunk and the decode batch stay two
+        programs here)."""
+        build = {"prefill": _jitted_paged_prefill,
+                 "decode": _jitted_paged_decode}.get(kind)
+        return build and build(frozen)
 
     @staticmethod
     def counted(kind, counts, ctx):

@@ -1048,6 +1048,95 @@ def _tp_gather_logits(logits, tp):
                               tiled=True)
 
 
+def _paged_embed(params, ids, config, tp):
+    """The hidden rows a paged step starts from: ``ids`` of any shape ->
+    [..., H] in the model's dtype (vocab-parallel inside a ``tp`` island)."""
+    rows = (jnp.take(params["embed"], ids, axis=0) if tp is None
+            else _tp_vocab_embed(params["embed"], ids, tp))
+    return rows.astype(config.dtype)
+
+
+def _paged_qkv(p, x, cos, sin, config):
+    """A layer's projections of a paged step's normed rows x [a, n, H]:
+    q [a, n, NH, hd], k and v [a, n, NKV, hd], q and k roped by the rows'
+    own phases. The head counts come from the weights' shapes, so they are
+    the local ones inside a tensor-parallel island."""
+    hd = config.head_dim
+    if "qkv_proj" in p:
+        ratio = config.num_attention_heads // config.num_key_value_heads
+        nkv = _mat_out_dim(p["qkv_proj"]) // hd // (ratio + 2)
+        nh = nkv * ratio
+        q, k, v = jnp.split(_mat(x, p["qkv_proj"]),
+                            [nh * hd, (nh + nkv) * hd], axis=-1)
+    else:
+        q, k, v = (_mat(x, p[name])
+                   for name in ("q_proj", "k_proj", "v_proj"))
+    q, k, v = (t.reshape(x.shape[:2] + (-1, hd)) for t in (q, k, v))
+    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+def _paged_attend_rows(q, k, v, pools, walk, layer, config):
+    """The decode batch's attention in one layer: q [B, NH, hd], k and v
+    [B, NKV, hd] (roped) of one new token a row; ``walk`` the batch's
+    ``paged_update_walk``, made once before the layer loop. The fused
+    paged kernel writes each row's column at its position into its block
+    and attends over the row's table, updating ``pools`` ((k, v) or the
+    int8 (k, v, k_scale, v_scale)) in place through input_output_aliases.
+    Returns (rows [B, NH*hd], pools)."""
+    from ..ops.paged_attention import (_LOG2E, kv_quant_columns,
+                                       paged_attend_update,
+                                       paged_attend_update_quant)
+    b, nh, hd = q.shape
+    nkv = k.shape[1]
+    kvd, rep = nkv * hd, nh // nkv
+    qg = q.reshape(b, nkv, rep, hd)
+    # block-diagonal q (see llama_decode_step): the paged kernel
+    # reads whole [KVD, bs] slab fragments per sequence
+    eye = jnp.eye(nkv, dtype=qg.dtype)
+    q_bd = jnp.einsum("bgrd,ge->bgred", qg, eye).reshape(b, nh, kvd)
+    qs = (q_bd.astype(jnp.float32)
+          * (_LOG2E / (hd ** 0.5))).astype(q_bd.dtype)
+    k, v = k.reshape(b, kvd), v.reshape(b, kvd)
+    if len(pools) == 2:
+        kp, vp = pools
+        attn_full, *pools = paged_attend_update(
+            qs, k.astype(kp.dtype), v.astype(vp.dtype), kp, vp, walk, layer)
+    else:
+        # each new column is quantized per-kv-head OUTSIDE the kernel (the
+        # bytes a prefill of the same tokens writes); the fused update
+        # merges bytes + scales in place
+        nk_q, nk_s = kv_quant_columns(k, nkv)
+        nv_q, nv_s = kv_quant_columns(v, nkv)
+        attn_full, *pools = paged_attend_update_quant(
+            qs, nk_q, nv_q, nk_s, nv_s, *pools, walk, layer)
+    attn = jnp.einsum("bgred,ge->bgrd",
+                      attn_full.reshape(b, nkv, rep, nkv, hd),
+                      eye.astype(attn_full.dtype)).astype(config.dtype)
+    return attn.reshape(b, nh * hd), tuple(pools)
+
+
+def _paged_layer_tail(p, h, ao, config, tp):
+    """What follows a paged step's attention, on rows of any leading shape:
+    ``o_proj`` of the attention's rows ``ao``, the residual, the post norm,
+    the FFN and its residual (the two row-parallel products reduce across
+    ranks inside a ``tp`` island)."""
+    h = h + (_mat(ao, p["o_proj"]) if tp is None
+             else _tp_o_proj(ao, p["o_proj"], tp))
+    x2 = fused_rms_norm(h, p["post_norm"], config.rms_norm_eps)
+    gated = jax.nn.silu(_mat(x2, p["gate_proj"])) * _mat(x2, p["up_proj"])
+    return h + (_mat(gated, p["down_proj"]) if tp is None
+                else _tp_down_proj(gated, p["down_proj"], tp))
+
+
+def _scan_paged_layers(layer_step, h, pools, params):
+    """Run ``layer_step(h, pools, p, layer) -> (h, pools)`` over the stacked
+    layers with the pools as donated carries; returns (h, pools)."""
+    xs = (params["layers"],
+          jnp.arange(pools[0].shape[0], dtype=jnp.int32))
+    return lax.scan(lambda carry, x: (layer_step(*carry, *x), None),
+                    (h, tuple(pools)), xs)[0]
+
+
 def llama_paged_decode_step(params, k_pool, v_pool, tables, positions,
                             ids, config: LlamaConfig, kv_scales=None,
                             tp=None):
@@ -1065,96 +1154,27 @@ def llama_paged_decode_step(params, k_pool, v_pool, tables, positions,
     exists (the conservative-aliasing trap documented in
     ops/decode_attention.py STATUS).
 
-    With ``kv_scales=(k_scale, v_scale)`` the pools are int8: each new
-    column is quantized per-kv-head OUTSIDE the kernel (the same
-    kv_quant_columns bytes a prefill of the same tokens writes) and
-    the fused update merges bytes + scales in place. Returns
-    (logits, k_pool, v_pool, k_scale, v_scale) in that mode."""
-    from ..ops.paged_attention import (_LOG2E, kv_quant_columns,
-                                       paged_attend_update,
-                                       paged_attend_update_quant)
+    With ``kv_scales=(k_scale, v_scale)`` the pools are int8 (see
+    ``_paged_attend_rows``). Returns (logits, k_pool, v_pool, k_scale,
+    v_scale) in that mode."""
+    from ..ops.paged_attention import paged_update_walk
     c = config
-    b = ids.shape[0]
-    hd = c.head_dim
-    if tp is None:
-        h = jnp.take(params["embed"], ids, axis=0).astype(c.dtype)  # [B, H]
-    else:
-        h = _tp_vocab_embed(params["embed"], ids, tp).astype(c.dtype)
-    cos, sin = build_rope_cache(b, hd, base=c.rope_theta,
+    h = _paged_embed(params, ids, c, tp)[:, None]               # [B, 1, H]
+    cos, sin = build_rope_cache(ids.shape[0], c.head_dim, base=c.rope_theta,
                                 position_ids=positions[:, None])  # [B,1,·]
+    walk = paged_update_walk(tables, positions, k_pool.shape[-1])
 
-    def layer_step(carry, xs):
-        if kv_scales is None:
-            h, kp, vp = carry
-        else:
-            h, kp, vp, ksc, vsc = carry
-        p, layer = xs
-        x = fused_rms_norm(h[:, None], p["input_norm"], c.rms_norm_eps)
-        if "qkv_proj" in p:
-            ratio = c.num_attention_heads // c.num_key_value_heads
-            nkv = _mat_out_dim(p["qkv_proj"]) // hd // (ratio + 2)
-            nh = nkv * ratio
-            qkv = _mat(x, p["qkv_proj"])
-            q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
-            q = q.reshape(b, 1, nh, hd)
-            k = k.reshape(b, 1, nkv, hd)
-            v = v.reshape(b, 1, nkv, hd)
-        else:
-            nh = _mat_out_dim(p["q_proj"]) // hd
-            nkv = _mat_out_dim(p["k_proj"]) // hd
-            q = _mat(x, p["q_proj"]).reshape(b, 1, nh, hd)
-            k = _mat(x, p["k_proj"]).reshape(b, 1, nkv, hd)
-            v = _mat(x, p["v_proj"]).reshape(b, 1, nkv, hd)
-        kvd = nkv * hd
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        layer_i = jnp.asarray(layer, jnp.int32)
-        rep = nh // nkv
-        qg = q[:, 0].reshape(b, nkv, rep, hd)
-        # block-diagonal q (see llama_decode_step): the paged kernel
-        # reads whole [KVD, bs] slab fragments per sequence
-        eye = jnp.eye(nkv, dtype=qg.dtype)
-        q_bd = jnp.einsum("bgrd,ge->bgred", qg, eye).reshape(b, nh, kvd)
-        qs = (q_bd.astype(jnp.float32)
-              * (_LOG2E / (hd ** 0.5))).astype(q_bd.dtype)
-        if kv_scales is None:
-            attn_full, kp, vp = paged_attend_update(
-                qs, k.reshape(b, kvd).astype(kp.dtype),
-                v.reshape(b, kvd).astype(vp.dtype), kp, vp,
-                tables, positions, layer_i)
-        else:
-            nk_q, nk_s = kv_quant_columns(k.reshape(b, kvd), nkv)
-            nv_q, nv_s = kv_quant_columns(v.reshape(b, kvd), nkv)
-            attn_full, kp, vp, ksc, vsc = paged_attend_update_quant(
-                qs, nk_q, nv_q, nk_s, nv_s, kp, vp, ksc, vsc,
-                tables, positions, layer_i)
-        attn = jnp.einsum("bgred,ge->bgrd",
-                          attn_full.reshape(b, nkv, rep, nkv, hd),
-                          eye.astype(attn_full.dtype)).astype(c.dtype)
-        ao = attn.reshape(b, nh * hd)
-        attn_out = (_mat(ao, p["o_proj"]) if tp is None
-                    else _tp_o_proj(ao, p["o_proj"], tp))
-        h = h + attn_out
-        x2 = fused_rms_norm(h[:, None], p["post_norm"], c.rms_norm_eps)[:, 0]
-        gated = jax.nn.silu(_mat(x2, p["gate_proj"])) * _mat(x2, p["up_proj"])
-        h = h + (_mat(gated, p["down_proj"]) if tp is None
-                 else _tp_down_proj(gated, p["down_proj"], tp))
-        if kv_scales is None:
-            return (h, kp, vp), None
-        return (h, kp, vp, ksc, vsc), None
+    def layer_step(h, pools, p, layer):
+        x = fused_rms_norm(h, p["input_norm"], c.rms_norm_eps)
+        q, k, v = _paged_qkv(p, x, cos, sin, c)
+        ao, pools = _paged_attend_rows(q[:, 0], k[:, 0], v[:, 0], pools,
+                                       walk, layer, c)
+        return _paged_layer_tail(p, h, ao[:, None], c, tp), pools
 
-    n_layers = k_pool.shape[0]
-    xs = (params["layers"], jnp.arange(n_layers, dtype=jnp.int32))
-    if kv_scales is None:
-        (h, k_pool, v_pool), _ = lax.scan(
-            layer_step, (h, k_pool, v_pool), xs)
-        logits = llama_logits(params, h[:, None], config)[:, 0]
-        return logits.astype(jnp.float32), k_pool, v_pool
-    k_scale, v_scale = kv_scales
-    (h, k_pool, v_pool, k_scale, v_scale), _ = lax.scan(
-        layer_step, (h, k_pool, v_pool, k_scale, v_scale), xs)
-    logits = llama_logits(params, h[:, None], config)[:, 0]
-    return logits.astype(jnp.float32), k_pool, v_pool, k_scale, v_scale
+    h, pools = _scan_paged_layers(
+        layer_step, h, (k_pool, v_pool) + tuple(kv_scales or ()), params)
+    logits = llama_logits(params, h, config)[:, 0]
+    return (logits.astype(jnp.float32),) + pools
 
 
 def _pin_pool_layout(pool):
@@ -1222,6 +1242,36 @@ def _chunk_window(table_row, start, n_live, C, bs):
     return wbid, fresh, window
 
 
+def _paged_attend_chunk(q, k, v, pools, table_row, start, n_live, layer,
+                        where):
+    """A prefill chunk's attention in one layer: q [C, NH, hd], k and v
+    [C, NKV, hd] (roped), ``where`` the chunk's ``_chunk_window``. The
+    chunk's KV columns land in their blocks first (int8 pools: quantized
+    per-kv-head, one scale a column, so the bytes do not depend on chunk
+    boundaries); the attention then reads prefix and chunk alike from the
+    pools, walking the sequence's live blocks. Returns (rows [C, NH*hd],
+    pools)."""
+    from ..ops.paged_attention import (kv_quant_columns,
+                                       paged_prefill_attention)
+    wbid, fresh, window = where
+    C, nkv = k.shape[:2]
+    k, v = k.reshape(C, -1), v.reshape(C, -1)
+    if len(pools) == 2:
+        cols = (k.astype(pools[0].dtype), v.astype(pools[1].dtype))
+    else:
+        kq, ksq = kv_quant_columns(k, nkv)
+        vq, vsq = kv_quant_columns(v, nkv)
+        cols = (kq, vq, ksq, vsq)
+    pools = tuple(
+        _pool_write_chunk(_pin_pool_layout(pool), layer, wbid, fresh,
+                          window(col))
+        for pool, col in zip(pools, cols))
+    attn = paged_prefill_attention(q, pools[0], pools[1], table_row, start,
+                                   n_live, layer,
+                                   kv_scales=pools[2:] or None)
+    return attn.reshape(C, -1), pools
+
+
 def llama_paged_prefill_chunk(params, k_pool, v_pool, table_row, start,
                               ids, n_live, config: LlamaConfig,
                               kv_scales=None, tp=None):
@@ -1236,98 +1286,79 @@ def llama_paged_prefill_chunk(params, k_pool, v_pool, table_row, start,
     returns the logits of the LAST REAL token ([vocab] f32 — only
     meaningful on the final chunk) plus the updated pools.
 
-    With ``kv_scales=(k_scale, v_scale)`` the pools are int8: each
-    column quantizes per-kv-head via kv_quant_columns before the
-    scatter (one scale per column — bytes independent of chunk
-    boundaries) and the attention dequantizes each block tile. Returns
-    (logits, k_pool, v_pool, k_scale, v_scale) in that mode."""
-    from ..ops.paged_attention import (kv_quant_columns,
-                                       paged_prefill_attention)
+    With ``kv_scales=(k_scale, v_scale)`` the pools are int8 (see
+    ``_paged_attend_chunk``). Returns (logits, k_pool, v_pool, k_scale,
+    v_scale) in that mode."""
     c = config
     C = ids.shape[0]
-    hd = c.head_dim
-    bs = k_pool.shape[-1]
-    if tp is None:
-        h = jnp.take(params["embed"], ids, axis=0)[None].astype(c.dtype)
-    else:
-        h = _tp_vocab_embed(params["embed"], ids, tp)[None].astype(c.dtype)
+    h = _paged_embed(params, ids, c, tp)[None]                  # [1, C, H]
     pidx = start + jnp.arange(C, dtype=jnp.int32)          # [C] positions
-    cos, sin = build_rope_cache(C, hd, base=c.rope_theta,
+    cos, sin = build_rope_cache(C, c.head_dim, base=c.rope_theta,
                                 position_ids=pidx)         # [C, hd/2]
-    wbid, fresh, window = _chunk_window(table_row, start, n_live, C, bs)
+    where = _chunk_window(table_row, start, n_live, C, k_pool.shape[-1])
 
-    def layer_step(carry, xs):
-        if kv_scales is None:
-            h, kp, vp = carry
-        else:
-            h, kp, vp, ksc, vsc = carry
-            ksc, vsc = _pin_pool_layout(ksc), _pin_pool_layout(vsc)
-        kp, vp = _pin_pool_layout(kp), _pin_pool_layout(vp)
-        p, layer = xs
+    def layer_step(h, pools, p, layer):
         x = fused_rms_norm(h, p["input_norm"], c.rms_norm_eps)
-        if "qkv_proj" in p:
-            ratio = c.num_attention_heads // c.num_key_value_heads
-            nkv = _mat_out_dim(p["qkv_proj"]) // hd // (ratio + 2)
-            nh = nkv * ratio
-            qkv = _mat(x, p["qkv_proj"])
-            q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
-            q = q.reshape(1, C, nh, hd)
-            k = k.reshape(1, C, nkv, hd)
-            v = v.reshape(1, C, nkv, hd)
-        else:
-            nh = _mat_out_dim(p["q_proj"]) // hd
-            nkv = _mat_out_dim(p["k_proj"]) // hd
-            q = _mat(x, p["q_proj"]).reshape(1, C, nh, hd)
-            k = _mat(x, p["k_proj"]).reshape(1, C, nkv, hd)
-            v = _mat(x, p["v_proj"]).reshape(1, C, nkv, hd)
-        kvd = nkv * hd
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        # the chunk's KV columns land in their blocks first; the attention
-        # then reads prefix and chunk alike from the pools
-        if kv_scales is None:
-            kp = _pool_write_chunk(kp, layer, wbid, fresh, window(
-                k.reshape(C, kvd).astype(kp.dtype)))
-            vp = _pool_write_chunk(vp, layer, wbid, fresh, window(
-                v.reshape(C, kvd).astype(vp.dtype)))
-            scales = None
-        else:
-            kq, ksq = kv_quant_columns(k.reshape(C, kvd), nkv)
-            vq, vsq = kv_quant_columns(v.reshape(C, kvd), nkv)
-            kp = _pool_write_chunk(kp, layer, wbid, fresh, window(kq))
-            vp = _pool_write_chunk(vp, layer, wbid, fresh, window(vq))
-            ksc = _pool_write_chunk(ksc, layer, wbid, fresh, window(ksq))
-            vsc = _pool_write_chunk(vsc, layer, wbid, fresh, window(vsq))
-            scales = (ksc, vsc)
-        attn = paged_prefill_attention(q[0], kp, vp, table_row, start,
-                                       n_live, layer, kv_scales=scales)
-        ao = attn.reshape(1, C, nh * hd)
-        attn_out = (_mat(ao, p["o_proj"]) if tp is None
-                    else _tp_o_proj(ao, p["o_proj"], tp))
-        h = h + attn_out
-        x2 = fused_rms_norm(h, p["post_norm"], c.rms_norm_eps)
-        gated = jax.nn.silu(_mat(x2, p["gate_proj"])) * _mat(x2, p["up_proj"])
-        h = h + (_mat(gated, p["down_proj"]) if tp is None
-                 else _tp_down_proj(gated, p["down_proj"], tp))
-        if kv_scales is None:
-            return (h, kp, vp), None
-        return (h, kp, vp, ksc, vsc), None
+        q, k, v = _paged_qkv(p, x, cos, sin, c)
+        ao, pools = _paged_attend_chunk(q[0], k[0], v[0], pools, table_row,
+                                        start, n_live, layer, where)
+        return _paged_layer_tail(p, h, ao[None], c, tp), pools
 
-    n_layers = k_pool.shape[0]
-    xs = (params["layers"], jnp.arange(n_layers, dtype=jnp.int32))
-    if kv_scales is None:
-        (h, k_pool, v_pool), _ = lax.scan(
-            layer_step, (h, k_pool, v_pool), xs)
-        h_last = lax.dynamic_slice_in_dim(h[0], n_live - 1, 1, 0)[None]
-        logits = llama_logits(params, h_last, config)[0, 0]
-        return logits.astype(jnp.float32), k_pool, v_pool
-    k_scale, v_scale = kv_scales
-    (h, k_pool, v_pool, k_scale, v_scale), _ = lax.scan(
-        layer_step, (h, k_pool, v_pool, k_scale, v_scale), xs)
+    h, pools = _scan_paged_layers(
+        layer_step, h, (k_pool, v_pool) + tuple(kv_scales or ()), params)
     h_last = lax.dynamic_slice_in_dim(h[0], n_live - 1, 1, 0)[None]
     logits = llama_logits(params, h_last, config)[0, 0]
-    return (logits.astype(jnp.float32), k_pool, v_pool, k_scale,
-            v_scale)
+    return (logits.astype(jnp.float32),) + pools
+
+
+def llama_paged_prefill_chunk_with_decode(params, k_pool, v_pool, table_row,
+                                          start, ids, n_live, tables,
+                                          positions, row_ids,
+                                          config: LlamaConfig):
+    """A prefill chunk with the decode batch riding it, for an iteration
+    that has both: ONE layer scan over the chunk's C rows (``table_row``,
+    ``start``, ``ids``, ``n_live`` as ``llama_paged_prefill_chunk`` takes
+    them) and the batch's R rows (``tables``, ``positions``, ``row_ids`` as
+    ``llama_paged_decode_step`` takes them; padding rows at null block 0,
+    position 0), so the weights stream once for both. Embedding, norms,
+    projections, ``o_proj``, the FFN and the head run on the C + R rows
+    together; between the projections and ``o_proj`` the rows part, each to
+    the attention of its own step (the chunk's write and block-table flash
+    attention, the batch's fused paged update), each roped by its own
+    positions. The parts touch disjoint blocks: a sequence is in prefill or
+    running, never both. The batch's update goes first, so the chunk's
+    attention is the pools' last reader in a layer and nothing copies them.
+
+    Returns (the chunk's last-live-token logits [vocab] f32, the batch's
+    logits [R, vocab] f32, k_pool, v_pool)."""
+    from ..ops.paged_attention import paged_update_walk
+    c = config
+    C = ids.shape[0]
+    h = _paged_embed(params, jnp.concatenate([ids, row_ids]), c,
+                     None)[None]                            # [1, C + R, H]
+    pidx = jnp.concatenate([start + jnp.arange(C, dtype=jnp.int32),
+                            positions])
+    cos, sin = build_rope_cache(pidx.shape[0], c.head_dim,
+                                base=c.rope_theta, position_ids=pidx)
+    where = _chunk_window(table_row, start, n_live, C, k_pool.shape[-1])
+    walk = paged_update_walk(tables, positions, k_pool.shape[-1])
+
+    def layer_step(h, pools, p, layer):
+        x = fused_rms_norm(h, p["input_norm"], c.rms_norm_eps)
+        q, k, v = (t[0] for t in _paged_qkv(p, x, cos, sin, c))
+        ao_rows, pools = _paged_attend_rows(q[C:], k[C:], v[C:], pools,
+                                            walk, layer, c)
+        ao_chunk, pools = _paged_attend_chunk(q[:C], k[:C], v[:C], pools,
+                                              table_row, start, n_live,
+                                              layer, where)
+        ao = jnp.concatenate([ao_chunk, ao_rows])[None]
+        return _paged_layer_tail(p, h, ao, c, None), pools
+
+    h, pools = _scan_paged_layers(layer_step, h, (k_pool, v_pool), params)
+    h_last = lax.dynamic_slice_in_dim(h[0], n_live - 1, 1, 0)
+    heads = jnp.concatenate([h_last, h[0, C:]])[:, None]    # [1 + R, 1, H]
+    logits = llama_logits(params, heads, config)[:, 0].astype(jnp.float32)
+    return (logits[0], logits[1:]) + pools
 
 
 @functools.lru_cache(maxsize=32)
@@ -1350,6 +1381,19 @@ def _jitted_paged_prefill(frozen):
                                          start, ids, n_live, config)
     paged_prefill_fn.__name__ = "paged_prefill_chunk"
     return jax.jit(paged_prefill_fn, donate_argnums=(1, 2))
+
+
+@functools.lru_cache(maxsize=32)
+def _jitted_paged_prefill_with_decode(frozen):
+    config = LlamaConfig(*frozen)
+
+    def paged_prefill_with_decode_fn(params, kp, vp, table_row, start, ids,
+                                     n_live, tables, positions, row_ids):
+        return llama_paged_prefill_chunk_with_decode(
+            params, kp, vp, table_row, start, ids, n_live, tables,
+            positions, row_ids, config)
+    paged_prefill_with_decode_fn.__name__ = "paged_prefill_chunk_with_decode"
+    return jax.jit(paged_prefill_with_decode_fn, donate_argnums=(1, 2))
 
 
 @functools.lru_cache(maxsize=32)
@@ -1568,10 +1612,7 @@ def llama_paged_verify_step(params, k_pool, v_pool, tables, qstart,
     c = config
     B, T = fed.shape
     hd = c.head_dim
-    if tp is None:
-        h = jnp.take(params["embed"], fed, axis=0).astype(c.dtype)  # [B,T,H]
-    else:
-        h = _tp_vocab_embed(params["embed"], fed, tp).astype(c.dtype)
+    h = _paged_embed(params, fed, c, tp)                        # [B,T,H]
     pos2d = qstart[:, None] + jnp.arange(T, dtype=jnp.int32)    # [B,T]
     cos, sin = build_rope_cache(T, hd, base=c.rope_theta,
                                 position_ids=pos2d)             # [B,T,hd/2]
@@ -1585,24 +1626,9 @@ def llama_paged_verify_step(params, k_pool, v_pool, tables, qstart,
         h, = carry
         p, layer = xs
         x = fused_rms_norm(h, p["input_norm"], c.rms_norm_eps)
-        if "qkv_proj" in p:
-            ratio = c.num_attention_heads // c.num_key_value_heads
-            nkv = _mat_out_dim(p["qkv_proj"]) // hd // (ratio + 2)
-            nh = nkv * ratio
-            qkv = _mat(x, p["qkv_proj"])
-            q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
-            q = q.reshape(B, T, nh, hd)
-            k = k.reshape(B, T, nkv, hd)
-            v = v.reshape(B, T, nkv, hd)
-        else:
-            nh = _mat_out_dim(p["q_proj"]) // hd
-            nkv = _mat_out_dim(p["k_proj"]) // hd
-            q = _mat(x, p["q_proj"]).reshape(B, T, nh, hd)
-            k = _mat(x, p["k_proj"]).reshape(B, T, nkv, hd)
-            v = _mat(x, p["v_proj"]).reshape(B, T, nkv, hd)
+        q, k, v = _paged_qkv(p, x, cos, sin, c)
+        nh, nkv = q.shape[2], k.shape[2]
         kvd = nkv * hd
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
         layer_i = jnp.asarray(layer, jnp.int32)
         rep = nh // nkv
         # t-major block-diagonal rows: row t*NH + i is fed token t's
@@ -1657,14 +1683,7 @@ def llama_paged_verify_step(params, k_pool, v_pool, tables, qstart,
         attn = jnp.einsum("btgred,ge->btgrd",
                           attn_rows.reshape(B, T, nkv, rep, nkv, hd),
                           eye.astype(attn_rows.dtype)).astype(c.dtype)
-        ao = attn.reshape(B, T, nh * hd)
-        attn_out = (_mat(ao, p["o_proj"]) if tp is None
-                    else _tp_o_proj(ao, p["o_proj"], tp))
-        h = h + attn_out
-        x2 = fused_rms_norm(h, p["post_norm"], c.rms_norm_eps)
-        gated = jax.nn.silu(_mat(x2, p["gate_proj"])) * _mat(x2, p["up_proj"])
-        h = h + (_mat(gated, p["down_proj"]) if tp is None
-                 else _tp_down_proj(gated, p["down_proj"], tp))
+        h = _paged_layer_tail(p, h, attn.reshape(B, T, nh * hd), c, tp)
         return (h,), ys
 
     n_layers = k_pool.shape[0]

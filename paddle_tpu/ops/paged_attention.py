@@ -317,26 +317,36 @@ def _paged_update_kernel(lp_ref, sc_ref, q_ref, nk_ref, nv_ref,
         o_ref[0] = acc_s[...] / jnp.maximum(l_s[:, :1], jnp.float32(1e-30))
 
 
-def paged_attend_update(q_bd, new_k, new_v, k_pool, v_pool, tables,
-                        positions, layer, *, n_steps=None):
+def paged_update_walk(tables, positions, block_size):
+    """What the fused update kernels walk for one decode batch, the same in
+    every layer, so a step makes it once before its layer loop: (the flat
+    schedule over every slot of every table, its live total as a traced
+    i32). tables [B, max_nb] i32; positions [B] i32 = the NEW token's
+    position per row (its block must already be in the table). The
+    schedule runs over len+1, so the written position's block is the
+    walk's last live tile even when it was freshly allocated; the kernels'
+    grid ends at the live total (a dynamic grid bound: steps past it would
+    replay the last live one and move nothing, at about a quarter of a
+    microsecond each)."""
+    B, max_nb = tables.shape
+    sched = paged_schedule(positions + 1, tables, B * max_nb, block_size)
+    return sched, jnp.sum(sched[_LIVE], dtype=jnp.int32)
+
+
+def paged_attend_update(q_bd, new_k, new_v, k_pool, v_pool, walk, layer):
     """Fused pool-update + paged attention for one decode layer: writes
     each sequence's new k/v column IN PLACE (the pools alias through
     the custom call) and attends over the prefix INCLUDING it.
 
-    q_bd [B, NH, KVD] pre-scaled; new_k/new_v [B, KVD]; positions [B]
-    i32 = the NEW token's position per row (its block must already be
-    in the table). Every row writes — padding rows must point their
-    tables at the reserved null block 0 with positions 0. Returns
-    (attn [B, NH, KVD] f32, k_pool, v_pool)."""
+    q_bd [B, NH, KVD] pre-scaled; new_k/new_v [B, KVD]; ``walk`` the
+    batch's ``paged_update_walk``. Every row writes — padding rows must
+    point their tables at the reserved null block 0 with positions 0.
+    Returns (attn [B, NH, KVD] f32, k_pool, v_pool)."""
     b, nh, kvd = q_bd.shape
-    L, NP, _, bs = k_pool.shape
-    B, max_nb = tables.shape
-    if n_steps is None:
-        n_steps = B * max_nb
+    bs = k_pool.shape[-1]
     it = jnp.dtype(k_pool.dtype).itemsize
-    # schedule over len+1 so the written position's block is the walk's
-    # last live tile even when it was freshly allocated
-    sched = paged_schedule(positions + 1, tables, n_steps, bs)
+    sched, live_steps = walk
+    n_steps = sched.shape[1]        # the cost estimate's worst case
     lp = jnp.asarray([layer], jnp.int32)
 
     def kv_map(j, lp_ref, sc_ref):
@@ -361,7 +371,7 @@ def paged_attend_update(q_bd, new_k, new_v, k_pool, v_pool, tables,
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
-                grid=(n_steps,),
+                grid=(live_steps,),
                 in_specs=[
                     pl.BlockSpec((1, nh, kvd), q_map),
                     pl.BlockSpec((1, 1, kvd), new_map),
@@ -691,8 +701,8 @@ def _paged_update_quant_kernel(lp_ref, sc_ref, q_ref, nk_ref, nv_ref,
 
 
 def paged_attend_update_quant(q_bd, new_k, new_v, new_ks, new_vs,
-                              k_pool, v_pool, k_scale, v_scale, tables,
-                              positions, layer, *, n_steps=None):
+                              k_pool, v_pool, k_scale, v_scale, walk,
+                              layer):
     """Fused int8 pool-update + paged attention for one decode layer.
 
     Same contract as :func:`paged_attend_update`, except the pools are
@@ -702,14 +712,12 @@ def paged_attend_update_quant(q_bd, new_k, new_v, new_ks, new_vs,
     through the custom call. Returns (attn [B, NH, KVD] f32, k_pool,
     v_pool, k_scale, v_scale)."""
     b, nh, kvd = q_bd.shape
-    L, NP, _, bs = k_pool.shape
+    bs = k_pool.shape[-1]
     nkv = k_scale.shape[2]
-    B, max_nb = tables.shape
-    if n_steps is None:
-        n_steps = B * max_nb
     it = jnp.dtype(k_pool.dtype).itemsize
     kvd_b, bs_b, nkv_b = _fit_paged_kv_blocks(nh, kvd, nkv, bs, it)
-    sched = paged_schedule(positions + 1, tables, n_steps, bs)
+    sched, live_steps = walk
+    n_steps = sched.shape[1]        # the cost estimate's worst case
     lp = jnp.asarray([layer], jnp.int32)
 
     def kv_map(j, lp_ref, sc_ref):
@@ -731,7 +739,7 @@ def paged_attend_update_quant(q_bd, new_k, new_v, new_ks, new_vs,
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
-                grid=(n_steps,),
+                grid=(live_steps,),
                 in_specs=[
                     pl.BlockSpec((1, nh, kvd_b), q_map),
                     pl.BlockSpec((1, 1, kvd_b), new_map),

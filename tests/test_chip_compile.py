@@ -106,11 +106,19 @@ def paged_args(sds):
     q_chunk = sds((FULL.prefill_chunk, NH, KVD // NKV), bf16)
     chunk_at = (sds((MAX_NB,), i32), sds((), i32), sds((), i32), layer)
     return {
-        "attend_update": (pa.paged_attend_update, (
-            q, col[False], col[False], kp, kp, tables, lens, layer)),
-        "attend_update_quant": (pa.paged_attend_update_quant, (
-            q, col[True], col[True], col_s, col_s, kq, kq, scale, scale,
-            tables, lens, layer)),
+        "attend_update": (
+            lambda q, k, v, kp, vp, tables, lens, layer:
+            pa.paged_attend_update(
+                q, k, v, kp, vp, pa.paged_update_walk(tables, lens, BS),
+                layer),
+            (q, col[False], col[False], kp, kp, tables, lens, layer)),
+        "attend_update_quant": (
+            lambda q, k, v, ks, vs, kp, vp, ksp, vsp, tables, lens, layer:
+            pa.paged_attend_update_quant(
+                q, k, v, ks, vs, kp, vp, ksp, vsp,
+                pa.paged_update_walk(tables, lens, BS), layer),
+            (q, col[True], col[True], col_s, col_s, kq, kq, scale, scale,
+             tables, lens, layer)),
         "verify_commit": (pa.paged_verify_commit, (
             fed[False], fed[False], kp, kp, tables, lens, lens)),
         "verify_commit_quant": (pa.paged_verify_commit_quant, (
@@ -159,24 +167,35 @@ def serve_args(sds):
     return L._freeze_config(config), params, pools
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
-@pytest.mark.parametrize("kind", ["decode", "prefill"])
+@pytest.mark.parametrize("kind, quant", [
+    ("decode", False), ("decode", True), ("prefill", False),
+    ("prefill", True), ("prefill+decode", False)],
+    ids=["decode-fp", "decode-int8", "prefill-fp", "prefill-int8",
+         "prefill+decode-fp"])
 def test_engine_step_compiles_with_a_pool_half_of_hbm(serve_args, sds, kind,
                                                       quant):
     """The donated pools must alias through the step: the prefill once
     copied the whole pool to another layout and back (a second pool-sized
-    buffer, refused outright at this pool size)."""
+    buffer, refused outright at this pool size). The chunk that carries the
+    decode batch writes the pools twice a layer (the batch's fused update,
+    then the chunk's columns) before its attention reads them: still in
+    place."""
     frozen, params, pools = serve_args
+    rows = (sds((BATCH, MAX_NB), i32), sds((BATCH,), i32),
+            sds((BATCH,), i32))
+    chunk = (sds((MAX_NB,), i32), sds((), i32),
+             sds((FULL.prefill_chunk,), i32), sds((), i32))
     if kind == "decode":
         fn = (L._jitted_paged_decode_quant if quant
               else L._jitted_paged_decode)(frozen)
-        args = (sds((BATCH, MAX_NB), i32), sds((BATCH,), i32),
-                sds((BATCH,), i32))
-    else:
+        args = rows
+    elif kind == "prefill":
         fn = (L._jitted_paged_prefill_quant if quant
               else L._jitted_paged_prefill)(frozen)
-        args = (sds((MAX_NB,), i32), sds((), i32),
-                sds((FULL.prefill_chunk,), i32), sds((), i32))
+        args = chunk
+    else:
+        fn = L._jitted_paged_prefill_with_decode(frozen)
+        args = chunk + rows
     compiled = compile_for_chip(fn, params, *pools[quant], *args)
     assert "tpu_custom_call" in compiled.as_text()
     pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize
@@ -186,7 +205,7 @@ def test_engine_step_compiles_with_a_pool_half_of_hbm(serve_args, sds, kind,
     assert gib(ma.temp_size_in_bytes) < 1.0, (
         f"{gib(ma.temp_size_in_bytes):.2f} GiB of temporaries beside a "
         f"{gib(pool_bytes):.2f} GiB pool: something pool-sized is copied")
-    if kind == "prefill":
+    if kind != "decode":
         # the chunk's scores stay in VMEM: the dense path held them against
         # every slot of the table as f32 [chunk, heads, max_seq_len] in HBM
         scores = FULL.prefill_chunk * NH * FULL.max_seq_len * 4

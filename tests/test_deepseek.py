@@ -268,6 +268,15 @@ def logit_gaps(m, params, prompt, out, mode="f32"):
     return ref, (ref.max(-1) - ref[rows, np.asarray(out)]) / ref.std(-1)
 
 
+def test_serving_object_offers_the_two_programs_and_no_other():
+    """``step_fn`` answers None for a kind it has no builder for; the engine
+    chooses its iteration by that answer."""
+    _, c, _ = tiny()
+    assert D.DeepSeekServing.step_fn("prefill+decode", c, False, None) is None
+    for kind in ("prefill", "decode"):
+        assert callable(D.DeepSeekServing.step_fn(kind, c, False, None))
+
+
 def test_engine_prefill_then_decode_against_the_reference():
     """Prompts of 5, 70 (three chunks) and 150 tokens (five chunks, two
     blocks) through ``submit()`` / ``step()``, eight new tokens each: every
@@ -282,6 +291,11 @@ def test_engine_prefill_then_decode_against_the_reference():
         _, gaps = logit_gaps(m, params, p, out[i])
         assert gaps.max() <= LOGIT_TOL, (i, gaps)
     assert {("prefill", 32), ("decode", 1)} <= set(eng._compiled)
+    # no chunk carries the decode batch here: two programs, as before
+    assert not eng._chunk_carries
+    assert all(k[0] in ("prefill", "decode") for k in eng._compiled)
+    assert eng.work_totals["prefill_chunks_with_decode_total"] == 0
+    assert eng.work_totals["prefill_chunks_total"] == 1 + 3 + 5
     # the counters the model's steps return: pairs = tokens x 4 x 2 layers
     w = eng.work_totals
     tokens = w["prefill_tokens_total"] + w["decode_rows_total"]

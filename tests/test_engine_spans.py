@@ -162,11 +162,16 @@ def test_one_step_span_per_iteration(runs, config):
 
 @pytest.mark.parametrize("config", list(CONFIGS))
 def test_counts_on_the_spans_add_up_to_the_registry(runs, config):
-    """(d): useful never exceeds attempted, and the four counters are
-    the sums over the recorded spans."""
+    """(d): useful never exceeds attempted, and the counters are the sums
+    over the recorded spans. A ``serve.prefill`` whose program carried the
+    decode batch (the plain cache alone offers it) says so with ``rows`` of
+    a ``bucket`` of ``max_batch``, and its rows count as decoded rows do."""
     eng, events = runs[config]["eng"], runs[config]["events"]
-    decodes = [e[3] for e in events if e[0] == "serve.decode"]
     chunks = [e[3] for e in events if e[0] == "serve.prefill"]
+    carrying = [c for c in chunks if "rows" in c]
+    assert bool(carrying) == (config == "plain")
+    assert all(1 <= c["rows"] <= c["bucket"] == 2 for c in carrying)
+    decodes = [e[3] for e in events if e[0] == "serve.decode"] + carrying
     assert decodes and chunks
     assert all(1 <= d["rows"] <= d["bucket"] <= 2 for d in decodes)
     assert all(1 <= c["n_live"] <= c["chunk"] == 64 for c in chunks)
@@ -187,10 +192,13 @@ def test_counts_on_the_spans_add_up_to_the_registry(runs, config):
     assert snap["prefill_ctx_blocks_total"] \
         == sum(c["ctx_blocks"] for c in chunks) == 1 + 4 + 1
     assert snap["prefill_table_blocks_total"] == 4 * len(chunks)
+    assert snap["prefill_chunks_total"] == len(chunks)
+    assert snap["prefill_chunks_with_decode_total"] == len(carrying)
     prom = eng.render_prometheus()
     for name in ("decode_rows_total", "decode_slots_total",
                  "prefill_tokens_total", "prefill_slots_total",
-                 "prefill_ctx_blocks_total", "prefill_table_blocks_total"):
+                 "prefill_ctx_blocks_total", "prefill_table_blocks_total",
+                 "prefill_chunks_total", "prefill_chunks_with_decode_total"):
         assert f"paddle_tpu_serve_{name} {snap[name]}" in prom
 
 

@@ -25,6 +25,7 @@ Tiny shapes, pallas interpret mode on CPU.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu.inference import InferenceEngine, Request, ServeConfig
@@ -33,6 +34,7 @@ from paddle_tpu.ops import _common
 from paddle_tpu.ops.paged_attention import (_LOG2E, KV_QMAX, KV_SCALE_FLOOR,
                                             kv_quant_columns,
                                             paged_attend_update_quant,
+                                            paged_update_walk,
                                             paged_attention_quant,
                                             paged_attention_xla)
 
@@ -124,7 +126,8 @@ def test_quant_update_writes_prequantized_bytes():
     tables = jnp.asarray([[1, 3]], jnp.int32)
     pos = jnp.asarray([127], jnp.int32)
     out, kp_u, vp_u, ks_u, vs_u = paged_attend_update_quant(
-        qs, nkq, nvq, nks, nvs, kq, vq, ks, vs, tables, pos, 1)
+        qs, nkq, nvq, nks, nvs, kq, vq, ks, vs,
+        paged_update_walk(tables, pos, BS), 1)
     kp_u, ks_u = np.asarray(kp_u), np.asarray(ks_u)
     # the written column is the quantizer's bytes, bitwise
     assert (kp_u[1, 1, :, 127] == np.asarray(nkq)[0]).all()
@@ -145,6 +148,39 @@ def test_quant_update_writes_prequantized_bytes():
         tables, lens, 1, 1.0 / (HD ** 0.5))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("positions", [[127, 128, 300, 0], [0, 0, 0, 0]],
+                         ids=["ragged", "all_padding"])
+def test_quant_update_grid_ended_at_the_last_live_block(positions):
+    """As the plain kernel's: the grid ends at the walk's live total, and
+    driven over every slot of every table instead it writes bitwise the
+    same attention, bytes and scales."""
+    rng = np.random.RandomState(5)
+    b = len(positions)
+    kq, ks = _quantize_pool(rng.randn(L, 12, KVD, BS).astype(np.float32), NKV)
+    vq, vs = _quantize_pool(rng.randn(L, 12, KVD, BS).astype(np.float32), NKV)
+    qs = jnp.asarray(rng.randn(b, NH, KVD).astype(np.float32) * 0.1)
+    nkq, nks = kv_quant_columns(
+        jnp.asarray(rng.randn(b, KVD).astype(np.float32)), NKV)
+    nvq, nvs = kv_quant_columns(
+        jnp.asarray(rng.randn(b, KVD).astype(np.float32)), NKV)
+    tables = np.zeros((b, 3), np.int32)
+    free = iter(range(1, 12))
+    for i, pos in enumerate(positions):
+        for j in range(pos // BS + 1 if pos else 0):
+            tables[i, j] = next(free)
+    sched, live = paged_update_walk(
+        jnp.asarray(tables), jnp.asarray(positions, jnp.int32), BS)
+    assert int(live) == sum(p // BS + 1 for p in positions) < sched.shape[1]
+
+    def run(total):
+        return jax.jit(lambda *a: paged_attend_update_quant(
+            *a[:-1], (sched, a[-1]), 1))(
+                qs, nkq, nvq, nks, nvs, kq, vq, ks, vs, jnp.int32(total))
+
+    for whole, ended in zip(run(sched.shape[1]), run(live)):
+        np.testing.assert_array_equal(np.asarray(whole), np.asarray(ended))
 
 
 @pytest.fixture(scope="module")

@@ -285,6 +285,9 @@ def _legacy_engine_dict(eng):
             eng.work_totals["prefill_ctx_blocks_total"],
         "prefill_table_blocks_total":
             eng.work_totals["prefill_table_blocks_total"],
+        "prefill_chunks_total": eng.work_totals["prefill_chunks_total"],
+        "prefill_chunks_with_decode_total":
+            eng.work_totals["prefill_chunks_with_decode_total"],
     }
 
 

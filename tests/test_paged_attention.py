@@ -21,6 +21,7 @@ Everything runs in pallas interpret mode on CPU with tiny shapes.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu.ops import _common
@@ -30,6 +31,7 @@ from paddle_tpu.ops.paged_attention import (_LOG2E, paged_attend_update,
                                             paged_attention,
                                             paged_attention_xla,
                                             paged_schedule,
+                                            paged_update_walk,
                                             paged_schedule_stats)
 
 L, NH, HD, BS = 2, 4, 32, 128
@@ -102,8 +104,8 @@ def test_fused_update_bitwise_and_cache_contents(data):
     newv = rng.randn(1, KVD).astype(np.float32)
     tables = jnp.asarray([[1, 3]], jnp.int32)
     out, kp_u, vp_u = paged_attend_update(
-        qs, jnp.asarray(newk), jnp.asarray(newv), kp, vp, tables,
-        jnp.asarray([127], jnp.int32), 1)
+        qs, jnp.asarray(newk), jnp.asarray(newv), kp, vp,
+        paged_update_walk(tables, jnp.asarray([127], jnp.int32), BS), 1)
     kc = np.concatenate([pool_k[:, 1:2], pool_k[:, 3:4]], -1)
     vc = np.concatenate([pool_v[:, 1:2], pool_v[:, 3:4]], -1)
     out_s, kcs, vcs = decode_attend_update_slab(
@@ -123,8 +125,8 @@ def test_fused_update_straddles_into_fresh_block(data):
     newv = rng.randn(1, KVD).astype(np.float32)
     tables = jnp.asarray([[1, 3]], jnp.int32)
     out, kp_u, vp_u = paged_attend_update(
-        qs, jnp.asarray(newk), jnp.asarray(newv), kp, vp, tables,
-        jnp.asarray([BS], jnp.int32), 1)
+        qs, jnp.asarray(newk), jnp.asarray(newv), kp, vp,
+        paged_update_walk(tables, jnp.asarray([BS], jnp.int32), BS), 1)
     kc = np.concatenate([pool_k[:, 1:2], pool_k[:, 3:4]], -1)
     vc = np.concatenate([pool_v[:, 1:2], pool_v[:, 3:4]], -1)
     out_s, kcs, _ = decode_attend_update_slab(
@@ -134,6 +136,40 @@ def test_fused_update_straddles_into_fresh_block(data):
     kb3 = np.asarray(kp_u)[1, 3]
     assert (kb3[:, 0] == newk[0]).all()
     assert (kb3 == np.asarray(kcs)[1, 0, :, BS:]).all()
+
+
+@pytest.mark.parametrize("positions", [
+    [127, 128, 300, 0], [0, 0, 0, 0], [383, 5, 255, 256]],
+    ids=["ragged", "all_padding", "at_block_edges"])
+def test_fused_update_grid_ended_at_the_last_live_block(positions):
+    """The grid ends where the walk's live steps end. Driven over every
+    slot of every table instead (the walk's total set to the schedule's
+    length, whose dead steps replay the last live one), the attention of
+    every row and both pools come out bitwise the same: the steps left out
+    moved nothing."""
+    rng = np.random.RandomState(4)
+    b = len(positions)
+    q = jnp.asarray(rng.randn(b, NH, KVD).astype(np.float32) * 0.1)
+    newk = jnp.asarray(rng.randn(b, KVD).astype(np.float32))
+    newv = jnp.asarray(rng.randn(b, KVD).astype(np.float32))
+    kp = jnp.asarray(rng.randn(L, 12, KVD, BS).astype(np.float32))
+    vp = jnp.asarray(rng.randn(L, 12, KVD, BS).astype(np.float32))
+    tables = np.zeros((b, 3), np.int32)
+    free = iter(range(1, 12))
+    for i, pos in enumerate(positions):
+        for j in range(pos // BS + 1 if pos else 0):
+            tables[i, j] = next(free)
+    sched, live = paged_update_walk(
+        jnp.asarray(tables), jnp.asarray(positions, jnp.int32), BS)
+    assert int(live) == sum(p // BS + 1 for p in positions) < sched.shape[1]
+
+    def run(total):
+        return jax.jit(lambda q, k, v, kp, vp, total: paged_attend_update(
+            q, k, v, kp, vp, (sched, total), 1))(
+                q, newk, newv, kp, vp, jnp.int32(total))
+
+    for whole, ended in zip(run(sched.shape[1]), run(live)):
+        np.testing.assert_array_equal(np.asarray(whole), np.asarray(ended))
 
 
 def test_schedule_dead_steps_replay_last_live():

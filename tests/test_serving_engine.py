@@ -94,9 +94,11 @@ def test_deterministic_replay(basic_run):
 
 
 def test_bounded_compiles(basic_run):
-    """One compile per bucketed shape: prefill chunk + decode buckets."""
+    """One compile per bucketed shape: prefill chunk, decode buckets, and
+    the chunk that carries the decode batch (``max_batch`` rows wide)."""
     stats = basic_run["stats"]
-    assert set(stats["compiles"]) <= {"prefill_32", "decode_1", "decode_2"}
+    assert set(stats["compiles"]) <= {"prefill_32", "decode_1", "decode_2",
+                                      "prefill+decode_32_2"}
 
 
 def test_bounded_compiles_speculative(model):

@@ -83,9 +83,11 @@ def test_tp_streams_bit_identical(model, kw):
     assert e1.pool.used_blocks == 0 and e2.pool.used_blocks == 0
     assert e2.stats()["mp"] == 2
     # the compiled-shape family is bounded: sharding changes the mesh a
-    # program runs on, never which programs exist
+    # program runs on, never which programs exist, but for the chunk that
+    # carries the decode batch, which one chip alone offers
     assert (sorted(e2.stats()["compiles"])
-            == sorted(e1.stats()["compiles"]))
+            == sorted(c for c in e1.stats()["compiles"]
+                      if not c.startswith("prefill+decode")))
 
 
 def test_tp_parity_under_eviction(model):
