@@ -326,13 +326,9 @@ class Server:
         pools = (pool, pool, scale, scale) if quant else (pool, pool)
         b, i32 = s.max_batch, jnp.int32
         args = (sds((b, max_nb), i32), sds((b,), i32), sds((b,), i32))
-        if kind == "decode":
-            fn = (L._jitted_paged_decode_quant if quant
-                  else L._jitted_paged_decode)(fz)
-        else:
-            fn = (L._jitted_paged_verify_quant if quant
-                  else L._jitted_paged_verify)(fz)
+        if kind == "verify":
             args += (sds((b, s.draft_k + 1), i32),)
+        fn = L._jitted_paged_step(kind, fz, quant, None)
         require_custom_calls(
             fn.lower(shapes_of(self.params), *pools, *args).compile(),
             f"{tag} {kind} step")

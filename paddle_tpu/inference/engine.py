@@ -17,9 +17,9 @@ compiled step family per bucketed shape —
     the chunk AND the decode batch in one layer scan, for an iteration
     that has both, so that the weights stream once for it and the host
     launches and waits once (piggybacked decodes: Sarathi-Serve). Only
-    where the model's serving object offers it (``_LlamaServing``: the
-    plain cache on one chip); without it such an iteration runs the two
-    programs above one after the other.
+    where the model's serving object offers it (``models/llama.py``
+    ``LlamaServing``: the plain cache on one chip); without it such an
+    iteration runs the two programs above one after the other.
 
 Recompiles are therefore bounded by ``len(decode_buckets) + 2`` and
 counted (``serve.compile.*`` counters + StepMetrics.record_compile).
@@ -84,26 +84,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import envs
 from ..testing import faults
-from ..models.llama import (LlamaConfig, ParallelConfig, _freeze_config,
-                            _jitted_paged_decode,
-                            _jitted_paged_decode_quant,
-                            _jitted_paged_decode_quant_tp,
-                            _jitted_paged_decode_tp,
-                            _jitted_paged_prefill,
-                            _jitted_paged_prefill_quant,
-                            _jitted_paged_prefill_quant_tp,
-                            _jitted_paged_prefill_tp,
-                            _jitted_paged_prefill_with_decode,
-                            _jitted_paged_verify,
-                            _jitted_paged_verify_quant,
-                            _jitted_paged_verify_quant_tp,
-                            _jitted_paged_verify_tp, init_paged_kv_pool,
-                            init_paged_kv_scales, make_draft_model,
-                            make_mesh, param_pspecs)
 from ..observability.flight_recorder import (FlightRecorder,
                                              flight_recorder_enabled)
 from ..observability.histogram import LogHistogram
@@ -356,68 +339,14 @@ _WORK_TOTALS = {"rows": "decode_rows_total", "bucket": "decode_slots_total",
                 "table_blocks": "prefill_table_blocks_total"}
 
 
-class _LlamaServing:
-    """What the engine asks of a model, chosen by the config's type
-    (:func:`_serving_for`): the frozen config its jitted programs are keyed
-    by, the cache arrays (a tuple, each indexed by block id on axis 1), and
-    the jitted programs ``fn(params, *cache, ...) -> (..., *cache[, counts])``
-    by ``kind``: ``prefill`` (one chunk of one prompt), ``decode`` (one token
-    a running row), ``verify`` (speculation) and, where the model offers it,
-    ``prefill+decode`` (a chunk with the decode batch riding it:
-    ``fn(params, *cache, <the chunk's inputs>, <the batch's>) -> (chunk
-    logits, row logits, *cache)``). ``step_fn`` returns None for a kind it
-    does not offer, and the engine then runs the iteration with the
-    programs it has. This one is Llama's: plain, int8, tensor-parallel and
-    verify builders; the chunk that carries the batch for the plain cache on
-    one chip alone; an int8 cache is (k, v, k_scale, v_scale). ``work`` names
-    the registry counters a model adds to ``_WORK_TOTALS``' (none here)."""
-
-    work: Dict[str, str] = {}
-    # (kind, int8 cache): (the plain builder, its mp-sharded twin: the same
-    # argument lists and output tuples)
-    _BUILDERS = {
-        ("prefill", False): (_jitted_paged_prefill,
-                             _jitted_paged_prefill_tp),
-        ("prefill", True): (_jitted_paged_prefill_quant,
-                            _jitted_paged_prefill_quant_tp),
-        ("decode", False): (_jitted_paged_decode, _jitted_paged_decode_tp),
-        ("decode", True): (_jitted_paged_decode_quant,
-                           _jitted_paged_decode_quant_tp),
-        ("verify", False): (_jitted_paged_verify, _jitted_paged_verify_tp),
-        ("verify", True): (_jitted_paged_verify_quant,
-                           _jitted_paged_verify_quant_tp),
-        ("prefill+decode", False): (_jitted_paged_prefill_with_decode, None),
-    }
-
-    @staticmethod
-    def refuse(**features) -> None:
-        """Raise for a serving feature this model cannot run: none."""
-
-    freeze = staticmethod(_freeze_config)
-
-    @staticmethod
-    def init_cache(config, num_blocks: int, block_size: int,
-                   kv_dtype: str) -> Tuple:
-        kv = init_paged_kv_pool(config, num_blocks, block_size,
-                                kv_dtype=kv_dtype)
-        if kv_dtype == "int8":
-            kv += init_paged_kv_scales(config, num_blocks, block_size)
-        return kv
-
-    @classmethod
-    def step_fn(cls, kind: str, frozen, quant: bool, mesh):
-        plain, tp = cls._BUILDERS.get((kind, quant), (None, None))
-        if mesh is None:
-            return plain and plain(frozen)
-        return tp and tp(frozen, mesh)
-
-
 def _serving_for(config):
-    """The serving side of ``config``'s model (see :class:`_LlamaServing`)."""
-    from ..models import deepseek
+    """The serving side of ``config``'s model: what a model hands the engine
+    lives beside the model (see ``models/llama.py`` ``LlamaServing``), and
+    this module imports nothing else of ``paddle_tpu.models``."""
+    from ..models import deepseek, llama
     if isinstance(config, deepseek.DeepSeekConfig):
         return deepseek.DeepSeekServing
-    return _LlamaServing
+    return llama.LlamaServing
 
 
 class InferenceEngine:
@@ -429,7 +358,7 @@ class InferenceEngine:
     Greedy decoding; one engine owns its device pools, so drive it from
     a single thread."""
 
-    def __init__(self, params: Dict[str, Any], config: LlamaConfig,
+    def __init__(self, params: Dict[str, Any], config: Any,
                  serve: Optional[ServeConfig] = None,
                  telemetry: Optional[StepMetrics] = None,
                  record_events: bool = False,
@@ -437,7 +366,7 @@ class InferenceEngine:
                  flight_recorder: Optional[bool] = None,
                  journal: Optional[str] = None,
                  draft_params: Optional[Dict[str, Any]] = None,
-                 draft_config: Optional[LlamaConfig] = None):
+                 draft_config: Any = None):
         self.params = params
         self.config = config
         self.serve = serve or ServeConfig()
@@ -467,7 +396,7 @@ class InferenceEngine:
         spec = (self.serve.speculative
                 if self.serve.speculative is not None
                 else envs.get(ENV_SERVE_SPEC))
-        # what the model brings (see _LlamaServing); it refuses loudly, here,
+        # what the model brings (see _serving_for); it refuses loudly, here,
         # what it cannot run
         self.model = _serving_for(config)
         self.model.refuse(mp=self.mp, kv_dtype=self.kv_dtype,
@@ -502,7 +431,7 @@ class InferenceEngine:
         if self.draft_k < 1:
             raise ValueError(f"draft_k must be >= 1, got {self.draft_k}")
         self.draft_params: Optional[Dict[str, Any]] = None
-        self.draft_config: Optional[LlamaConfig] = None
+        self.draft_config: Any = None
         self._draft_frozen: Optional[Tuple] = None
         self._spec_proposed = 0
         self._spec_accepted = 0
@@ -510,21 +439,26 @@ class InferenceEngine:
             if draft_params is None:
                 # default draft: the base model truncated to its first
                 # layer, sharing embedding/head weights by reference
-                draft_params, draft_config = make_draft_model(params,
-                                                              config)
+                draft_params, draft_config = self.model.draft(params, config)
             elif draft_config is None:
                 raise ValueError("draft_params given without draft_config")
             self.draft_params = draft_params
             self.draft_config = draft_config
-            self._draft_frozen = _freeze_config(draft_config)
+            self._draft_frozen = self.model.freeze(draft_config)
             # the draft pools mirror the base pool's block geometry (one
             # shared block table per sequence) but always store the
             # model dtype: draft KV only shapes proposals, never output
             # bytes, so int8 buys nothing there
-            self.kv_draft = init_paged_kv_pool(
-                draft_config, self.serve.num_blocks, self.serve.block_size)
+            self.kv_draft = self.model.init_cache(
+                draft_config, self.serve.num_blocks, self.serve.block_size,
+                "auto")
         if self.mp > 1:
-            self._shard_tp()
+            # the mesh, the weights sliced over it and every pool sharded by
+            # kv head: the model knows its own parameter tree
+            (self.mesh, self.params, self.kv, self.draft_params,
+             self.kv_draft) = self.model.place(
+                self.mp, params, config, self.kv, self.draft_params,
+                self.draft_config, self.kv_draft)
         self.metrics = telemetry
         self.record_events = record_events
         # request-lifecycle tracing is measurement-only: spans are recorded
@@ -640,59 +574,6 @@ class InferenceEngine:
         lambda self: self.kv_draft[0] if self.kv_draft else None)
     v_draft = property(
         lambda self: self.kv_draft[1] if self.kv_draft else None)
-
-    def _shard_tp(self) -> None:
-        """Build the serving mesh and place weights + pools for mp > 1.
-
-        Weight slicing follows ``param_pspecs`` over 'mp' alone
-        (column-parallel q/k/v/gate/up, row-parallel o/down, vocab-
-        parallel embed + lm_head); every KV/scale pool — fp16, int8 and
-        draft — shards its kv-head-major axis 2. Rejects geometries the
-        contiguous-head slicing cannot express (see PARITY.md PR 19)."""
-        c, mp = self.config, self.mp
-        for dim, name in ((c.num_attention_heads, "num_attention_heads"),
-                          (c.num_key_value_heads, "num_key_value_heads"),
-                          (c.vocab_size, "vocab_size"),
-                          (c.intermediate_size, "intermediate_size")):
-            if dim % mp:
-                raise ValueError(
-                    f"ServeConfig.mp={mp} needs {name} % mp == 0 "
-                    f"(got {dim}): heads/vocab/ffn slice contiguously "
-                    f"across ranks")
-        ndev = len(jax.devices())
-        if ndev < mp:
-            raise ValueError(f"ServeConfig.mp={mp} needs {mp} devices, "
-                             f"have {ndev}")
-        if "qkv_proj" in self.params.get("layers", {}):
-            raise ValueError(
-                "tensor-parallel serving needs split q/k/v projections; "
-                "fused qkv_proj weights interleave heads and cannot "
-                "slice contiguously over 'mp'")
-        for tree in (self.params, self.draft_params or {}):
-            for leaf in jax.tree_util.tree_leaves(
-                    tree, is_leaf=lambda x: isinstance(x, dict) and
-                    ("w" in x or "wT" in x)):
-                if isinstance(leaf, dict):
-                    raise ValueError(
-                        "tensor-parallel serving takes plain weight "
-                        "arrays; int8/transposed weight dicts don't "
-                        "carry the param_pspecs tree")
-        self.mesh = make_mesh(ParallelConfig(mp=mp))
-
-        def put(tree, cfg):
-            specs = param_pspecs(cfg, ParallelConfig(mp=mp))
-            shardings = jax.tree_util.tree_map(
-                lambda s: NamedSharding(self.mesh, s), specs,
-                is_leaf=lambda x: isinstance(x, P))
-            return jax.device_put(tree, shardings)
-
-        self.params = put(self.params, c)
-        pool_sh = NamedSharding(self.mesh, P(None, None, "mp", None))
-        self.kv = tuple(jax.device_put(a, pool_sh) for a in self.kv)
-        if self.speculative:
-            self.draft_params = put(self.draft_params, self.draft_config)
-            self.kv_draft = tuple(jax.device_put(a, pool_sh)
-                                  for a in self.kv_draft)
 
     def _register_metrics(self) -> None:
         """Register every engine metric into the unified registry: the

@@ -509,7 +509,7 @@ def _jitted_paged_prefill(c: DeepSeekConfig):
 
 
 class DeepSeekServing:
-    """What ``InferenceEngine`` asks of a model (``engine._LlamaServing`` is
+    """What ``InferenceEngine`` asks of a model (``llama.LlamaServing`` is
     Llama's): the frozen config, the cache (ONE latent pool), the jitted
     programs, which return ``counts`` after the cache, and the registry
     counters those feed."""

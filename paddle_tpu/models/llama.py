@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1137,10 +1137,10 @@ def _scan_paged_layers(layer_step, h, pools, params):
                     (h, tuple(pools)), xs)[0]
 
 
-def llama_paged_decode_step(params, k_pool, v_pool, tables, positions,
-                            ids, config: LlamaConfig, kv_scales=None,
-                            tp=None):
-    """One decode step over a PAGED cache: ids [B] i32, tables
+def llama_paged_decode_step(params, pools, tables, positions, ids,
+                            config: LlamaConfig, tp=None):
+    """One decode step over a PAGED cache: ``pools`` (k, v), or the int8
+    (k, v, k_scale, v_scale) (see ``_paged_attend_rows``); ids [B] i32, tables
     [B, max_nb] i32 block tables, positions [B] i32 = the slot each
     row's new token occupies (== its cached length; the block holding
     it must already be in the table). Per-row rope phases come from
@@ -1148,21 +1148,17 @@ def llama_paged_decode_step(params, k_pool, v_pool, tables, positions,
     depth — the whole point of continuous batching. Padding rows point
     their tables at null block 0 with positions 0.
 
-    Returns (logits [B, vocab] f32, k_pool, v_pool). The pools ride
+    Returns (logits [B, vocab] f32, *pools). The pools ride
     the layer scan as carries and the Pallas kernel updates them
     in-place through input_output_aliases, so no per-layer cache copy
     exists (the conservative-aliasing trap documented in
-    ops/decode_attention.py STATUS).
-
-    With ``kv_scales=(k_scale, v_scale)`` the pools are int8 (see
-    ``_paged_attend_rows``). Returns (logits, k_pool, v_pool, k_scale,
-    v_scale) in that mode."""
+    ops/decode_attention.py STATUS)."""
     from ..ops.paged_attention import paged_update_walk
     c = config
     h = _paged_embed(params, ids, c, tp)[:, None]               # [B, 1, H]
     cos, sin = build_rope_cache(ids.shape[0], c.head_dim, base=c.rope_theta,
                                 position_ids=positions[:, None])  # [B,1,·]
-    walk = paged_update_walk(tables, positions, k_pool.shape[-1])
+    walk = paged_update_walk(tables, positions, pools[0].shape[-1])
 
     def layer_step(h, pools, p, layer):
         x = fused_rms_norm(h, p["input_norm"], c.rms_norm_eps)
@@ -1171,8 +1167,7 @@ def llama_paged_decode_step(params, k_pool, v_pool, tables, positions,
                                        walk, layer, c)
         return _paged_layer_tail(p, h, ao[:, None], c, tp), pools
 
-    h, pools = _scan_paged_layers(
-        layer_step, h, (k_pool, v_pool) + tuple(kv_scales or ()), params)
+    h, pools = _scan_paged_layers(layer_step, h, pools, params)
     logits = llama_logits(params, h, config)[:, 0]
     return (logits.astype(jnp.float32),) + pools
 
@@ -1272,10 +1267,10 @@ def _paged_attend_chunk(q, k, v, pools, table_row, start, n_live, layer,
     return attn.reshape(C, -1), pools
 
 
-def llama_paged_prefill_chunk(params, k_pool, v_pool, table_row, start,
-                              ids, n_live, config: LlamaConfig,
-                              kv_scales=None, tp=None):
-    """One chunked-prefill slice for ONE sequence: ids [C] i32 padded
+def llama_paged_prefill_chunk(params, pools, table_row, start, ids, n_live,
+                              config: LlamaConfig, tp=None):
+    """One chunked-prefill slice for ONE sequence over ``pools`` ((k, v) or
+    the int8 four, see ``_paged_attend_chunk``): ids [C] i32 padded
     to the chunk bucket, n_live (traced) real tokens, start (traced) =
     tokens already cached from earlier chunks. Scatters the chunk's KV
     into the sequence's blocks (padding tokens land in null block 0),
@@ -1284,18 +1279,15 @@ def llama_paged_prefill_chunk(params, k_pool, v_pool, table_row, start,
     (ops.paged_attention.paged_prefill_attention: the pools are read back
     after the write, so the chunk's own columns come from there too), and
     returns the logits of the LAST REAL token ([vocab] f32 — only
-    meaningful on the final chunk) plus the updated pools.
-
-    With ``kv_scales=(k_scale, v_scale)`` the pools are int8 (see
-    ``_paged_attend_chunk``). Returns (logits, k_pool, v_pool, k_scale,
-    v_scale) in that mode."""
+    meaningful on the final chunk) plus the updated pools:
+    (logits, *pools)."""
     c = config
     C = ids.shape[0]
     h = _paged_embed(params, ids, c, tp)[None]                  # [1, C, H]
     pidx = start + jnp.arange(C, dtype=jnp.int32)          # [C] positions
     cos, sin = build_rope_cache(C, c.head_dim, base=c.rope_theta,
                                 position_ids=pidx)         # [C, hd/2]
-    where = _chunk_window(table_row, start, n_live, C, k_pool.shape[-1])
+    where = _chunk_window(table_row, start, n_live, C, pools[0].shape[-1])
 
     def layer_step(h, pools, p, layer):
         x = fused_rms_norm(h, p["input_norm"], c.rms_norm_eps)
@@ -1304,17 +1296,16 @@ def llama_paged_prefill_chunk(params, k_pool, v_pool, table_row, start,
                                         start, n_live, layer, where)
         return _paged_layer_tail(p, h, ao[None], c, tp), pools
 
-    h, pools = _scan_paged_layers(
-        layer_step, h, (k_pool, v_pool) + tuple(kv_scales or ()), params)
+    h, pools = _scan_paged_layers(layer_step, h, pools, params)
     h_last = lax.dynamic_slice_in_dim(h[0], n_live - 1, 1, 0)[None]
     logits = llama_logits(params, h_last, config)[0, 0]
     return (logits.astype(jnp.float32),) + pools
 
 
-def llama_paged_prefill_chunk_with_decode(params, k_pool, v_pool, table_row,
-                                          start, ids, n_live, tables,
-                                          positions, row_ids,
-                                          config: LlamaConfig):
+def llama_paged_prefill_chunk_with_decode(params, pools, table_row, start,
+                                          ids, n_live, tables, positions,
+                                          row_ids, config: LlamaConfig,
+                                          tp=None):
     """A prefill chunk with the decode batch riding it, for an iteration
     that has both: ONE layer scan over the chunk's C rows (``table_row``,
     ``start``, ``ids``, ``n_live`` as ``llama_paged_prefill_chunk`` takes
@@ -1330,18 +1321,18 @@ def llama_paged_prefill_chunk_with_decode(params, k_pool, v_pool, table_row,
     attention is the pools' last reader in a layer and nothing copies them.
 
     Returns (the chunk's last-live-token logits [vocab] f32, the batch's
-    logits [R, vocab] f32, k_pool, v_pool)."""
+    logits [R, vocab] f32, *pools)."""
     from ..ops.paged_attention import paged_update_walk
     c = config
     C = ids.shape[0]
     h = _paged_embed(params, jnp.concatenate([ids, row_ids]), c,
-                     None)[None]                            # [1, C + R, H]
+                     tp)[None]                              # [1, C + R, H]
     pidx = jnp.concatenate([start + jnp.arange(C, dtype=jnp.int32),
                             positions])
     cos, sin = build_rope_cache(pidx.shape[0], c.head_dim,
                                 base=c.rope_theta, position_ids=pidx)
-    where = _chunk_window(table_row, start, n_live, C, k_pool.shape[-1])
-    walk = paged_update_walk(tables, positions, k_pool.shape[-1])
+    where = _chunk_window(table_row, start, n_live, C, pools[0].shape[-1])
+    walk = paged_update_walk(tables, positions, pools[0].shape[-1])
 
     def layer_step(h, pools, p, layer):
         x = fused_rms_norm(h, p["input_norm"], c.rms_norm_eps)
@@ -1352,194 +1343,13 @@ def llama_paged_prefill_chunk_with_decode(params, k_pool, v_pool, table_row,
                                               table_row, start, n_live,
                                               layer, where)
         ao = jnp.concatenate([ao_chunk, ao_rows])[None]
-        return _paged_layer_tail(p, h, ao, c, None), pools
+        return _paged_layer_tail(p, h, ao, c, tp), pools
 
-    h, pools = _scan_paged_layers(layer_step, h, (k_pool, v_pool), params)
+    h, pools = _scan_paged_layers(layer_step, h, pools, params)
     h_last = lax.dynamic_slice_in_dim(h[0], n_live - 1, 1, 0)
     heads = jnp.concatenate([h_last, h[0, C:]])[:, None]    # [1 + R, 1, H]
     logits = llama_logits(params, heads, config)[:, 0].astype(jnp.float32)
     return (logits[0], logits[1:]) + pools
-
-
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_decode(frozen):
-    config = LlamaConfig(*frozen)
-
-    def paged_decode_fn(params, kp, vp, tables, positions, ids):
-        return llama_paged_decode_step(params, kp, vp, tables, positions,
-                                       ids, config)
-    paged_decode_fn.__name__ = "paged_decode_step"
-    return jax.jit(paged_decode_fn, donate_argnums=(1, 2))
-
-
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_prefill(frozen):
-    config = LlamaConfig(*frozen)
-
-    def paged_prefill_fn(params, kp, vp, table_row, start, ids, n_live):
-        return llama_paged_prefill_chunk(params, kp, vp, table_row,
-                                         start, ids, n_live, config)
-    paged_prefill_fn.__name__ = "paged_prefill_chunk"
-    return jax.jit(paged_prefill_fn, donate_argnums=(1, 2))
-
-
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_prefill_with_decode(frozen):
-    config = LlamaConfig(*frozen)
-
-    def paged_prefill_with_decode_fn(params, kp, vp, table_row, start, ids,
-                                     n_live, tables, positions, row_ids):
-        return llama_paged_prefill_chunk_with_decode(
-            params, kp, vp, table_row, start, ids, n_live, tables,
-            positions, row_ids, config)
-    paged_prefill_with_decode_fn.__name__ = "paged_prefill_chunk_with_decode"
-    return jax.jit(paged_prefill_with_decode_fn, donate_argnums=(1, 2))
-
-
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_decode_quant(frozen):
-    config = LlamaConfig(*frozen)
-
-    def paged_decode_quant_fn(params, kp, vp, ks, vs, tables, positions,
-                              ids):
-        return llama_paged_decode_step(params, kp, vp, tables, positions,
-                                       ids, config, kv_scales=(ks, vs))
-    paged_decode_quant_fn.__name__ = "paged_decode_step_int8"
-    return jax.jit(paged_decode_quant_fn, donate_argnums=(1, 2, 3, 4))
-
-
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_prefill_quant(frozen):
-    config = LlamaConfig(*frozen)
-
-    def paged_prefill_quant_fn(params, kp, vp, ks, vs, table_row, start,
-                               ids, n_live):
-        return llama_paged_prefill_chunk(params, kp, vp, table_row,
-                                         start, ids, n_live, config,
-                                         kv_scales=(ks, vs))
-    paged_prefill_quant_fn.__name__ = "paged_prefill_chunk_int8"
-    return jax.jit(paged_prefill_quant_fn, donate_argnums=(1, 2, 3, 4))
-
-
-# KV/scale pools [L, NP, NKV*HD|NKV, bs] shard their kv-head-major axis
-# 2 across 'mp' — each rank runs the unchanged paged kernels (and their
-# shape-priced _fit_* fitters) on its head shard with the SAME
-# rank-replicated block tables, so BlockPool / PrefixCache / the commit
-# schedule stay host-side and rank-agnostic.
-_TP_POOL_SPEC = P(None, None, "mp", None)
-
-
-def _tp_specs(config: LlamaConfig, mesh: Mesh):
-    """(param pspec tree, ``tp`` tuple) for a serving island: weights
-    sliced per param_pspecs over 'mp' alone (no fsdp inside the serving
-    mesh). The trees only match PLAIN param arrays — the engine rejects
-    fused/int8 weight dicts under TP at init."""
-    n = int(mesh.shape["mp"])
-    return param_pspecs(config, ParallelConfig(mp=n)), ("mp", n)
-
-
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_decode_tp(frozen, mesh):
-    """mp-sharded twin of _jitted_paged_decode: one fully-manual
-    shard_map island per decode step (the paged Pallas kernels cannot be
-    auto-partitioned under GSPMD). Logits leave vocab-sharded
-    P(None, 'mp') — the engine's host argmax reads the exact concat."""
-    config = LlamaConfig(*frozen)
-    pspecs, tp = _tp_specs(config, mesh)
-    rep = P()
-
-    def step(params, kp, vp, tables, positions, ids):
-        return llama_paged_decode_step(params, kp, vp, tables, positions,
-                                       ids, config, tp=tp)
-
-    body = shard_map(
-        step, mesh=mesh,
-        in_specs=(pspecs, _TP_POOL_SPEC, _TP_POOL_SPEC, rep, rep, rep),
-        out_specs=(P(None, "mp"), _TP_POOL_SPEC, _TP_POOL_SPEC),
-        check_vma=False)
-
-    def paged_decode_tp_fn(params, kp, vp, tables, positions, ids):
-        return body(params, kp, vp, tables, positions, ids)
-    paged_decode_tp_fn.__name__ = "paged_decode_step_tp"
-    return jax.jit(paged_decode_tp_fn, donate_argnums=(1, 2))
-
-
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_decode_quant_tp(frozen, mesh):
-    config = LlamaConfig(*frozen)
-    pspecs, tp = _tp_specs(config, mesh)
-    rep = P()
-
-    def step(params, kp, vp, ks, vs, tables, positions, ids):
-        return llama_paged_decode_step(params, kp, vp, tables, positions,
-                                       ids, config, kv_scales=(ks, vs),
-                                       tp=tp)
-
-    body = shard_map(
-        step, mesh=mesh,
-        in_specs=(pspecs, _TP_POOL_SPEC, _TP_POOL_SPEC, _TP_POOL_SPEC,
-                  _TP_POOL_SPEC, rep, rep, rep),
-        out_specs=(P(None, "mp"), _TP_POOL_SPEC, _TP_POOL_SPEC,
-                   _TP_POOL_SPEC, _TP_POOL_SPEC),
-        check_vma=False)
-
-    def paged_decode_quant_tp_fn(params, kp, vp, ks, vs, tables,
-                                 positions, ids):
-        return body(params, kp, vp, ks, vs, tables, positions, ids)
-    paged_decode_quant_tp_fn.__name__ = "paged_decode_step_int8_tp"
-    return jax.jit(paged_decode_quant_tp_fn, donate_argnums=(1, 2, 3, 4))
-
-
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_prefill_tp(frozen, mesh):
-    config = LlamaConfig(*frozen)
-    pspecs, tp = _tp_specs(config, mesh)
-    rep = P()
-
-    def step(params, kp, vp, table_row, start, ids, n_live):
-        return llama_paged_prefill_chunk(params, kp, vp, table_row,
-                                         start, ids, n_live, config,
-                                         tp=tp)
-
-    body = shard_map(
-        step, mesh=mesh,
-        in_specs=(pspecs, _TP_POOL_SPEC, _TP_POOL_SPEC, rep, rep, rep,
-                  rep),
-        out_specs=(P("mp"), _TP_POOL_SPEC, _TP_POOL_SPEC),
-        check_vma=False)
-
-    def paged_prefill_tp_fn(params, kp, vp, table_row, start, ids,
-                            n_live):
-        return body(params, kp, vp, table_row, start, ids, n_live)
-    paged_prefill_tp_fn.__name__ = "paged_prefill_chunk_tp"
-    return jax.jit(paged_prefill_tp_fn, donate_argnums=(1, 2))
-
-
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_prefill_quant_tp(frozen, mesh):
-    config = LlamaConfig(*frozen)
-    pspecs, tp = _tp_specs(config, mesh)
-    rep = P()
-
-    def step(params, kp, vp, ks, vs, table_row, start, ids, n_live):
-        return llama_paged_prefill_chunk(params, kp, vp, table_row,
-                                         start, ids, n_live, config,
-                                         kv_scales=(ks, vs), tp=tp)
-
-    body = shard_map(
-        step, mesh=mesh,
-        in_specs=(pspecs, _TP_POOL_SPEC, _TP_POOL_SPEC, _TP_POOL_SPEC,
-                  _TP_POOL_SPEC, rep, rep, rep, rep),
-        out_specs=(P("mp"), _TP_POOL_SPEC, _TP_POOL_SPEC, _TP_POOL_SPEC,
-                   _TP_POOL_SPEC),
-        check_vma=False)
-
-    def paged_prefill_quant_tp_fn(params, kp, vp, ks, vs, table_row,
-                                  start, ids, n_live):
-        return body(params, kp, vp, ks, vs, table_row, start, ids,
-                    n_live)
-    paged_prefill_quant_tp_fn.__name__ = "paged_prefill_chunk_int8_tp"
-    return jax.jit(paged_prefill_quant_tp_fn, donate_argnums=(1, 2, 3, 4))
 
 
 # ---------------------------------------------------------------------------
@@ -1570,11 +1380,11 @@ def make_draft_model(params, config: LlamaConfig, num_layers: int = 1):
     return dparams, dcfg
 
 
-def llama_paged_verify_step(params, k_pool, v_pool, tables, qstart,
-                            t_live, fed, config: LlamaConfig,
-                            kv_scales=None, tp=None):
+def llama_paged_verify_step(params, pools, tables, qstart, t_live, fed,
+                            config: LlamaConfig, tp=None):
     """Score T fed tokens per sequence in ONE base-model pass over a
-    paged cache, greedily accept/reject, and commit only accepted KV.
+    paged cache (``pools``: (k, v), or the int8 (k, v, k_scale, v_scale)),
+    greedily accept/reject, and commit only accepted KV.
 
     fed [B, T] i32 — fed[:, 0] is each row's last emitted token (its KV
     is NOT yet cached), fed[:, 1:] the draft's proposals; qstart [B]
@@ -1594,15 +1404,13 @@ def llama_paged_verify_step(params, k_pool, v_pool, tables, qstart,
     next iteration's fed[:, 0], exactly like sequential decode).
 
     Returns (out [B, T] i32, commit_len [B] i32, fin_ok [B] bool,
-    k_pool, v_pool) — the emitted tokens for row b are
+    *pools) — the emitted tokens for row b are
     out[b, :commit_len[b]]; fin_ok flags rows whose logits were all
     finite (the engine's poison screen — it never sees logits). With
-    ``kv_scales=(k_scale, v_scale)`` the pools are int8: fed columns
-    quantize OUTSIDE the kernels via kv_quant_columns and the fed-block
-    attention reads the DEQUANTIZED values, so both the committed bytes
-    and the numerics each token sees match sequential int8 decode.
-    Returns (out, commit_len, fin_ok, k_pool, v_pool, k_scale,
-    v_scale) in that mode."""
+    int8 pools the fed columns quantize OUTSIDE the kernels via
+    kv_quant_columns and the fed-block attention reads the DEQUANTIZED
+    values, so both the committed bytes and the numerics each token sees
+    match sequential int8 decode."""
     from ..ops.paged_attention import (_LOG2E, kv_quant_columns,
                                        merge_verify_partials,
                                        paged_attention_verify,
@@ -1639,18 +1447,17 @@ def llama_paged_verify_step(params, k_pool, v_pool, tables, qstart,
             .reshape(B, T * nh, kvd)
         qs = (q_bd.astype(jnp.float32)
               * (_LOG2E / (hd ** 0.5))).astype(q_bd.dtype)
-        if kv_scales is None:
+        if len(pools) == 2:
             acc_c, m_c, l_c = paged_attention_verify(
-                qs, k_pool, v_pool, tables, qstart, layer_i)
+                qs, *pools, tables, qstart, layer_i)
             # fed columns AS STORED (pool dtype round-trip): the exact
             # values sequential decode would read back from the cache
-            k_st = k.reshape(B, T, kvd).astype(k_pool.dtype)
-            v_st = v.reshape(B, T, kvd).astype(v_pool.dtype)
+            k_st = k.reshape(B, T, kvd).astype(pools[0].dtype)
+            v_st = v.reshape(B, T, kvd).astype(pools[1].dtype)
             kf = k_st.astype(jnp.float32)
             vf = v_st.astype(jnp.float32)
             ys = (k_st, v_st)
         else:
-            ksc, vsc = kv_scales
             kq, ksq = kv_quant_columns(k.reshape(B * T, kvd), nkv)
             vq, vsq = kv_quant_columns(v.reshape(B * T, kvd), nkv)
             kq = kq.reshape(B, T, kvd)
@@ -1658,7 +1465,7 @@ def llama_paged_verify_step(params, k_pool, v_pool, tables, qstart,
             ksq = ksq.reshape(B, T, nkv)
             vsq = vsq.reshape(B, T, nkv)
             acc_c, m_c, l_c = paged_attention_verify_quant(
-                qs, k_pool, v_pool, ksc, vsc, tables, qstart, layer_i)
+                qs, *pools, tables, qstart, layer_i)
             kf = (kq.astype(jnp.float32).reshape(B, T, nkv, hd)
                   * ksq[..., None]).reshape(B, T, kvd)
             vf = (vq.astype(jnp.float32).reshape(B, T, nkv, hd)
@@ -1686,8 +1493,8 @@ def llama_paged_verify_step(params, k_pool, v_pool, tables, qstart,
         h = _paged_layer_tail(p, h, attn.reshape(B, T, nh * hd), c, tp)
         return (h,), ys
 
-    n_layers = k_pool.shape[0]
-    xs = (params["layers"], jnp.arange(n_layers, dtype=jnp.int32))
+    xs = (params["layers"],
+          jnp.arange(pools[0].shape[0], dtype=jnp.int32))
     (h,), cols = lax.scan(layer_step, (h,), xs)
     logits = llama_logits(params, h, config).astype(jnp.float32)
     if tp is not None:
@@ -1710,94 +1517,194 @@ def llama_paged_verify_step(params, k_pool, v_pool, tables, qstart,
     else:
         accepted = jnp.zeros((B,), jnp.int32)
     commit_len = jnp.where(t_live > 0, accepted + 1, 0).astype(jnp.int32)
-    if kv_scales is None:
-        k_cols, v_cols = cols
-        kp, vp = paged_verify_commit(k_cols, v_cols, k_pool, v_pool,
-                                     tables, qstart, commit_len)
-        return out, commit_len, fin_ok, kp, vp
-    kq_cols, vq_cols, ks_cols, vs_cols = cols
-    k_scale, v_scale = kv_scales
-    kp, vp, ks, vs = paged_verify_commit_quant(
-        kq_cols, vq_cols, ks_cols, vs_cols, k_pool, v_pool,
-        k_scale, v_scale, tables, qstart, commit_len)
-    return out, commit_len, fin_ok, kp, vp, ks, vs
+    commit = (paged_verify_commit if len(pools) == 2
+              else paged_verify_commit_quant)
+    return (out, commit_len, fin_ok,
+            *commit(*cols, *pools, tables, qstart, commit_len))
 
 
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_verify(frozen):
+# ---------------------------------------------------------------------------
+# what InferenceEngine asks of a model: the paged programs, placement, draft
+# ---------------------------------------------------------------------------
+
+# KV/scale pools [L, NP, NKV*HD|NKV, bs] shard their kv-head-major axis
+# 2 across 'mp' — each rank runs the unchanged paged kernels (and their
+# shape-priced _fit_* fitters) on its head shard with the SAME
+# rank-replicated block tables, so BlockPool / PrefixCache / the commit
+# schedule stay host-side and rank-agnostic.
+_TP_POOL_SPEC = P(None, None, "mp", None)
+
+
+def _tp_specs(config: LlamaConfig, mesh: Mesh):
+    """(param pspec tree, ``tp`` tuple) for a serving island: weights
+    sliced per param_pspecs over 'mp' alone (no fsdp inside the serving
+    mesh). The trees only match PLAIN param arrays — ``LlamaServing.place``
+    rejects fused/int8 weight dicts under TP."""
+    n = int(mesh.shape["mp"])
+    return param_pspecs(config, ParallelConfig(mp=n)), ("mp", n)
+
+
+# kind: (the step ``fn(params, pools, *inputs, config, tp)``, the jitted
+# name's stem, how many inputs follow the cache, and under a mesh the
+# out-specs of what precedes the cache: decode logits leave the island
+# vocab-sharded (the engine's host argmax reads the exact concat), the
+# chunk's too; verify gathers its logits in the island (exact vocab concat),
+# so out / commit_len / fin_ok are rank-identical and leave replicated)
+_PAGED_STEPS = {
+    "decode": (llama_paged_decode_step, "paged_decode_step", 3,
+               (P(None, "mp"),)),
+    "prefill": (llama_paged_prefill_chunk, "paged_prefill_chunk", 4,
+                (P("mp"),)),
+    "prefill+decode": (llama_paged_prefill_chunk_with_decode,
+                       "paged_prefill_chunk_with_decode", 7,
+                       (P("mp"), P(None, "mp"))),
+    "verify": (llama_paged_verify_step, "paged_verify_step", 4,
+               (P(), P(), P())),
+}
+
+
+@functools.lru_cache(maxsize=128)
+def _jitted_paged_step(kind, frozen, quant, mesh):
+    """The jitted paged program of ``kind`` (a key of ``_PAGED_STEPS``),
+    ``fn(params, *pools, *inputs) -> (*outputs, *pools)`` with the pools
+    donated: two pools, or with ``quant`` the int8 four (``_int8`` in the
+    jitted name). Under a ``mesh`` (``_tp``) the step runs inside one
+    fully-manual shard_map island (the paged Pallas kernels cannot be
+    auto-partitioned under GSPMD), every pool sharded by ``_TP_POOL_SPEC``.
+    Call it with all four arguments by position: they are the cache's key."""
+    step, stem, n_inputs, head_specs = _PAGED_STEPS[kind]
     config = LlamaConfig(*frozen)
+    n_pools = 4 if quant else 2
+    pspecs, tp = (None, None) if mesh is None else _tp_specs(config, mesh)
 
-    def paged_verify_fn(params, kp, vp, tables, qstart, t_live, fed):
-        return llama_paged_verify_step(params, kp, vp, tables, qstart,
-                                       t_live, fed, config)
-    paged_verify_fn.__name__ = "paged_verify_step"
-    return jax.jit(paged_verify_fn, donate_argnums=(1, 2))
+    def run(params, *args):
+        return step(params, args[:n_pools], *args[n_pools:], config, tp)
 
+    if mesh is None:
+        fn = run
+    else:
+        pool_specs = (_TP_POOL_SPEC,) * n_pools
+        island = shard_map(
+            run, mesh=mesh,
+            in_specs=(pspecs, *pool_specs, *(P(),) * n_inputs),
+            out_specs=(*head_specs, *pool_specs), check_vma=False)
 
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_verify_quant(frozen):
-    config = LlamaConfig(*frozen)
-
-    def paged_verify_quant_fn(params, kp, vp, ks, vs, tables, qstart,
-                              t_live, fed):
-        return llama_paged_verify_step(params, kp, vp, tables, qstart,
-                                       t_live, fed, config,
-                                       kv_scales=(ks, vs))
-    paged_verify_quant_fn.__name__ = "paged_verify_step_int8"
-    return jax.jit(paged_verify_quant_fn, donate_argnums=(1, 2, 3, 4))
-
-
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_verify_tp(frozen, mesh):
-    """mp-sharded verify: logits all-gather in-island (exact vocab
-    concat) so out/commit_len/fin_ok are computed rank-identically and
-    each rank drives the commit kernel on its pool shard with the same
-    schedule — they leave the island replicated."""
-    config = LlamaConfig(*frozen)
-    pspecs, tp = _tp_specs(config, mesh)
-    rep = P()
-
-    def step(params, kp, vp, tables, qstart, t_live, fed):
-        return llama_paged_verify_step(params, kp, vp, tables, qstart,
-                                       t_live, fed, config, tp=tp)
-
-    body = shard_map(
-        step, mesh=mesh,
-        in_specs=(pspecs, _TP_POOL_SPEC, _TP_POOL_SPEC, rep, rep, rep,
-                  rep),
-        out_specs=(rep, rep, rep, _TP_POOL_SPEC, _TP_POOL_SPEC),
-        check_vma=False)
-
-    def paged_verify_tp_fn(params, kp, vp, tables, qstart, t_live, fed):
-        return body(params, kp, vp, tables, qstart, t_live, fed)
-    paged_verify_tp_fn.__name__ = "paged_verify_step_tp"
-    return jax.jit(paged_verify_tp_fn, donate_argnums=(1, 2))
+        def fn(params, *args):
+            return island(params, *args)
+    fn.__name__ = (stem + ("_int8" if quant else "")
+                   + ("_tp" if mesh is not None else ""))
+    return jax.jit(fn, donate_argnums=tuple(range(1, 1 + n_pools)))
 
 
-@functools.lru_cache(maxsize=32)
-def _jitted_paged_verify_quant_tp(frozen, mesh):
-    config = LlamaConfig(*frozen)
-    pspecs, tp = _tp_specs(config, mesh)
-    rep = P()
+# chipbench/families/llama.py ``aot_programs`` reads these two names; they go
+# once a benchmark PR points it at _jitted_paged_step (ROADMAP D1)
+_jitted_paged_decode = lambda frozen: _jitted_paged_step(  # noqa: E731
+    "decode", frozen, False, None)
+_jitted_paged_prefill = lambda frozen: _jitted_paged_step(  # noqa: E731
+    "prefill", frozen, False, None)
 
-    def step(params, kp, vp, ks, vs, tables, qstart, t_live, fed):
-        return llama_paged_verify_step(params, kp, vp, tables, qstart,
-                                       t_live, fed, config,
-                                       kv_scales=(ks, vs), tp=tp)
 
-    body = shard_map(
-        step, mesh=mesh,
-        in_specs=(pspecs, _TP_POOL_SPEC, _TP_POOL_SPEC, _TP_POOL_SPEC,
-                  _TP_POOL_SPEC, rep, rep, rep, rep),
-        out_specs=(rep, rep, rep, _TP_POOL_SPEC, _TP_POOL_SPEC,
-                   _TP_POOL_SPEC, _TP_POOL_SPEC),
-        check_vma=False)
+class LlamaServing:
+    """What ``InferenceEngine`` asks of a model, chosen by the config's type
+    (``engine._serving_for``): the frozen config its jitted programs are keyed
+    by, the cache arrays (a tuple, each indexed by block id on axis 1), and
+    the jitted programs ``fn(params, *cache, ...) -> (..., *cache[, counts])``
+    by ``kind``: ``prefill`` (one chunk of one prompt), ``decode`` (one token
+    a running row), ``verify`` (speculation) and, where the model offers it,
+    ``prefill+decode`` (a chunk with the decode batch riding it:
+    ``fn(params, *cache, <the chunk's inputs>, <the batch's>) -> (chunk
+    logits, row logits, *cache)``). ``step_fn`` returns None for a kind it
+    does not offer, and the engine then runs the iteration with the
+    programs it has. ``work`` names the registry counters a model adds to
+    the engine's ``_WORK_TOTALS`` (none here). A model that does not
+    ``refuse`` them also answers ``place`` (``mp > 1``) and ``draft``
+    (speculation). This one is Llama's: every program is
+    ``_jitted_paged_step``'s; an int8 cache is (k, v, k_scale, v_scale)."""
 
-    def paged_verify_quant_tp_fn(params, kp, vp, ks, vs, tables, qstart,
-                                 t_live, fed):
-        return body(params, kp, vp, ks, vs, tables, qstart, t_live, fed)
-    paged_verify_quant_tp_fn.__name__ = "paged_verify_step_int8_tp"
-    return jax.jit(paged_verify_quant_tp_fn, donate_argnums=(1, 2, 3, 4))
+    work: Dict[str, str] = {}
+
+    @staticmethod
+    def refuse(**features) -> None:
+        """Raise for a serving feature this model cannot run: none."""
+
+    @staticmethod
+    def freeze(config: LlamaConfig):
+        return _freeze_config(config)
+
+    @staticmethod
+    def init_cache(config, num_blocks: int, block_size: int,
+                   kv_dtype: str) -> Tuple:
+        kv = init_paged_kv_pool(config, num_blocks, block_size,
+                                kv_dtype=kv_dtype)
+        if kv_dtype == "int8":
+            kv += init_paged_kv_scales(config, num_blocks, block_size)
+        return kv
+
+    @staticmethod
+    def step_fn(kind: str, frozen, quant: bool, mesh):
+        # ROADMAP S10: the builder makes the chunk that carries the batch for
+        # every cache and mesh, but only the plain cache on one chip has a
+        # cell that prices it; this line goes in the PR that brings the others'
+        if kind == "prefill+decode" and (quant or mesh is not None):
+            return None
+        return _jitted_paged_step(kind, frozen, quant, mesh)
+
+    # the default draft of speculation, (draft_params, draft_config): its
+    # frozen config and its model-dtype cache come from the two above
+    draft = staticmethod(make_draft_model)
+
+    @staticmethod
+    def place(mp: int, params, config: LlamaConfig, kv: Tuple,
+              draft_params=None, draft_config=None, kv_draft: Tuple = ()):
+        """Build the ('mp',) serving mesh and place weights and pools on it.
+
+        Weight slicing follows ``param_pspecs`` over 'mp' alone
+        (column-parallel q/k/v/gate/up, row-parallel o/down, vocab-
+        parallel embed + lm_head); every KV/scale pool — fp16, int8 and
+        draft — shards its kv-head-major axis 2. Rejects geometries the
+        contiguous-head slicing cannot express (see PARITY.md PR 19).
+        Returns (mesh, params, kv, draft_params, kv_draft)."""
+        c = config
+        for dim, name in ((c.num_attention_heads, "num_attention_heads"),
+                          (c.num_key_value_heads, "num_key_value_heads"),
+                          (c.vocab_size, "vocab_size"),
+                          (c.intermediate_size, "intermediate_size")):
+            if dim % mp:
+                raise ValueError(
+                    f"ServeConfig.mp={mp} needs {name} % mp == 0 "
+                    f"(got {dim}): heads/vocab/ffn slice contiguously "
+                    f"across ranks")
+        ndev = len(jax.devices())
+        if ndev < mp:
+            raise ValueError(f"ServeConfig.mp={mp} needs {mp} devices, "
+                             f"have {ndev}")
+        if "qkv_proj" in params.get("layers", {}):
+            raise ValueError(
+                "tensor-parallel serving needs split q/k/v projections; "
+                "fused qkv_proj weights interleave heads and cannot "
+                "slice contiguously over 'mp'")
+        for tree in (params, draft_params or {}):
+            for leaf in jax.tree_util.tree_leaves(
+                    tree, is_leaf=lambda x: isinstance(x, dict) and
+                    ("w" in x or "wT" in x)):
+                if isinstance(leaf, dict):
+                    raise ValueError(
+                        "tensor-parallel serving takes plain weight "
+                        "arrays; int8/transposed weight dicts don't "
+                        "carry the param_pspecs tree")
+        mesh = make_mesh(ParallelConfig(mp=mp))
+
+        def put(tree, cfg):
+            shardings = jax.tree_util.tree_map(
+                lambda s: NamedSharding(mesh, s), _tp_specs(cfg, mesh)[0],
+                is_leaf=lambda x: isinstance(x, P))
+            return jax.device_put(tree, shardings)
+
+        pool_sh = NamedSharding(mesh, _TP_POOL_SPEC)
+        return (mesh, put(params, c),
+                tuple(jax.device_put(a, pool_sh) for a in kv),
+                draft_params and put(draft_params, draft_config),
+                tuple(jax.device_put(a, pool_sh) for a in kv_draft))
 
 
 def generate_scan(params, cache, first_token, num_tokens,

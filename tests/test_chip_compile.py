@@ -185,17 +185,9 @@ def test_engine_step_compiles_with_a_pool_half_of_hbm(serve_args, sds, kind,
             sds((BATCH,), i32))
     chunk = (sds((MAX_NB,), i32), sds((), i32),
              sds((FULL.prefill_chunk,), i32), sds((), i32))
-    if kind == "decode":
-        fn = (L._jitted_paged_decode_quant if quant
-              else L._jitted_paged_decode)(frozen)
-        args = rows
-    elif kind == "prefill":
-        fn = (L._jitted_paged_prefill_quant if quant
-              else L._jitted_paged_prefill)(frozen)
-        args = chunk
-    else:
-        fn = L._jitted_paged_prefill_with_decode(frozen)
-        args = chunk + rows
+    args = {"decode": rows, "prefill": chunk,
+            "prefill+decode": chunk + rows}[kind]
+    fn = L._jitted_paged_step(kind, frozen, quant, None)
     compiled = compile_for_chip(fn, params, *pools[quant], *args)
     assert "tpu_custom_call" in compiled.as_text()
     pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize
@@ -231,8 +223,8 @@ def test_tp_prefill_compiles_on_four_chips(topo, quant):
     pool = on((2, POOL_BLOCKS, 8 * 128, BS), i8 if quant else bf16,
               L._TP_POOL_SPEC)
     scale = on((2, POOL_BLOCKS, 8, BS), f32, L._TP_POOL_SPEC)
-    fn = (L._jitted_paged_prefill_quant_tp if quant
-          else L._jitted_paged_prefill_tp)(L._freeze_config(config), mesh)
+    fn = L._jitted_paged_step("prefill", L._freeze_config(config), quant,
+                              mesh)
     compiled = compile_for_chip(
         fn, params, *((pool, pool, scale, scale) if quant else (pool, pool)),
         on((MAX_NB,), i32), on((), i32), on((FULL.prefill_chunk,), i32),

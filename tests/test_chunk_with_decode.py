@@ -84,10 +84,13 @@ def test_program_equals_chunk_then_decode(model, start, n_live, batch):
     rows_in = (jnp.asarray(tables), jnp.asarray(positions),
                jnp.asarray(ids_r))
     frozen = L._freeze_config(cfg)
-    want_c, kp, vp = L._jitted_paged_prefill(frozen)(
-        params, *_pools(cfg), *chunk_in)
-    want_r, kp, vp = L._jitted_paged_decode(frozen)(params, kp, vp, *rows_in)
-    got_c, got_r, got_k, got_v = L._jitted_paged_prefill_with_decode(frozen)(
+
+    def program(kind):
+        return L._jitted_paged_step(kind, frozen, False, None)
+
+    want_c, kp, vp = program("prefill")(params, *_pools(cfg), *chunk_in)
+    want_r, kp, vp = program("decode")(params, kp, vp, *rows_in)
+    got_c, got_r, got_k, got_v = program("prefill+decode")(
         params, *_pools(cfg), *chunk_in, *rows_in)
     assert got_c.shape == (cfg.vocab_size,) and got_c.dtype == jnp.float32
     assert got_r.shape == (R, cfg.vocab_size) and got_r.dtype == jnp.float32
@@ -102,14 +105,14 @@ def test_program_equals_chunk_then_decode(model, start, n_live, batch):
 
 # -- the engine: one launch for an iteration that has both --------------------
 
-class _TwoPrograms(engine_mod._LlamaServing):
+class _TwoPrograms(L.LlamaServing):
     """Llama's serving object, offering no chunk that carries the batch."""
 
-    @classmethod
-    def step_fn(cls, kind, frozen, quant, mesh):
+    @staticmethod
+    def step_fn(kind, frozen, quant, mesh):
         if kind == "prefill+decode":
             return None
-        return super().step_fn(kind, frozen, quant, mesh)
+        return L.LlamaServing.step_fn(kind, frozen, quant, mesh)
 
 
 def _engine(model, two_programs=False, **kw):
@@ -357,7 +360,7 @@ def test_rows_of_a_program_that_failed_go_through_the_decode_program(
 ])
 def test_paths_left_alone_keep_two_programs(model, kw):
     """An int8 cache, speculation and tensor parallelism are offered no
-    such program, by what ``_LlamaServing`` has builders for: no knob."""
+    such program, by one line of ``LlamaServing.step_fn``: no knob."""
     cfg, params = model
     if kw.get("mp", 1) > len(jax.devices()):
         pytest.skip("needs two devices")
@@ -369,3 +372,33 @@ def test_paths_left_alone_keep_two_programs(model, kw):
     assert eng.work_totals["prefill_chunks_total"] == 1 + 3 + 2 + 1
     assert all(k[0] != "prefill+decode" for k in eng._compiled)
 
+
+
+# -- the seam: one builder, and what the serving object offers of it ----------
+
+STEMS = {"decode": "paged_decode_step", "prefill": "paged_prefill_chunk",
+         "prefill+decode": "paged_prefill_chunk_with_decode",
+         "verify": "paged_verify_step"}
+ALIASES = {"decode": "_jitted_paged_decode", "prefill": "_jitted_paged_prefill"}
+
+
+@pytest.mark.parametrize("mp", [1, 2])
+@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("kind", list(STEMS))
+def test_every_program_is_the_one_builders(model, kind, quant, mp):
+    """``LlamaServing.step_fn`` hands out the builder's cached program under
+    the jitted name the benchmark's per-layer metrics match, and None for the
+    three chunks that carry the batch and have no cell yet (ROADMAP S10)."""
+    frozen = L._freeze_config(model[0])
+    mesh = None if mp == 1 else L.make_mesh(L.ParallelConfig(mp=mp))
+    built = L._jitted_paged_step(kind, frozen, quant, mesh)
+    assert built.__name__ == (STEMS[kind] + "_int8" * quant
+                              + "_tp" * (mesh is not None))
+    offered = L.LlamaServing.step_fn(kind, frozen, quant, mesh)
+    if kind == "prefill+decode" and (quant or mesh is not None):
+        assert offered is None
+    else:
+        assert offered is built
+    if kind in ALIASES and not quant and mesh is None:
+        # chipbench/families/llama.py aot_programs reads these two names
+        assert getattr(L, ALIASES[kind])(frozen) is built

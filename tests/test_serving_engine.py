@@ -375,3 +375,36 @@ def test_exception_dumps_flight_recorder(model, tmp_path, monkeypatch):
     assert payload["source"] == "engine"
     assert payload["n_records"] > 0
     assert payload["records"][-1]["iteration"] == 6
+
+
+def test_engine_module_imports_no_model():
+    """What a model hands the engine lives beside the model and is fetched
+    inside ``_serving_for``: importing ``inference/engine.py`` runs no import
+    from ``paddle_tpu.models``."""
+    import ast
+
+    from paddle_tpu.inference import engine
+
+    def at_import(body):
+        for node in body:
+            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+                continue
+            yield node
+            for field in ("body", "orelse", "finalbody", "handlers"):
+                yield from at_import(getattr(node, field, []))
+
+    with open(engine.__file__) as f:
+        tree = ast.parse(f.read())
+    found = []
+    for node in at_import(tree.body):
+        if isinstance(node, ast.ImportFrom):
+            base = "." * node.level + (node.module or "")
+            names = [base + "." * bool(node.module) + a.name
+                     for a in node.names]
+        elif isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        else:
+            continue
+        found += [(node.lineno, n) for n in names
+                  if n.startswith(("..models", "paddle_tpu.models"))]
+    assert not found, f"module-level imports of a model: {found}"
