@@ -1285,9 +1285,13 @@ class InferenceEngine:
                 if rows:
                     row_logits = np.asarray(row_logits)  # noqa: PTA006 -- step boundary: sampled tokens must reach the scheduler
                 if counts:
-                    # a model's own work counts ride the sync just paid
+                    # a model's own work counts ride the sync just paid:
+                    # the chunk's context and, where they rode, the rows'
+                    ctx = [[seq.n_cached + int(n_live)]]
+                    if rows:
+                        ctx.append([s.n_cached + 1 for s in rows])
                     self._note_work(sp, **self.model.counted(
-                        "prefill", counts, [seq.n_cached + int(n_live)]))
+                        key[0], counts, *ctx))
         except Exception as e:  # noqa: BLE001 -- quarantine boundary
             failure, row_logits = e, None
         with self._span("serve.prefill.commit"):

@@ -259,6 +259,14 @@ def latent_out(p, o_lat, c: DeepSeekConfig):
                    preferred_element_type=jnp.float32)
 
 
+def _queries_and_latent(p, x, cos, sin, pool, c: DeepSeekConfig):
+    """What a paged step's attention starts from: (the absorbed queries
+    [T, NH, W], the rows' latent columns [T, W] in the pool's dtype)."""
+    with jax.named_scope("mla.project"):
+        q_nope, q_pe, lat = mla_project(p, x, cos, sin, c)
+        return absorbed_queries(p, q_nope, q_pe, c), lat.astype(pool.dtype)
+
+
 def mla_expanded(p, x, cos, sin, c: DeepSeekConfig):
     """The EXPANDED form over one whole sequence x [T, H] (normed), causal,
     in plain jnp: what the absorbed kernels must equal. Not on the serving
@@ -309,7 +317,12 @@ def route(y, router, bias, c: DeepSeekConfig):
     sel = s + bias.astype(jnp.float32)
     t, e = sel.shape
     g = sel.reshape(t, c.n_group, e // c.n_group)
-    group_score = lax.top_k(g, 2)[0].sum(-1)                   # [T, G]
+    # the two largest of a group as two maxima (``lax.top_k(g, 2)`` sorts
+    # every group, with the groups in lanes at a row count off the lane
+    # tile: 0.18 ms a layer at 576 rows); the same two numbers, ties too
+    first = jnp.argmax(g, axis=-1, keepdims=True)
+    second = jnp.where(jnp.arange(g.shape[-1]) == first, -jnp.inf, g)
+    group_score = g.max(-1) + second.max(-1)                   # [T, G]
     _, best = lax.top_k(group_score, c.topk_group)
     keep = jnp.zeros((t, c.n_group), bool).at[
         jnp.arange(t)[:, None], best].set(True)
@@ -436,19 +449,17 @@ def deepseek_paged_decode_step(params, pool, tables, positions, ids,
     [B, max_nb], positions [B] = the slot each row's new token takes.
     Padding rows point their tables at the null block 0 (position 0) and
     route to no expert. Returns (logits [B, vocab] f32, pool, counts)."""
-    from ..ops.paged_attention import mla_paged_decode
+    from ..ops.paged_attention import mla_paged_decode, paged_update_walk
     h = jnp.take(params["embed"], ids, axis=0).astype(jnp.float32)
     cos, sin = yarn_cos_sin(c, positions)
     live = tables[:, 0] > 0
+    walk = paged_update_walk(tables, positions, pool.shape[-1])
 
     def attend(p, x, pool, layer):
-        with jax.named_scope("mla.project"):
-            q_nope, q_pe, lat = mla_project(p, x, cos, sin, c)
-            q = absorbed_queries(p, q_nope, q_pe, c)
+        q, lat = _queries_and_latent(p, x, cos, sin, pool, c)
         with jax.named_scope("mla.attend"):
-            o_lat, pool = mla_paged_decode(
-                q, lat.astype(pool.dtype), pool, tables, positions, layer,
-                rank=c.kv_lora_rank)
+            o_lat, pool = mla_paged_decode(q, lat, pool, walk, layer,
+                                           rank=c.kv_lora_rank)
         with jax.named_scope("mla.project"):
             return latent_out(p, o_lat, c), pool
 
@@ -472,12 +483,10 @@ def deepseek_paged_prefill_chunk(params, pool, table_row, start, ids, n_live,
                                         pool.shape[-1])
 
     def attend(p, x, pool, layer):
-        with jax.named_scope("mla.project"):
-            q_nope, q_pe, lat = mla_project(p, x, cos, sin, c)
-            q = absorbed_queries(p, q_nope, q_pe, c)
+        q, lat = _queries_and_latent(p, x, cos, sin, pool, c)
         with jax.named_scope("mla.attend"):
             pool = _pool_write_chunk(_pin_pool_layout(pool), layer, wbid,
-                                     fresh, window(lat.astype(pool.dtype)))
+                                     fresh, window(lat))
             o_lat = mla_paged_prefill(q, pool, table_row, start, n_live,
                                       layer, rank=c.kv_lora_rank)
         with jax.named_scope("mla.project"):
@@ -488,30 +497,101 @@ def deepseek_paged_prefill_chunk(params, pool, table_row, start, ids, n_live,
     return _logits(params, h_last, c)[0], pool, counts
 
 
+def deepseek_paged_prefill_chunk_with_decode(params, pool, table_row, start,
+                                             ids, n_live, tables, positions,
+                                             row_ids, c: DeepSeekConfig):
+    """A prefill chunk with the decode batch riding it, for an iteration
+    that has both: ONE pass of the layers over the chunk's C rows
+    (``table_row``, ``start``, ``ids``, ``n_live`` as
+    ``deepseek_paged_prefill_chunk`` takes them) and the batch's B rows
+    (``tables``, ``positions``, ``row_ids`` as ``deepseek_paged_decode_step``
+    takes them; padding rows at the null block 0, position 0), so the
+    weights stream once for both: the attention's, the dense FFN's, the
+    shared expert's, the head's, and each expert's that either part hits
+    (the rows' pairs sit in the row tiles the chunk opens anyway). Between
+    ``mla_project`` and ``latent_out`` the rows part, each to the absorbed
+    queries and the attention of its own step, roped by its own positions.
+    The parts touch disjoint blocks: a sequence is in prefill or running,
+    never both.
+    The batch's update goes first, so the chunk's attention is the pool's
+    last reader in a layer and nothing copies it.
+
+    Returns (the chunk's last-live-token logits [vocab] f32, the batch's
+    logits [B, vocab] f32, pool, counts: once, for both parts)."""
+    from ..ops.paged_attention import (mla_paged_decode, mla_paged_prefill,
+                                       paged_update_walk)
+    C = ids.shape[0]
+    h = jnp.take(params["embed"], jnp.concatenate([ids, row_ids]),
+                 axis=0).astype(jnp.float32)
+    cos, sin = yarn_cos_sin(c, jnp.concatenate(
+        [start + jnp.arange(C, dtype=jnp.int32), positions]))
+    live = jnp.concatenate([jnp.arange(C) < n_live, tables[:, 0] > 0])
+    wbid, fresh, window = _chunk_window(table_row, start, n_live, C,
+                                        pool.shape[-1])
+    walk = paged_update_walk(tables, positions, pool.shape[-1])
+
+    def attend(p, x, pool, layer):
+        # projected on all rows together; absorbed a part at a time, so each
+        # kernel's queries are written where it reads them (a slice of one
+        # [C + B, NH, W] array would be a copy of it in every layer)
+        with jax.named_scope("mla.project"):
+            q_nope, q_pe, lat = mla_project(p, x, cos, sin, c)
+            q_chunk = absorbed_queries(p, q_nope[:C], q_pe[:C], c)
+            q_rows = absorbed_queries(p, q_nope[C:], q_pe[C:], c)
+            lat = lat.astype(pool.dtype)
+        with jax.named_scope("mla.attend"):
+            o_rows, pool = mla_paged_decode(q_rows, lat[C:], pool, walk,
+                                            layer, rank=c.kv_lora_rank)
+            pool = _pool_write_chunk(_pin_pool_layout(pool), layer, wbid,
+                                     fresh, window(lat[:C]))
+            o_chunk = mla_paged_prefill(q_chunk, pool, table_row, start,
+                                        n_live, layer, rank=c.kv_lora_rank)
+        with jax.named_scope("mla.project"):
+            o_lat = jnp.concatenate([o_chunk, o_rows.astype(o_chunk.dtype)])
+            return latent_out(p, o_lat, c), pool
+
+    h, pool, counts = _layers(params, c, attend, h, pool, live)
+    heads = jnp.concatenate(
+        [lax.dynamic_slice_in_dim(h, n_live - 1, 1, 0), h[C:]])
+    logits = _logits(params, heads, c)
+    return logits[0], logits[1:], pool, counts
+
+
 # -- what InferenceEngine asks of a model ----------------------------------------
 
-@functools.lru_cache(maxsize=8)
-def _jitted_paged_decode(c: DeepSeekConfig):
-    def paged_decode_fn(params, pool, tables, positions, ids):
-        return deepseek_paged_decode_step(params, pool, tables, positions,
-                                          ids, c)
-    paged_decode_fn.__name__ = "paged_decode_step_mla"
-    return jax.jit(paged_decode_fn, donate_argnums=(1,))
+# kind -> (the step, the jitted program's name: what the benchmark's metrics
+# match in a device trace; the chunk that carries the batch goes by the
+# chunk's name first, so what reads ``paged_prefill_chunk_mla`` reads both)
+_PAGED_STEPS = {
+    "prefill": (deepseek_paged_prefill_chunk, "paged_prefill_chunk_mla"),
+    "decode": (deepseek_paged_decode_step, "paged_decode_step_mla"),
+    "prefill+decode": (deepseek_paged_prefill_chunk_with_decode,
+                       "paged_prefill_chunk_mla_with_decode"),
+}
 
 
-@functools.lru_cache(maxsize=8)
-def _jitted_paged_prefill(c: DeepSeekConfig):
-    def paged_prefill_fn(params, pool, table_row, start, ids, n_live):
-        return deepseek_paged_prefill_chunk(params, pool, table_row, start,
-                                            ids, n_live, c)
-    paged_prefill_fn.__name__ = "paged_prefill_chunk_mla"
-    return jax.jit(paged_prefill_fn, donate_argnums=(1,))
+@functools.lru_cache(maxsize=24)
+def _jitted_paged_step(kind: str, c: DeepSeekConfig):
+    """The jitted program of ``kind`` for the frozen config:
+    ``fn(params, pool, *inputs)`` with the pool donated."""
+    step, name = _PAGED_STEPS[kind]
+
+    def fn(params, pool, *inputs):
+        return step(params, pool, *inputs, c)
+    fn.__name__ = name
+    return jax.jit(fn, donate_argnums=(1,))
+
+
+# ``chipbench/families/deepseek.py`` ``aot_programs`` asks for these by name
+_jitted_paged_decode = functools.partial(_jitted_paged_step, "decode")
+_jitted_paged_prefill = functools.partial(_jitted_paged_step, "prefill")
 
 
 class DeepSeekServing:
     """What ``InferenceEngine`` asks of a model (``llama.LlamaServing`` is
-    Llama's): the frozen config, the cache (ONE latent pool), the jitted
-    programs, which return ``counts`` after the cache, and the registry
+    Llama's): the frozen config, the cache (ONE latent pool), the three
+    jitted programs (a chunk, a decode step, a chunk that carries the decode
+    batch), which return ``counts`` after the cache, and the registry
     counters those feed."""
 
     # span argument -> registry counter (paddle_tpu_serve_<name>)
@@ -546,21 +626,28 @@ class DeepSeekServing:
 
     @staticmethod
     def step_fn(kind, frozen, quant, mesh):
-        """The jitted program of ``kind``; None for a kind not offered
-        (``prefill+decode``: a chunk and the decode batch stay two
-        programs here)."""
-        build = {"prefill": _jitted_paged_prefill,
-                 "decode": _jitted_paged_decode}.get(kind)
-        return build and build(frozen)
+        """The jitted program of ``kind`` (``prefill``, ``decode``,
+        ``prefill+decode``: a chunk with the decode batch riding it); None
+        for a kind not offered (``verify``)."""
+        return _jitted_paged_step(kind, frozen) if kind in _PAGED_STEPS \
+            else None
 
     @staticmethod
-    def counted(kind, counts, ctx):
+    def counted(kind, counts, *ctx):
         """The span arguments of one step: ``counts`` as the jitted step
         returned them ([n_moe, 4] i32: (live token, expert) pairs, those
         routed to experts held here, held experts hit, the busiest's rows;
-        summed here over the expert layers) and ``ctx``, the latent
-        columns each sequence's attention had to read, per layer."""
+        summed here over the expert layers) and, for each part of ``kind``
+        in its order (``prefill+decode``: the chunk's, then the rows'),
+        ``ctx``: the latent columns each sequence's attention had to read,
+        per layer. A program of both parts counts its pairs as the two
+        programs together would; ``experts_hit`` and ``busiest_rows`` are of
+        the union of its rows (an expert both parts hit streams, and counts,
+        once)."""
         c = np.asarray(counts[0])  # noqa: PTA006 -- read inside the wait the step's logits already pay
         pairs, local, hit, busiest = (int(x) for x in c.sum(axis=0))
-        return {"pairs": pairs, "local_pairs": local, "experts_hit": hit,
-                "busiest_rows": busiest, f"mla_{kind}_ctx": int(sum(ctx))}
+        out = {"pairs": pairs, "local_pairs": local, "experts_hit": hit,
+               "busiest_rows": busiest}
+        for part, cols in zip(kind.split("+"), ctx, strict=True):
+            out[f"mla_{part}_ctx"] = int(sum(cols))
+        return out

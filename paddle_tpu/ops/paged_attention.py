@@ -1644,25 +1644,23 @@ def _mla_decode_kernel(lp_ref, sc_ref, q_ref, new_ref, c_ref, o_ref, co_ref,
         chain(c_ref[0, 0])
 
 
-def mla_paged_decode(q, new_col, pool, tables, positions, layer, *, rank):
+def mla_paged_decode(q, new_col, pool, walk, layer, *, rank):
     """Fused pool-update + absorbed latent attention for one decode layer.
 
     q [B, NH, W] PRE-SCALED by scale*log2(e) (W = rank + rope dims: the
     absorbed nope query, then the roped query); new_col [B, W] the new
     token's latent column (normed compressed KV, then the roped shared key);
-    pool [L, NP, W, bs]; tables [B, max_nb] i32; positions [B] i32 = the NEW
-    token's position per row (its block must already be in the table; padding
-    rows point at the null block 0 with position 0). Every row writes its
-    column IN PLACE (the pool aliases through the call) and attends over its
-    prefix including it. Returns (o_lat [B, NH, rank] f32, pool)."""
+    pool [L, NP, W, bs]; ``walk`` the batch's ``paged_update_walk``, the same
+    in every layer and made once before a step's layers (padding rows point
+    at the null block 0 with position 0). Every row writes its column IN
+    PLACE (the pool aliases through the call) and attends over its prefix
+    including it; the grid is the walk's live steps alone. Returns (o_lat
+    [B, NH, rank] f32, pool)."""
     b, nh, w = q.shape
-    L, NP, _, bs = pool.shape
-    B, max_nb = tables.shape
-    n_steps = B * max_nb
+    bs = pool.shape[-1]
     it = jnp.dtype(pool.dtype).itemsize
-    sched = paged_schedule(positions + 1, tables, n_steps, bs)
-    # the grid: the live steps alone (int32: the package turns x64 on)
-    total = jnp.sum(sched[_LIVE], dtype=jnp.int32)
+    sched, total = walk
+    n_steps = sched.shape[1]        # the cost estimate's worst case
     lp = jnp.asarray([layer], jnp.int32)
     wp = -(-w // 128) * 128
     new = jnp.pad(new_col, ((0, 0), (0, wp - w)))[:, None]

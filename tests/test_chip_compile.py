@@ -323,7 +323,8 @@ def test_mla_decode_kernel_compiles(sds, batch):
     aliased through the call."""
     out = compile_for_chip(
         jax.jit(lambda q, new, pool, tables, pos: pa.mla_paged_decode(
-            q, new, pool, tables, pos, 2, rank=MLA_RANK), donate_argnums=(2,)),
+            q, new, pool, pa.paged_update_walk(tables, pos, BS), 2,
+            rank=MLA_RANK), donate_argnums=(2,)),
         sds((batch, MLA_NH, MLA_W), bf16), sds((batch, MLA_W), bf16),
         sds(MLA_POOL, bf16), sds((batch, MLA_MAX_NB), i32),
         sds((batch,), i32))
@@ -376,3 +377,37 @@ def test_deepseek_steps_compile_and_fit_one_chip(one_chip):
         names.append(name)
     assert names == ["decode, batch 1", "decode, batch 64",
                      "prefill chunk 512"]
+
+
+def test_deepseek_chunk_with_decode_compiles_and_fits_one_chip(sds):
+    """The chunk that carries the decode batch (PR 34) at the cell's size:
+    512 chunk rows and 64 row slots through one pass of the layers. Built
+    here from the cell's files (``aot_programs`` is the benchmark's and
+    lists the two programs it had). A layer holds two latent kernels (the
+    rows' fused update, the chunk's attention), two norm kernels and, in an
+    expert layer, three grouped matmuls: 4 in the dense layer, 7 in the
+    scanned one. The pool (1.69 GiB) stays in place: a copy of it would
+    show among the temporaries."""
+    from chipbench import spec
+    from chipbench.weights import is_leaf
+    from paddle_tpu.models import deepseek as D
+    cell = spec.load_cell("dsv3.serve.docqa")
+    fam, m, e = cell.family, cell.model, cell.traffic["engine"]
+    config = fam.deepseek_config(m)
+    params = jax.tree_util.tree_map(lambda leaf: sds(leaf.shape, bf16),
+                                    fam.leaves(m), is_leaf=is_leaf)
+    pool = sds((config.num_hidden_layers, e["num_blocks"],
+                config.latent_width, e["block_size"]), bf16)
+    max_nb = -(-e["max_seq_len"] // e["block_size"])
+    c, b = e["prefill_chunk"], e["max_batch"]
+    fn = D.DeepSeekServing.step_fn("prefill+decode", config, False, None)
+    out = compile_for_chip(
+        fn, params, pool, sds((max_nb,), i32), sds((), i32), sds((c,), i32),
+        sds((), i32), sds((b, max_nb), i32), sds((b,), i32), sds((b,), i32))
+    ma = out.memory_analysis()
+    held = ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes
+    assert 11.8 < gib(ma.argument_size_in_bytes) < 12.1
+    assert gib(held) < 15.0, gib(held)
+    assert gib(ma.temp_size_in_bytes) < 1.0, gib(ma.temp_size_in_bytes)
+    assert out.as_text().count("tpu_custom_call") == 4 + 7

@@ -3,8 +3,10 @@ weights (Pallas in the interpreter): the absorbed latent attention equals
 the expanded form and the float32 reference; the router follows a plain loop
 written from the equations; the parts that all shares of an expert layer
 give add up to the uncut reference; chunked prefill and then decode through
-``InferenceEngine`` agree with the reference's full forward on logits; what
-is out of scope raises at construction.
+``InferenceEngine`` agree with the reference's full forward on logits; the
+chunk that carries the decode batch is the chunk followed by the decode step,
+and the engine's streams are those of the two-program path; what is out of
+scope raises at construction.
 
 Tolerances, and why. The program's residual stream, weights and latent
 cache are bfloat16 (8 bits of mantissa: 0.4 % a rounding), the reference
@@ -90,7 +92,8 @@ def test_expanded_equals_absorbed_equals_reference():
     (float32, expanded, in blocks), the program's expanded form in plain
     jnp, and the program's absorbed form through both paged kernels."""
     from paddle_tpu.ops.paged_attention import (mla_paged_decode,
-                                                mla_paged_prefill)
+                                                mla_paged_prefill,
+                                                paged_update_walk)
     m, c, params = tiny()
     c32 = dataclasses.replace(c, dtype=jnp.float32)
     p = f32(layer0(params))
@@ -116,9 +119,10 @@ def test_expanded_equals_absorbed_equals_reference():
                           0, rank=c.kv_lora_rank)
     outs = [D.latent_out(p, o, c32)]
     for t in range(32, s):
-        o, pool = mla_paged_decode(q[t:t + 1], lat[t:t + 1], pool,
-                                   table[None], jnp.asarray([t], jnp.int32),
-                                   0, rank=c.kv_lora_rank)
+        walk = paged_update_walk(table[None], jnp.asarray([t], jnp.int32),
+                                 bs)
+        o, pool = mla_paged_decode(q[t:t + 1], lat[t:t + 1], pool, walk, 0,
+                                   rank=c.kv_lora_rank)
         outs.append(D.latent_out(p, o, c32))
     absorbed = jnp.concatenate(outs)
     np.testing.assert_allclose(np.asarray(absorbed), np.asarray(ref),
@@ -268,13 +272,152 @@ def logit_gaps(m, params, prompt, out, mode="f32"):
     return ref, (ref.max(-1) - ref[rows, np.asarray(out)]) / ref.std(-1)
 
 
-def test_serving_object_offers_the_two_programs_and_no_other():
+def test_serving_object_offers_the_three_programs_and_no_other():
     """``step_fn`` answers None for a kind it has no builder for; the engine
-    chooses its iteration by that answer."""
+    chooses its iteration by that answer. The chunk that carries the batch
+    goes by the chunk's name first, which is what the benchmark's metrics
+    match in a device trace."""
     _, c, _ = tiny()
-    assert D.DeepSeekServing.step_fn("prefill+decode", c, False, None) is None
-    for kind in ("prefill", "decode"):
+    assert D.DeepSeekServing.step_fn("verify", c, False, None) is None
+    for kind in ("prefill", "decode", "prefill+decode"):
         assert callable(D.DeepSeekServing.step_fn(kind, c, False, None))
+    names = {kind: D.DeepSeekServing.step_fn(kind, c, False, None).__name__
+             for kind in ("prefill", "decode", "prefill+decode")}
+    assert names == {"prefill": "paged_prefill_chunk_mla",
+                     "decode": "paged_decode_step_mla",
+                     "prefill+decode": "paged_prefill_chunk_mla_with_decode"}
+
+
+# -- the chunk that carries the decode batch against the two it replaces -------
+
+P_BS, P_NB, P_MAX_NB, P_C, P_R = 16, 24, 6, 16, 4
+
+# rows as (blocks, position): a row alone; a full batch with one row whose new
+# token opens its second block (position == block size); a batch half padding
+BATCHES = {
+    "one_row": [([1, 2, 3], 35)],
+    "full_with_boundary": [([1, 2, 3], 35), ([4, 5], 16), ([6], 3),
+                           ([11, 12, 13], 47)],
+    "half_padding": [([4, 5], 16), ([6], 0)],
+}
+
+
+@pytest.mark.parametrize("batch", list(BATCHES))
+@pytest.mark.parametrize("start, n_live", [(0, 16), (8, 11), (13, 1)])
+def test_program_equals_chunk_then_decode(start, n_live, batch):
+    """Chunk logits, row logits, the pool and the counts of the one program
+    against ``deepseek_paged_prefill_chunk`` followed by
+    ``deepseek_paged_decode_step`` on the same inputs, over a pool with
+    something in every block. Every block but the null one (which padding
+    rows and dead chunk slots scribble on in either order) comes out the
+    same; the logits to float32 rounding of matmuls whose row count changed
+    (C + B rows where they were C, then B). Pairs add up; the experts hit
+    and the busiest's rows are of the union of the rows."""
+    _, c, params = tiny()
+    rows = BATCHES[batch]
+    tables = np.zeros((P_R, P_MAX_NB), np.int32)
+    positions = np.zeros((P_R,), np.int32)
+    ids_r = np.zeros((P_R,), np.int32)
+    for i, (blocks, pos) in enumerate(rows):
+        tables[i, :len(blocks)] = blocks
+        positions[i], ids_r[i] = pos, 5 + 7 * i
+    table_row = np.zeros((P_MAX_NB,), np.int32)
+    table_row[:4] = [7, 8, 9, 10]
+    ids_c = np.random.default_rng(0).integers(
+        0, c.vocab_size, P_C).astype(np.int32)
+    chunk_in = (jnp.asarray(table_row), np.int32(start), jnp.asarray(ids_c),
+                np.int32(n_live))
+    rows_in = (jnp.asarray(tables), jnp.asarray(positions),
+               jnp.asarray(ids_r))
+
+    def pool():
+        shape = D.init_latent_pool(c, P_NB, P_BS).shape
+        return jax.random.normal(jax.random.PRNGKey(1), shape, c.dtype)
+
+    def program(kind):
+        return D.DeepSeekServing.step_fn(kind, c, False, None)
+
+    want_c, mid, counts_c = program("prefill")(params, pool(), *chunk_in)
+    want_r, want_pool, counts_r = program("decode")(params, mid, *rows_in)
+    got_c, got_r, got_pool, counts = program("prefill+decode")(
+        params, pool(), *chunk_in, *rows_in)
+    assert got_c.shape == (c.vocab_size,) and got_c.dtype == jnp.float32
+    assert got_r.shape == (P_R, c.vocab_size) and got_r.dtype == jnp.float32
+    tol = 1e-5 * float(np.std(np.asarray(want_c)))
+    np.testing.assert_allclose(got_c, want_c, rtol=1e-5, atol=tol)
+    n = len(rows)
+    np.testing.assert_allclose(got_r[:n], want_r[:n], rtol=1e-5, atol=tol)
+    np.testing.assert_array_equal(np.asarray(got_pool[:, 1:], np.float32),
+                                  np.asarray(want_pool[:, 1:], np.float32))
+    counts, counts_c, counts_r = (np.asarray(a) for a in
+                                  (counts, counts_c, counts_r))
+    assert counts.shape == (c.n_moe_layers, 4)
+    np.testing.assert_array_equal(counts[:, :2],
+                                  counts_c[:, :2] + counts_r[:, :2])
+    assert np.all(counts[:, 0] == (n_live + n) * c.num_experts_per_tok)
+    for col in (2, 3):      # held experts hit, the busiest's rows: the union's
+        assert np.all(counts[:, col] >= np.maximum(counts_c[:, col],
+                                                   counts_r[:, col]))
+        assert np.all(counts[:, col] <= counts_c[:, col] + counts_r[:, col])
+
+
+def test_counted_names_each_part_of_a_program():
+    counts = (jnp.asarray([[8, 4, 3, 2], [8, 5, 2, 3]], jnp.int32),)
+    both = D.DeepSeekServing.counted("prefill+decode", counts, [300],
+                                     [41, 7])
+    assert both == {"pairs": 16, "local_pairs": 9, "experts_hit": 5,
+                    "busiest_rows": 5, "mla_prefill_ctx": 300,
+                    "mla_decode_ctx": 48}
+    assert D.DeepSeekServing.counted("decode", counts, [41, 7])[
+        "mla_decode_ctx"] == 48
+    with pytest.raises(ValueError):
+        D.DeepSeekServing.counted("prefill+decode", counts, [300])
+
+
+class _TwoPrograms(D.DeepSeekServing):
+    """DeepSeek's serving object, offering no chunk that carries the batch."""
+
+    @staticmethod
+    def step_fn(kind, frozen, quant, mesh):
+        if kind == "prefill+decode":
+            return None
+        return D.DeepSeekServing.step_fn(kind, frozen, quant, mesh)
+
+
+def test_engine_streams_are_those_of_the_two_program_path():
+    """Three prompts (one chunk, three, five) through an engine whose chunks
+    carry the running rows and through one whose serving object offers no
+    such program: the same tokens; the carrying engine launched the one
+    program where there was work of both kinds; pairs and latent contexts
+    add up to the same totals either way (each token goes through the
+    layers once and attends to the same columns, whichever program runs
+    it)."""
+    from paddle_tpu.inference import engine as engine_mod
+    _, c, params = tiny()
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (5, 70, 150)]
+    one, out1 = serve(params, c, prompts, 8)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine_mod, "_serving_for", lambda config: _TwoPrograms)
+        two, out2 = serve(params, c, prompts, 8)
+    assert out1 == out2 and sorted(out1) == [0, 1, 2]
+    assert one._chunk_carries and not two._chunk_carries
+    assert ("prefill+decode", 32, 4) in one._compiled
+    assert all(k[0] != "prefill+decode" for k in two._compiled)
+    w1, w2 = one.work_totals, two.work_totals
+    assert w1["prefill_chunks_total"] == w2["prefill_chunks_total"] == 9
+    # every chunk but the first of all has a row running beside it
+    assert w1["prefill_chunks_with_decode_total"] == 8
+    assert w2["prefill_chunks_with_decode_total"] == 0
+    for name in ("decode_rows_total", "prefill_tokens_total",
+                 "moe_pairs_total", "moe_local_pairs_total",
+                 "mla_decode_ctx_tokens_total",
+                 "mla_prefill_ctx_tokens_total"):
+        assert w1[name] == w2[name] > 0, name
+    # an expert both parts hit is read, and counted, once
+    assert 0 < w1["moe_expert_hits_total"] <= w2["moe_expert_hits_total"]
+    assert one.registry.snapshot()["mla_decode_ctx_tokens_total"] \
+        == w1["mla_decode_ctx_tokens_total"]
 
 
 def test_engine_prefill_then_decode_against_the_reference():
@@ -290,12 +433,15 @@ def test_engine_prefill_then_decode_against_the_reference():
     for i, p in enumerate(prompts):
         _, gaps = logit_gaps(m, params, p, out[i])
         assert gaps.max() <= LOGIT_TOL, (i, gaps)
-    assert {("prefill", 32), ("decode", 1)} <= set(eng._compiled)
-    # no chunk carries the decode batch here: two programs, as before
-    assert not eng._chunk_carries
-    assert all(k[0] in ("prefill", "decode") for k in eng._compiled)
-    assert eng.work_totals["prefill_chunks_with_decode_total"] == 0
+    # the first chunk of all runs alone and a decode step follows it; every
+    # later chunk carries the rows that run by then: three programs
+    assert eng._chunk_carries
+    assert {("prefill", 32), ("decode", 1), ("prefill+decode", 32, 4)} \
+        <= set(eng._compiled)
+    assert all(k[0] in ("prefill", "decode", "prefill+decode")
+               for k in eng._compiled)
     assert eng.work_totals["prefill_chunks_total"] == 1 + 3 + 5
+    assert eng.work_totals["prefill_chunks_with_decode_total"] == 3 + 5
     # the counters the model's steps return: pairs = tokens x 4 x 2 layers
     w = eng.work_totals
     tokens = w["prefill_tokens_total"] + w["decode_rows_total"]

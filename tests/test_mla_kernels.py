@@ -1,15 +1,18 @@
 """The latent-attention (MLA) paged kernels against their XLA oracle, in the
 interpreter on tiny shapes: across block boundaries, at several context
-lengths, with fragmented tables, and the decode's in-place column write."""
+lengths, with fragmented tables, and the decode's in-place column write;
+one walk made before the layers against a schedule made in every layer."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu  # noqa: F401
-from paddle_tpu.ops.paged_attention import (_LOG2E, mla_paged_attention_xla,
+from paddle_tpu.ops.paged_attention import (_LIVE, _LOG2E,
+                                            mla_paged_attention_xla,
                                             mla_paged_decode,
-                                            mla_paged_prefill)
+                                            mla_paged_prefill, paged_schedule,
+                                            paged_update_walk)
 
 L, NP, BS, RANK, ROPE, NH = 2, 12, 16, 32, 8, 4
 W = RANK + ROPE
@@ -38,7 +41,9 @@ def test_decode_matches_oracle_and_writes_the_column(positions):
     q = jnp.asarray(rng.standard_normal((b, NH, W)), jnp.bfloat16)
     new = jnp.asarray(rng.standard_normal((b, W)), jnp.bfloat16)
     qs = (q.astype(jnp.float32) * (SCALE * _LOG2E)).astype(q.dtype)
-    out, pool2 = mla_paged_decode(qs, new, pool, tables, pos, 1, rank=RANK)
+    out, pool2 = mla_paged_decode(qs, new, pool,
+                                  paged_update_walk(tables, pos, BS), 1,
+                                  rank=RANK)
     # the pool differs from the old one in the new columns of layer 1 only
     want = np.asarray(pool, np.float32).copy()
     for r, p in enumerate(positions):
@@ -71,3 +76,43 @@ def test_prefill_matches_oracle(start, n_live):
                                np.asarray(ref[:n_live]),
                                rtol=3e-2, atol=3e-2)
     assert np.isfinite(np.asarray(out, np.float32)).all()
+
+
+@pytest.mark.parametrize("positions", [[0, 15, 16], [31, 0, 0], [17, 17, 32]])
+def test_one_walk_for_every_layer_is_the_schedule_made_in_each(positions):
+    """A step makes the batch's walk once and hands it to every layer's
+    call. Until PR 34 each call made ``paged_schedule(positions + 1, ...)``
+    and its live total itself: the same numbers, so outputs and pool are the
+    same to the bit, padding rows (null block, position 0) among them."""
+    rng = np.random.default_rng(7 + sum(positions))
+    b, max_nb = len(positions), 3
+    pool = _pool(rng)
+    tables = np.array(_tables(rng, b, max_nb))
+    tables[np.asarray(positions) == 0] = 0          # padding rows
+    tables, pos = jnp.asarray(tables), jnp.asarray(positions, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((L, b, NH, W)), jnp.bfloat16)
+    new = jnp.asarray(rng.standard_normal((L, b, W)), jnp.bfloat16)
+
+    def each_layers_own():
+        sched = paged_schedule(pos + 1, tables, b * max_nb, BS)
+        return sched, jnp.sum(sched[_LIVE], dtype=jnp.int32)
+
+    def layers(pool, walk_of):
+        outs = []
+        for layer in range(L):
+            o, pool = mla_paged_decode(q[layer], new[layer], pool, walk_of(),
+                                       layer, rank=RANK)
+            outs.append(o)
+        return jnp.stack(outs), pool
+
+    def made_once(pool):
+        walk = paged_update_walk(tables, pos, BS)
+        return layers(pool, lambda: walk)
+
+    want_o, want_pool = jax.jit(lambda p: layers(p, each_layers_own))(pool)
+    got_o, got_pool = jax.jit(made_once)(pool)
+    live = np.asarray(positions) > 0
+    np.testing.assert_array_equal(np.asarray(got_o)[:, live],
+                                  np.asarray(want_o)[:, live])
+    np.testing.assert_array_equal(np.asarray(got_pool, np.float32),
+                                  np.asarray(want_pool, np.float32))
