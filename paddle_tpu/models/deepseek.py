@@ -42,7 +42,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +55,7 @@ from ..ops.rope import apply_rope
 from .llama import _chunk_window, _pin_pool_layout, _pool_write_chunk
 
 _LOG2E = 1.4426950408889634
+INDEX_NORM_EPS = 1e-6       # the LayerNorm on index keys
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,11 +89,32 @@ class DeepSeekConfig:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 1.0
+    # learned sparse attention (GLM-5.2's): ``indexer_types`` names every
+    # layer ``full`` (it holds an indexer and selects) or ``shared`` (it
+    # attends what the nearest ``full`` layer before it selected); empty =
+    # no indexer anywhere, every layer attends its whole context
+    index_n_heads: int = 32
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    indexer_types: Tuple[str, ...] = ()
     dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        kinds = self.indexer_types
+        if kinds and (len(kinds) != self.num_hidden_layers
+                      or kinds[0] != "full"
+                      or set(kinds) - {"full", "shared"}):
+            raise ValueError(
+                f"indexer_types names each of the {self.num_hidden_layers} "
+                f"layers 'full' or 'shared', the first 'full': {kinds}")
 
     @property
     def latent_width(self) -> int:
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def n_index_layers(self) -> int:
+        return self.indexer_types.count("full")
 
     @property
     def n_moe_layers(self) -> int:
@@ -118,6 +140,28 @@ def deepseek_tiny(**over) -> DeepSeekConfig:
     return DeepSeekConfig(**dict(base, **over))
 
 
+def glm_dsa_tiny(**over) -> DeepSeekConfig:
+    """GLM-5.2's kind of model at a size the CPU runs in seconds: one group,
+    plain rope, value heads wider than the nope part, an indexer that keeps
+    16 positions, layers of both kinds (one dense ``full`` layer, then
+    expert layers ``shared``, ``full``, ``shared``)."""
+    base = dict(num_hidden_layers=4, v_head_dim=24, n_group=1, topk_group=1,
+                rope_factor=1.0, rope_theta=8e6, index_n_heads=4,
+                index_head_dim=16, index_topk=16,
+                indexer_types=("full", "shared", "full", "shared"))
+    return deepseek_tiny(**dict(base, **over))
+
+
+def indexer_shapes(c: DeepSeekConfig) -> Dict[str, tuple]:
+    """One indexer: queries from the latent queries, ONE key head from the
+    layer's input through a LayerNorm (scale ``k_norm``, bias ``k_bias``),
+    a weight a head from the layer's input."""
+    hi, di = c.index_n_heads, c.index_head_dim
+    return {"wq_b": (c.q_lora_rank, hi * di), "wk": (c.hidden_size, di),
+            "k_norm": (di,), "k_bias": (di,),
+            "weights_proj": (c.hidden_size, hi)}
+
+
 def attn_shapes(c: DeepSeekConfig) -> Dict[str, tuple]:
     nh, h = c.num_attention_heads, c.hidden_size
     return {
@@ -135,27 +179,34 @@ def param_shapes(c: DeepSeekConfig) -> Dict[str, Any]:
     """The tree ``InferenceEngine`` takes: the leading dense layers as a
     list, the expert layers stacked on axis 0 (two stacks: they do not fit
     one ``(n, ...)`` leaf). Norm scales end in ``norm``; ``router_bias`` is
-    the selection's correction bias."""
+    the selection's correction bias. Where the model has indexers, a
+    ``full`` dense layer holds its own under ``indexer`` and the ``full``
+    expert layers' are stacked under ``moe.indexer`` in their order (a
+    ``shared`` layer holds none)."""
     h, i, e = c.hidden_size, c.intermediate_size, c.moe_intermediate_size
     n, el = c.n_moe_layers, c.n_local_experts
+    nd = c.first_k_dense_replace
     attn = attn_shapes(c)
     sh = c.n_shared_experts * e
-    return {
-        "embed": (c.vocab_size, h),
-        "dense": [dict(attn, gate_proj=(h, i), up_proj=(h, i),
-                       down_proj=(i, h))
-                  for _ in range(c.first_k_dense_replace)],
-        "moe": dict(
-            {k: (n,) + s for k, s in attn.items()},
-            router=(n, h, c.n_routed_experts),
-            router_bias=(n, c.n_routed_experts),
-            experts={"gate": (n, el, h, e), "up": (n, el, h, e),
-                     "down": (n, el, e, h)},
-            shared={"gate": (n, h, sh), "up": (n, h, sh),
-                    "down": (n, sh, h)}),
-        "final_norm": (h,),
-        "lm_head": (h, c.vocab_size),
-    }
+    full = [k == "full" for k in c.indexer_types]
+    dense = [dict(attn, gate_proj=(h, i), up_proj=(h, i), down_proj=(i, h))
+             for _ in range(nd)]
+    for layer, is_full in zip(dense, full):
+        if is_full:
+            layer["indexer"] = indexer_shapes(c)
+    moe = dict(
+        {k: (n,) + s for k, s in attn.items()},
+        router=(n, h, c.n_routed_experts),
+        router_bias=(n, c.n_routed_experts),
+        experts={"gate": (n, el, h, e), "up": (n, el, h, e),
+                 "down": (n, el, e, h)},
+        shared={"gate": (n, h, sh), "up": (n, h, sh),
+                "down": (n, sh, h)})
+    if any(full[nd:]):
+        moe["indexer"] = {k: (sum(full[nd:]),) + s
+                          for k, s in indexer_shapes(c).items()}
+    return {"embed": (c.vocab_size, h), "dense": dense, "moe": moe,
+            "final_norm": (h,), "lm_head": (h, c.vocab_size)}
 
 
 def init_deepseek_params(c: DeepSeekConfig, seed: int = 0, std: float = 0.02):
@@ -175,9 +226,18 @@ def init_deepseek_params(c: DeepSeekConfig, seed: int = 0, std: float = 0.02):
 
 
 def init_latent_pool(c: DeepSeekConfig, num_blocks: int, block_size: int):
-    """The whole cache: ONE latent pool [L, NP, kv_lora_rank +
-    qk_rope_head_dim, block_size], time in lanes, block 0 the null block."""
+    """The latent pool [L, NP, kv_lora_rank + qk_rope_head_dim, block_size],
+    time in lanes, block 0 the null block: the whole cache of a model
+    without indexers, the first of two arrays of one with them."""
     return jnp.zeros((c.num_hidden_layers, num_blocks, c.latent_width,
+                      block_size), c.dtype)
+
+
+def init_index_pool(c: DeepSeekConfig, num_blocks: int, block_size: int):
+    """The index keys' pool [Li, NP, index_head_dim, block_size]: one layer
+    for each ``full`` layer (a ``shared`` layer caches no key of its own),
+    one key head, time in lanes, under the latent pool's block table."""
+    return jnp.zeros((c.n_index_layers, num_blocks, c.index_head_dim,
                       block_size), c.dtype)
 
 
@@ -215,13 +275,21 @@ def yarn_cos_sin(c: DeepSeekConfig, positions):
 
 # -- attention -------------------------------------------------------------------
 
-def mla_project(p, x, cos, sin, c: DeepSeekConfig):
+def latent_queries(p, x, c: DeepSeekConfig):
+    """c_q [T, q_lora_rank]: what the heads' queries, and an indexer's, are
+    projected from."""
+    return fused_rms_norm(x @ p["q_a"], p["q_a_norm"], c.rms_norm_eps)
+
+
+def mla_project(p, x, cos, sin, c: DeepSeekConfig, cq=None):
     """x [T, H] (normed) at the positions of cos/sin [T, rope/2] ->
     (q_nope [T, NH, nope], q_pe [T, NH, rope] roped, latent [T, W]: the
-    normed compressed KV, then the roped shared key)."""
+    normed compressed KV, then the roped shared key). ``cq``: the layer's
+    ``latent_queries`` where the caller made them already."""
     t = x.shape[0]
     nh, dn, dr = c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
-    cq = fused_rms_norm(x @ p["q_a"], p["q_a_norm"], c.rms_norm_eps)
+    if cq is None:
+        cq = latent_queries(p, x, c)
     q = (cq @ p["q_b"]).reshape(t, nh, dn + dr)
     q_nope, q_pe = q[..., :dn], q[..., dn:]
     kv = x @ p["kv_a"]
@@ -259,12 +327,36 @@ def latent_out(p, o_lat, c: DeepSeekConfig):
                    preferred_element_type=jnp.float32)
 
 
-def _queries_and_latent(p, x, cos, sin, pool, c: DeepSeekConfig):
+def _queries_and_latent(p, x, cq, cos, sin, pool, c: DeepSeekConfig):
     """What a paged step's attention starts from: (the absorbed queries
     [T, NH, W], the rows' latent columns [T, W] in the pool's dtype)."""
     with jax.named_scope("mla.project"):
-        q_nope, q_pe, lat = mla_project(p, x, cos, sin, c)
+        q_nope, q_pe, lat = mla_project(p, x, cos, sin, c, cq)
         return absorbed_queries(p, q_nope, q_pe, c), lat.astype(pool.dtype)
+
+
+def indexer_project(ip, x, cq, cos, sin, c: DeepSeekConfig):
+    """One indexer's inputs for rows x [T, H] (normed) with latent queries
+    cq [T, q_lora_rank]: (qI [T, HI, DI] roped, w [T, HI] f32 scaled by
+    HI^-1/2 * DI^-1/2, kI [T, DI] f32: LayerNorm(x W_k), roped). Rope turns
+    the FIRST ``qk_rope_head_dim`` of the DI dimensions, in the pairing and
+    at the frequencies of the attention's."""
+    t = x.shape[0]
+    hi, di, dr = c.index_n_heads, c.index_head_dim, c.qk_rope_head_dim
+    qi = (cq @ ip["wq_b"]).reshape(t, hi, di)
+    qi = jnp.concatenate(
+        [apply_rope(qi[None, ..., :dr], cos, sin)[0], qi[..., dr:]], axis=-1)
+    k = jnp.dot(x, ip["wk"], preferred_element_type=jnp.float32)
+    k = k - jnp.mean(k, axis=-1, keepdims=True)
+    k = k * lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True)
+                      + INDEX_NORM_EPS) * ip["k_norm"].astype(jnp.float32) \
+        + ip["k_bias"].astype(jnp.float32)
+    k = jnp.concatenate(
+        [apply_rope(k[None, :, None, :dr], cos, sin)[0, :, 0], k[:, dr:]],
+        axis=-1)
+    w = jnp.dot(x, ip["weights_proj"], preferred_element_type=jnp.float32) \
+        * (hi ** -0.5 * di ** -0.5)
+    return qi, w, k
 
 
 def mla_expanded(p, x, cos, sin, c: DeepSeekConfig):
@@ -315,6 +407,16 @@ def route(y, router, bias, c: DeepSeekConfig):
                                router.astype(jnp.float32),
                                precision=lax.Precision.HIGHEST))
     sel = s + bias.astype(jnp.float32)
+    _, idx = lax.top_k(_best_groups(sel, c), c.num_experts_per_tok)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * c.routed_scaling_factor
+    return idx.astype(jnp.int32), w
+
+
+def _best_groups(sel, c: DeepSeekConfig):
+    """sel [T, E] -> the same with the experts outside the ``topk_group``
+    best groups at -inf (a group's score: the sum of its two largest). One
+    group (GLM-5.2's) holds every expert, so this is the identity."""
     t, e = sel.shape
     g = sel.reshape(t, c.n_group, e // c.n_group)
     # the two largest of a group as two maxima (``lax.top_k(g, 2)`` sorts
@@ -326,11 +428,7 @@ def route(y, router, bias, c: DeepSeekConfig):
     _, best = lax.top_k(group_score, c.topk_group)
     keep = jnp.zeros((t, c.n_group), bool).at[
         jnp.arange(t)[:, None], best].set(True)
-    masked = jnp.where(keep[:, :, None], g, -jnp.inf).reshape(t, e)
-    _, idx = lax.top_k(masked, c.num_experts_per_tok)
-    w = jnp.take_along_axis(s, idx, axis=1)
-    w = w / jnp.sum(w, axis=-1, keepdims=True) * c.routed_scaling_factor
-    return idx.astype(jnp.int32), w
+    return jnp.where(keep[:, :, None], g, -jnp.inf).reshape(t, e)
 
 
 def _expert_tile(t: int) -> int:
@@ -397,43 +495,88 @@ def moe_ffn(y, live, p, experts, slot, c: DeepSeekConfig):
 
 # -- the paged steps -------------------------------------------------------------
 
-def _layers(params, c: DeepSeekConfig, attend, h, pool, live):
+def _runs(kinds):
+    """Consecutive layers of one kind: [(kind, lo, hi)]."""
+    out, lo = [], 0
+    for i in range(1, len(kinds) + 1):
+        if i == len(kinds) or kinds[i] != kinds[lo]:
+            out.append((kinds[lo], lo, i))
+            lo = i
+    return out
+
+
+def _layers(params, c: DeepSeekConfig, attend, h, pools, live, index=None):
     """Every layer over the residual stream h [T, H], held in FLOAT32 (a
     sub-layer's inputs are rounded to the weights' dtype, its last product's
     float32 accumulator is added unrounded: a bf16 stream's rounding decides
     the router's near-ties the other way more often than the float32
     reference's, and with a share of the experts held a flipped choice is not
-    made up by the others), with the pool as a carry: the leading dense
-    layers one by one, the expert layers in a scan. ``attend(p, x, pool,
-    layer) -> (attention output [T, H], pool)``. Returns (h, pool, counts
-    [n_moe, 4] i32)."""
-    def attn(p, h, pool, layer):
-        x = rms_norm(h, p["input_norm"], c.rms_norm_eps).astype(c.dtype)
-        a, pool = attend(p, x, pool, layer)
-        h = h + a
-        return h, rms_norm(h, p["post_norm"], c.rms_norm_eps), pool
+    made up by the others), with the cache ``pools`` (a tuple: the latent
+    pool, then the index keys' where the model has indexers) as a carry: the
+    leading dense layers one by one, the expert layers in scans.
+    ``attend(p, x, cq, pools, layer, sel) -> (attention output [T, H],
+    pools)``. A model without indexers is one scan over alike layers, ``sel``
+    None. With them the layers are of TWO kinds and the expert layers go in
+    runs of one kind, a scan each: a ``full`` layer first calls ``index(ip,
+    x, cq, pools, slot) -> (pools, sel)`` with its indexer's weights ``ip``
+    and its layer ``slot`` of the index pool (it writes the rows' index
+    keys there and selects), a ``shared`` layer runs no indexer and attends
+    the ``sel`` it is handed, which rides the scans' carry from the nearest
+    ``full`` layer before it. Returns (h, pools, counts [n_moe, 4] i32)."""
+    nd = len(params["dense"])
+    kinds = c.indexer_types or ("plain",) * c.num_hidden_layers
 
+    def attn(p, ip, h, pools, layer, slot, sel):
+        x = rms_norm(h, p["input_norm"], c.rms_norm_eps).astype(c.dtype)
+        with jax.named_scope("mla.project"):
+            cq = latent_queries(p, x, c)
+        if ip is not None:
+            pools, sel = index(ip, x, cq, pools, slot)
+        a, pools = attend(p, x, cq, pools, layer, sel)
+        h = h + a
+        return h, rms_norm(h, p["post_norm"], c.rms_norm_eps), pools, sel
+
+    sel, n_full = None, 0
     for i, p in enumerate(params["dense"]):
-        h, y, pool = attn(p, h, pool, jnp.int32(i))
+        ip = p.get("indexer")
+        h, y, pools, sel = attn(p, ip, h, pools, jnp.int32(i),
+                                jnp.int32(n_full), sel)
+        n_full += ip is not None
         with jax.named_scope("ffn.dense"):
             h = h + swiglu(y.astype(c.dtype), p["gate_proj"], p["up_proj"],
                            p["down_proj"])
-    n_dense = len(params["dense"])
     moe = params["moe"]
     experts = moe["experts"]
-    scanned = {k: v for k, v in moe.items() if k != "experts"}
-
-    def moe_layer(carry, xs):
-        h, pool = carry
-        p, slot = xs
-        h, y, pool = attn(p, h, pool, slot + n_dense)
-        f, stats = moe_ffn(y, live, p, experts, slot, c)
-        return (h + f, pool), stats
-
+    scanned = {k: v for k, v in moe.items() if k not in ("experts", "indexer")}
     n = experts["gate"].shape[0]
-    (h, pool), counts = lax.scan(
-        moe_layer, (h, pool), (scanned, jnp.arange(n, dtype=jnp.int32)))
-    return h, pool, counts
+    at = lambda tree, i: jax.tree_util.tree_map(
+        lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
+
+    n_full_dense, counts = n_full, []
+
+    def run(kind, lo, first_slot):
+        def moe_layer(carry, xs):
+            h, pools, sel = carry
+            i, p = xs
+            if p is None:       # a run inside the stack reads its layer
+                p = at(scanned, i)
+            ip, slot = None, None
+            if kind == "full":
+                slot = first_slot + (i - lo)
+                ip = at(moe["indexer"], slot - n_full_dense)
+            h, y, pools, sel = attn(p, ip, h, pools, i + nd, slot, sel)
+            f, stats = moe_ffn(y, live, p, experts, i, c)
+            return (h + f, pools, sel), stats
+        return moe_layer
+
+    for kind, lo, hi in _runs(kinds[nd:]):
+        whole = (lo, hi) == (0, n)
+        (h, pools, sel), stats = lax.scan(
+            run(kind, lo, n_full), (h, pools, sel),
+            (jnp.arange(lo, hi, dtype=jnp.int32), scanned if whole else None))
+        n_full += (hi - lo) * (kind == "full")
+        counts.append(stats)
+    return h, pools, jnp.concatenate(counts) if len(counts) > 1 else counts[0]
 
 
 def _logits(params, h, c: DeepSeekConfig):
@@ -443,61 +586,134 @@ def _logits(params, h, c: DeepSeekConfig):
                        preferred_element_type=jnp.float32)
 
 
-def deepseek_paged_decode_step(params, pool, tables, positions, ids,
+def _attend_scope(sel):
+    """A layer's attention kernel by what it attends: ``dsa.attend`` under
+    a selection, ``mla.attend`` over the whole context."""
+    return jax.named_scope("mla.attend" if sel is None else "dsa.attend")
+
+
+def _dsa_counts(c: DeepSeekConfig, reach):
+    """What the steps of a model with indexers return after ``counts``:
+    ([3] i32: (row, key) pairs its indexers scored, tokens attended to and
+    tokens in reach, each summed over the layers it holds for), from
+    ``reach`` [R] i32, the positions at or before each live row (0 for a
+    padding row). Nothing for a model without indexers."""
+    if not c.indexer_types:
+        return ()
+    total = jnp.sum(reach)
+    picked = jnp.sum(jnp.minimum(reach, c.index_topk))
+    n = c.num_hidden_layers
+    return (jnp.stack([c.n_index_layers * total, n * picked,
+                       n * total]).astype(jnp.int32),)
+
+
+def _index_rows(ip, x, cq, cos, sin, ipool, walk, positions, slot,
+                c: DeepSeekConfig):
+    """A decode batch's rows through one indexer: each writes its index key
+    (its own new token is a candidate), scores its prefix and selects.
+    Returns (ipool, sel [B, 1, T])."""
+    from ..ops.paged_attention import dsa_index_decode, dsa_select
+    with jax.named_scope("dsa.index"):
+        qi, w, k = indexer_project(ip, x, cq, cos, sin, c)
+        scores, ipool = dsa_index_decode(qi, w, k.astype(ipool.dtype), ipool,
+                                         walk, slot)
+    with jax.named_scope("dsa.select"):
+        sel = dsa_select(scores[:, 0], positions, c.index_topk, c.dtype)
+    return ipool, sel[:, None]
+
+
+def _index_chunk(ip, x, cq, cos, sin, ipool, where, table_row, start, n_live,
+                 slot, c: DeepSeekConfig):
+    """A prefill chunk's rows through one indexer: the chunk's index keys
+    land in the sequence's blocks, every row scores the sequence's keys up
+    to its own and selects. Returns (ipool, sel [C, T])."""
+    from ..ops.paged_attention import dsa_index_prefill, dsa_select
+    wbid, fresh, window = where
+    with jax.named_scope("dsa.index"):
+        qi, w, k = indexer_project(ip, x, cq, cos, sin, c)
+        ipool = _pool_write_chunk(_pin_pool_layout(ipool), slot, wbid, fresh,
+                                  window(k.astype(ipool.dtype)))
+        scores = dsa_index_prefill(qi, w, ipool, table_row, start, n_live,
+                                   slot)
+    with jax.named_scope("dsa.select"):
+        sel = dsa_select(
+            scores, start + jnp.arange(x.shape[0], dtype=jnp.int32),
+            c.index_topk, c.dtype)
+    return ipool, sel
+
+
+def deepseek_paged_decode_step(params, pools, tables, positions, ids,
                                c: DeepSeekConfig):
-    """One decode step over the paged latent pool: ids [B], tables
-    [B, max_nb], positions [B] = the slot each row's new token takes.
-    Padding rows point their tables at the null block 0 (position 0) and
-    route to no expert. Returns (logits [B, vocab] f32, pool, counts)."""
-    from ..ops.paged_attention import mla_paged_decode, paged_update_walk
+    """One decode step over the paged cache ``pools`` (``init_cache``'s
+    tuple): ids [B], tables [B, max_nb], positions [B] = the slot each row's
+    new token takes. Padding rows point their tables at the null block 0
+    (position 0) and route to no expert. Returns (logits [B, vocab] f32,
+    *pools, counts[, ``_dsa_counts``])."""
+    from ..ops.paged_attention import mla_paged_decode, mla_update_walk
     h = jnp.take(params["embed"], ids, axis=0).astype(jnp.float32)
     cos, sin = yarn_cos_sin(c, positions)
     live = tables[:, 0] > 0
-    walk = paged_update_walk(tables, positions, pool.shape[-1])
+    walk = mla_update_walk(tables, positions, pools[0].shape[-1])
 
-    def attend(p, x, pool, layer):
-        q, lat = _queries_and_latent(p, x, cos, sin, pool, c)
-        with jax.named_scope("mla.attend"):
-            o_lat, pool = mla_paged_decode(q, lat, pool, walk, layer,
-                                           rank=c.kv_lora_rank)
+    def attend(p, x, cq, pools, layer, sel):
+        q, lat = _queries_and_latent(p, x, cq, cos, sin, pools[0], c)
+        with _attend_scope(sel):
+            o_lat, pool = mla_paged_decode(q, lat, pools[0], walk, layer,
+                                           rank=c.kv_lora_rank, select=sel)
         with jax.named_scope("mla.project"):
-            return latent_out(p, o_lat, c), pool
+            return latent_out(p, o_lat, c), (pool,) + pools[1:]
 
-    h, pool, counts = _layers(params, c, attend, h, pool, live)
-    return _logits(params, h, c), pool, counts
+    def index(ip, x, cq, pools, slot):
+        ipool, sel = _index_rows(ip, x, cq, cos, sin, pools[1], walk,
+                                 positions, slot, c)
+        return (pools[0], ipool), sel
+
+    h, pools, counts = _layers(params, c, attend, h, tuple(pools), live,
+                               index)
+    return (_logits(params, h, c), *pools, counts,
+            *_dsa_counts(c, jnp.where(live, positions + 1, 0)))
 
 
-def deepseek_paged_prefill_chunk(params, pool, table_row, start, ids, n_live,
+def deepseek_paged_prefill_chunk(params, pools, table_row, start, ids, n_live,
                                  c: DeepSeekConfig):
     """One chunked-prefill slice of ONE sequence: ids [C] padded to the
     chunk, ``n_live`` real tokens, ``start`` tokens already cached. Writes
-    the chunk's latent columns into the sequence's blocks (padding lands in
-    the null block), attends the live context through the block table and
-    returns (logits [vocab] f32 of the last real token, pool, counts)."""
+    the chunk's latent columns (and, in a ``full`` layer, its index keys)
+    into the sequence's blocks (padding lands in the null block), attends
+    the live context through the block table and returns (logits [vocab] f32
+    of the last real token, *pools, counts[, ``_dsa_counts``])."""
     from ..ops.paged_attention import mla_paged_prefill
     C = ids.shape[0]
     h = jnp.take(params["embed"], ids, axis=0).astype(jnp.float32)
     cos, sin = yarn_cos_sin(c, start + jnp.arange(C, dtype=jnp.int32))
     live = jnp.arange(C) < n_live
-    wbid, fresh, window = _chunk_window(table_row, start, n_live, C,
-                                        pool.shape[-1])
+    where = _chunk_window(table_row, start, n_live, C, pools[0].shape[-1])
+    wbid, fresh, window = where
 
-    def attend(p, x, pool, layer):
-        q, lat = _queries_and_latent(p, x, cos, sin, pool, c)
-        with jax.named_scope("mla.attend"):
-            pool = _pool_write_chunk(_pin_pool_layout(pool), layer, wbid,
+    def attend(p, x, cq, pools, layer, sel):
+        q, lat = _queries_and_latent(p, x, cq, cos, sin, pools[0], c)
+        with _attend_scope(sel):
+            pool = _pool_write_chunk(_pin_pool_layout(pools[0]), layer, wbid,
                                      fresh, window(lat))
             o_lat = mla_paged_prefill(q, pool, table_row, start, n_live,
-                                      layer, rank=c.kv_lora_rank)
+                                      layer, rank=c.kv_lora_rank, select=sel)
         with jax.named_scope("mla.project"):
-            return latent_out(p, o_lat, c), pool
+            return latent_out(p, o_lat, c), (pool,) + pools[1:]
 
-    h, pool, counts = _layers(params, c, attend, h, pool, live)
+    def index(ip, x, cq, pools, slot):
+        ipool, sel = _index_chunk(ip, x, cq, cos, sin, pools[1], where,
+                                  table_row, start, n_live, slot, c)
+        return (pools[0], ipool), sel
+
+    h, pools, counts = _layers(params, c, attend, h, tuple(pools), live,
+                               index)
     h_last = lax.dynamic_slice_in_dim(h, n_live - 1, 1, 0)
-    return _logits(params, h_last, c)[0], pool, counts
+    reach = jnp.where(live, start + 1 + jnp.arange(C, dtype=jnp.int32), 0)
+    return (_logits(params, h_last, c)[0], *pools, counts,
+            *_dsa_counts(c, reach))
 
 
-def deepseek_paged_prefill_chunk_with_decode(params, pool, table_row, start,
+def deepseek_paged_prefill_chunk_with_decode(params, pools, table_row, start,
                                              ids, n_live, tables, positions,
                                              row_ids, c: DeepSeekConfig):
     """A prefill chunk with the decode batch riding it, for an iteration
@@ -510,51 +726,68 @@ def deepseek_paged_prefill_chunk_with_decode(params, pool, table_row, start,
     shared expert's, the head's, and each expert's that either part hits
     (the rows' pairs sit in the row tiles the chunk opens anyway). Between
     ``mla_project`` and ``latent_out`` the rows part, each to the absorbed
-    queries and the attention of its own step, roped by its own positions.
+    queries and the attention of its own step, roped by its own positions;
+    so they do through an indexer: the chunk's rows and the batch's each
+    score, and select from, their own sequence's keys.
     The parts touch disjoint blocks: a sequence is in prefill or running,
     never both.
     The batch's update goes first, so the chunk's attention is the pool's
     last reader in a layer and nothing copies it.
 
     Returns (the chunk's last-live-token logits [vocab] f32, the batch's
-    logits [B, vocab] f32, pool, counts: once, for both parts)."""
+    logits [B, vocab] f32, *pools, counts: once, for both parts[,
+    ``_dsa_counts``])."""
     from ..ops.paged_attention import (mla_paged_decode, mla_paged_prefill,
-                                       paged_update_walk)
+                                       mla_update_walk)
     C = ids.shape[0]
     h = jnp.take(params["embed"], jnp.concatenate([ids, row_ids]),
                  axis=0).astype(jnp.float32)
-    cos, sin = yarn_cos_sin(c, jnp.concatenate(
-        [start + jnp.arange(C, dtype=jnp.int32), positions]))
+    chunk_pos = start + jnp.arange(C, dtype=jnp.int32)
+    cos, sin = yarn_cos_sin(c, jnp.concatenate([chunk_pos, positions]))
     live = jnp.concatenate([jnp.arange(C) < n_live, tables[:, 0] > 0])
-    wbid, fresh, window = _chunk_window(table_row, start, n_live, C,
-                                        pool.shape[-1])
-    walk = paged_update_walk(tables, positions, pool.shape[-1])
+    where = _chunk_window(table_row, start, n_live, C, pools[0].shape[-1])
+    wbid, fresh, window = where
+    walk = mla_update_walk(tables, positions, pools[0].shape[-1])
 
-    def attend(p, x, pool, layer):
+    def attend(p, x, cq, pools, layer, sel):
         # projected on all rows together; absorbed a part at a time, so each
         # kernel's queries are written where it reads them (a slice of one
         # [C + B, NH, W] array would be a copy of it in every layer)
+        sel_chunk, sel_rows = sel if sel is not None else (None, None)
         with jax.named_scope("mla.project"):
-            q_nope, q_pe, lat = mla_project(p, x, cos, sin, c)
+            q_nope, q_pe, lat = mla_project(p, x, cos, sin, c, cq)
             q_chunk = absorbed_queries(p, q_nope[:C], q_pe[:C], c)
             q_rows = absorbed_queries(p, q_nope[C:], q_pe[C:], c)
-            lat = lat.astype(pool.dtype)
-        with jax.named_scope("mla.attend"):
-            o_rows, pool = mla_paged_decode(q_rows, lat[C:], pool, walk,
-                                            layer, rank=c.kv_lora_rank)
+            lat = lat.astype(pools[0].dtype)
+        with _attend_scope(sel):
+            o_rows, pool = mla_paged_decode(q_rows, lat[C:], pools[0], walk,
+                                            layer, rank=c.kv_lora_rank,
+                                            select=sel_rows)
             pool = _pool_write_chunk(_pin_pool_layout(pool), layer, wbid,
                                      fresh, window(lat[:C]))
             o_chunk = mla_paged_prefill(q_chunk, pool, table_row, start,
-                                        n_live, layer, rank=c.kv_lora_rank)
+                                        n_live, layer, rank=c.kv_lora_rank,
+                                        select=sel_chunk)
         with jax.named_scope("mla.project"):
             o_lat = jnp.concatenate([o_chunk, o_rows.astype(o_chunk.dtype)])
-            return latent_out(p, o_lat, c), pool
+            return latent_out(p, o_lat, c), (pool,) + pools[1:]
 
-    h, pool, counts = _layers(params, c, attend, h, pool, live)
+    def index(ip, x, cq, pools, slot):
+        ipool, sel_rows = _index_rows(
+            ip, x[C:], cq[C:], cos[C:], sin[C:], pools[1], walk, positions,
+            slot, c)
+        ipool, sel_chunk = _index_chunk(
+            ip, x[:C], cq[:C], cos[:C], sin[:C], ipool, where, table_row,
+            start, n_live, slot, c)
+        return (pools[0], ipool), (sel_chunk, sel_rows)
+
+    h, pools, counts = _layers(params, c, attend, h, tuple(pools), live,
+                               index)
     heads = jnp.concatenate(
         [lax.dynamic_slice_in_dim(h, n_live - 1, 1, 0), h[C:]])
     logits = _logits(params, heads, c)
-    return logits[0], logits[1:], pool, counts
+    reach = jnp.where(live, jnp.concatenate([chunk_pos, positions]) + 1, 0)
+    return (logits[0], logits[1:], *pools, counts, *_dsa_counts(c, reach))
 
 
 # -- what InferenceEngine asks of a model ----------------------------------------
@@ -573,13 +806,15 @@ _PAGED_STEPS = {
 @functools.lru_cache(maxsize=24)
 def _jitted_paged_step(kind: str, c: DeepSeekConfig):
     """The jitted program of ``kind`` for the frozen config:
-    ``fn(params, pool, *inputs)`` with the pool donated."""
+    ``fn(params, *pools, *inputs)`` with the cache's arrays donated (one
+    latent pool; with indexers, the index keys' pool after it)."""
     step, name = _PAGED_STEPS[kind]
+    n = 2 if c.indexer_types else 1
 
-    def fn(params, pool, *inputs):
-        return step(params, pool, *inputs, c)
+    def fn(params, *args):
+        return step(params, args[:n], *args[n:], c)
     fn.__name__ = name
-    return jax.jit(fn, donate_argnums=(1,))
+    return jax.jit(fn, donate_argnums=tuple(range(1, 1 + n)))
 
 
 # ``chipbench/families/deepseek.py`` ``aot_programs`` asks for these by name
@@ -589,10 +824,16 @@ _jitted_paged_prefill = functools.partial(_jitted_paged_step, "prefill")
 
 class DeepSeekServing:
     """What ``InferenceEngine`` asks of a model (``llama.LlamaServing`` is
-    Llama's): the frozen config, the cache (ONE latent pool), the three
-    jitted programs (a chunk, a decode step, a chunk that carries the decode
-    batch), which return ``counts`` after the cache, and the registry
-    counters those feed."""
+    Llama's): the frozen config, the cache, the three jitted programs (a
+    chunk, a decode step, a chunk that carries the decode batch), which
+    return ``counts`` after the cache, and the registry counters those feed.
+    The cache is ONE latent pool; a model with indexers
+    (``DeepSeekConfig.indexer_types``: GLM-5.2's learned sparse attention)
+    keeps the index keys of its ``full`` layers in a SECOND pool of its own
+    depth and width under the same block table, written by the same
+    programs, and its steps return their selection's counts after
+    ``counts``. What a layer attends to follows from the config and the
+    context alone: no option chooses."""
 
     # span argument -> registry counter (paddle_tpu_serve_<name>)
     work = {"pairs": "moe_pairs_total",
@@ -600,16 +841,24 @@ class DeepSeekServing:
             "experts_hit": "moe_expert_hits_total",
             "busiest_rows": "moe_busiest_rows_total",
             "mla_decode_ctx": "mla_decode_ctx_tokens_total",
-            "mla_prefill_ctx": "mla_prefill_ctx_tokens_total"}
+            "mla_prefill_ctx": "mla_prefill_ctx_tokens_total",
+            # a model with indexers: (row, key) pairs its indexers scored,
+            # tokens attended to and tokens in reach, summed over layers
+            "dsa_index_pairs": "dsa_index_pairs_total",
+            "dsa_selected": "dsa_selected_tokens_total",
+            "dsa_ctx": "dsa_ctx_tokens_total"}
 
     @staticmethod
     def refuse(*, mp, kv_dtype, speculative, draft) -> None:
-        """Out of scope for this model, refused rather than half-done."""
+        """Out of scope for this model, refused rather than half-done;
+        with indexers as without (a selection across chips, int8 or fp8
+        index keys and a drafter that shares the selection are ROADMAP.md
+        M5's)."""
         for on, what in ((mp > 1, "ServeConfig.mp > 1 (tensor-parallel "
                                    "serving shards kv heads; the latent "
-                                   "pool has none)"),
+                                   "pool and the index keys' have none)"),
                          (kv_dtype != "auto", "kv_dtype='int8' (no int8 "
-                                              "latent pool)"),
+                                              "latent or index-key pool)"),
                          (speculative or draft, "speculative decoding and "
                                                 "a draft model")):
             if on:
@@ -622,7 +871,10 @@ class DeepSeekServing:
 
     @staticmethod
     def init_cache(config, num_blocks, block_size, kv_dtype):
-        return (init_latent_pool(config, num_blocks, block_size),)
+        pools = (init_latent_pool(config, num_blocks, block_size),)
+        if config.indexer_types:
+            pools += (init_index_pool(config, num_blocks, block_size),)
+        return pools
 
     @staticmethod
     def step_fn(kind, frozen, quant, mesh):
@@ -650,4 +902,8 @@ class DeepSeekServing:
                "busiest_rows": busiest}
         for part, cols in zip(kind.split("+"), ctx, strict=True):
             out[f"mla_{part}_ctx"] = int(sum(cols))
+        if len(counts) > 1:
+            d = np.asarray(counts[1])  # noqa: PTA006 -- as above
+            out.update(dsa_index_pairs=int(d[0]), dsa_selected=int(d[1]),
+                       dsa_ctx=int(d[2]))
         return out

@@ -1587,6 +1587,20 @@ MLA_VMEM_LIMIT = 64 * 1024 * 1024
 MLA_PREFILL_BUDGET = 32 * 1024 * 1024
 MLA_PREFILL_SLOTS = 4          # table slots a grid step of the prefill walks
 
+# The decode kernels of this section walk ``paged_update_walk``'s schedule
+# without its ``_LIVE`` row (their grid ends at the live total): 8 rows,
+# which SMEM holds unpadded (9 pad to 16: at 64 rows x 262 table slots the
+# padded schedule alone is over SMEM's 1 MiB).
+_W_FIELDS = (_SEQ, _BLK, _START, _FIRST, _LAST, _POS, _COL, _UBLK)
+_WPOS, _WCOL, _WUBLK = 5, 6, 7      # the rows before them keep their index
+
+
+def mla_update_walk(tables, positions, block_size):
+    """``paged_update_walk`` as the latent and index decode kernels hold it
+    in SMEM: (the schedule's 8 rows they read, its live total)."""
+    sched, total = paged_update_walk(tables, positions, block_size)
+    return sched[jnp.asarray(_W_FIELDS)], total
+
 
 def _online_step(s, tile, m_s, l_s, acc_s, rank):
     """One block's scores ``s`` [R, bs] f32 (masked) into the running max,
@@ -1603,12 +1617,16 @@ def _online_step(s, tile, m_s, l_s, acc_s, rank):
         preferred_element_type=jnp.float32)
 
 
-def _mla_decode_kernel(lp_ref, sc_ref, q_ref, new_ref, c_ref, o_ref, co_ref,
-                       m_s, l_s, acc_s, *, block_size, rank):
+def _mla_decode_kernel(lp_ref, sc_ref, q_ref, new_ref, c_ref, *rest,
+                       block_size, rank, selected):
+    # ``selected``: one more window before the outputs, the row's
+    # selection of this block's positions ([1, 1, bs], 1 = attend)
+    sel_ref = rest[0] if selected else None
+    o_ref, co_ref, m_s, l_s, acc_s = rest[1:] if selected else rest
     j = pl.program_id(0)
-    pos = sc_ref[_POS, j]
+    pos = sc_ref[_WPOS, j]
     start = sc_ref[_START, j]
-    col = sc_ref[_COL, j]
+    col = sc_ref[_WCOL, j]
     upd = sc_ref[_LAST, j] == np.int32(1)   # the new token's block IS the last
     w = q_ref.shape[2]
 
@@ -1623,7 +1641,11 @@ def _mla_decode_kernel(lp_ref, sc_ref, q_ref, new_ref, c_ref, o_ref, co_ref,
             q_ref[0], tile, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [NH, bs]
         t = start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _online_step(jnp.where(t <= pos, s, jnp.float32(-1e30)), tile,
+        keep = t <= pos
+        if selected:
+            keep = jnp.logical_and(
+                keep, sel_ref[0].astype(jnp.float32) > jnp.float32(0.5))
+        _online_step(jnp.where(keep, s, jnp.float32(-1e30)), tile,
                      m_s, l_s, acc_s, rank)
 
     @pl.when(upd)
@@ -1644,17 +1666,19 @@ def _mla_decode_kernel(lp_ref, sc_ref, q_ref, new_ref, c_ref, o_ref, co_ref,
         chain(c_ref[0, 0])
 
 
-def mla_paged_decode(q, new_col, pool, walk, layer, *, rank):
+def mla_paged_decode(q, new_col, pool, walk, layer, *, rank, select=None):
     """Fused pool-update + absorbed latent attention for one decode layer.
 
     q [B, NH, W] PRE-SCALED by scale*log2(e) (W = rank + rope dims: the
     absorbed nope query, then the roped query); new_col [B, W] the new
     token's latent column (normed compressed KV, then the roped shared key);
-    pool [L, NP, W, bs]; ``walk`` the batch's ``paged_update_walk``, the same
+    pool [L, NP, W, bs]; ``walk`` the batch's ``mla_update_walk``, the same
     in every layer and made once before a step's layers (padding rows point
     at the null block 0 with position 0). Every row writes its column IN
     PLACE (the pool aliases through the call) and attends over its prefix
-    including it; the grid is the walk's live steps alone. Returns (o_lat
+    including it; the grid is the walk's live steps alone. ``select``
+    [B, 1, T] (T >= max_nb * bs, the pool's dtype; ``dsa_select``'s): a row
+    attends the positions it marks 1 and no other. Returns (o_lat
     [B, NH, rank] f32, pool)."""
     b, nh, w = q.shape
     bs = pool.shape[-1]
@@ -1672,9 +1696,14 @@ def mla_paged_decode(q, new_col, pool, walk, layer, *, rank):
         return (sc_ref[_SEQ, j], 0, 0)
 
     def upd_map(j, lp_ref, sc_ref):
-        return (lp_ref[0], sc_ref[_UBLK, j], 0, 0)
+        return (lp_ref[0], sc_ref[_WUBLK, j], 0, 0)
 
-    kernel = functools.partial(_mla_decode_kernel, block_size=bs, rank=rank)
+    def sel_map(j, lp_ref, sc_ref):
+        return (sc_ref[_SEQ, j], 0, sc_ref[_START, j] // bs)
+
+    masked = select is not None
+    kernel = functools.partial(_mla_decode_kernel, block_size=bs, rank=rank,
+                               selected=masked)
     with _mosaic_ctx():
         out, pool = pl.pallas_call(
             kernel,
@@ -1685,7 +1714,7 @@ def mla_paged_decode(q, new_col, pool, walk, layer, *, rank):
                     pl.BlockSpec((1, nh, w), q_map),
                     pl.BlockSpec((1, 1, wp), q_map),
                     pl.BlockSpec((1, 1, w, bs), c_map),
-                ],
+                ] + ([pl.BlockSpec((1, 1, bs), sel_map)] if masked else []),
                 out_specs=[
                     pl.BlockSpec((1, nh, rank), q_map),
                     pl.BlockSpec((1, 1, w, bs), upd_map),
@@ -1715,11 +1744,11 @@ def mla_paged_decode(q, new_col, pool, walk, layer, *, rank):
                                 + 2 * b * w * bs * it),
                 name="paged.mla_decode"),
             interpret=_interpret(),
-        )(lp, sched, q, new, pool)
+        )(lp, sched, q, new, pool, *([select] if masked else []))
     return out, pool
 
 
-def _fit_mla_prefill_tile(c, nh, w, rank, bs, itemsize):
+def _fit_mla_prefill_tile(c, nh, w, rank, bs, itemsize, selected=False):
     """Query tokens a tile of the latent prefill attention holds (PTA002
     contract): the largest divisor of the chunk ``c``, at most
     PREFILL_BLOCK_Q, whose rows (tokens x heads) are whole sublane tiles and
@@ -1730,7 +1759,7 @@ def _fit_mla_prefill_tile(c, nh, w, rank, bs, itemsize):
         rows = tq * nh
         return (2 * rows * w * itemsize + 2 * rows * rank * itemsize
                 + rows * rank * 4 + 2 * rows * 128 * 4
-                + 2 * rows * MLA_PREFILL_SLOTS * bs * 4
+                + (3 if selected else 2) * rows * MLA_PREFILL_SLOTS * bs * 4
                 + 2 * MLA_PREFILL_SLOTS * w * bs * itemsize)
     for tq in range(min(c, PREFILL_BLOCK_Q), 0, -1):
         if c % tq == 0 and ((tq * nh) % 16 == 0 or tq == c) \
@@ -1742,13 +1771,17 @@ def _fit_mla_prefill_tile(c, nh, w, rank, bs, itemsize):
 
 
 def _mla_prefill_kernel(lp_ref, blk_ref, tl_ref, q_ref, *rest, block_size,
-                        rank, nh, slots):
+                        rank, nh, slots, selected):
     """One (query tile, group of ``slots`` table slots) step: rows are
     (token, head) pairs, token-major, against the group's latent tiles (the
     same pool presented once a slot). Max, sum and accumulator are rescaled
     once a group, not once a block. A slot past the tile's frontier
     re-presents a live block and is masked whole by its nominal positions."""
-    c_refs, (o_ref, m_s, l_s, acc_s) = rest[:slots], rest[slots:]
+    c_refs, rest = rest[:slots], rest[slots:]
+    # ``selected``: one more window, the tile's tokens' selection of the
+    # group's positions ([tq, slots * bs], 1 = attend; causal by itself)
+    sel_ref = rest[0] if selected else None
+    o_ref, m_s, l_s, acc_s = rest[1:] if selected else rest
     i, j = pl.program_id(0), pl.program_id(1)
     q0 = tl_ref[_TQ0, i]
     nblk = tl_ref[_TNBLK, i]
@@ -1766,7 +1799,17 @@ def _mla_prefill_kernel(lp_ref, blk_ref, tl_ref, q_ref, *rest, block_size,
             q_ref[...], t, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) for t in tiles],
             axis=1)                                    # [rows, slots * bs]
-        if masked:
+        if selected:
+            # a token's row of the selection to its ``nh`` rows of scores,
+            # on the MXU: [rows, tq] one-hot x [tq, slots * bs]
+            tq = sel_ref.shape[0]
+            own = (lax.broadcasted_iota(jnp.int32, (tq * nh, tq), 0) // nh
+                   == lax.broadcasted_iota(jnp.int32, (tq * nh, tq), 1))
+            keep = jax.lax.dot_general(
+                own.astype(sel_ref.dtype), sel_ref[...],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            s = jnp.where(keep > jnp.float32(0.5), s, jnp.float32(-1e30))
+        elif masked:
             qpos = q0 + lax.broadcasted_iota(jnp.int32, s.shape, 0) // nh
             t = j * (slots * bs) + lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(t <= qpos, s, jnp.float32(-1e30))
@@ -1803,7 +1846,8 @@ def _mla_prefill_kernel(lp_ref, blk_ref, tl_ref, q_ref, *rest, block_size,
             l_s[:, :1], jnp.float32(1e-30))).astype(o_ref.dtype)
 
 
-def mla_paged_prefill(q, pool, table_row, start, n_live, layer, *, rank):
+def mla_paged_prefill(q, pool, table_row, start, n_live, layer, *, rank,
+                      select=None):
     """Absorbed latent attention of one sequence's prefill chunk over its
     live context, read from the latent pool through the block table.
 
@@ -1812,13 +1856,16 @@ def mla_paged_prefill(q, pool, table_row, start, n_live, layer, *, rank):
     ALREADY holding the chunk's own columns; table_row [max_nb] i32; start,
     n_live traced scalars (n_live >= 1). Returns o_lat [C, NH, rank] in q's
     dtype; rows past n_live are zero. The table axis of the grid walks
-    MLA_PREFILL_SLOTS slots a step and ends at the chunk's last live block."""
+    MLA_PREFILL_SLOTS slots a step and ends at the chunk's last live block.
+    ``select`` [C, T] (T = ``dsa_width(max_nb, bs)``, q's dtype;
+    ``dsa_select``'s): a token attends the positions it marks 1 and no
+    other (the marks are causal by themselves)."""
     c, nh, w = q.shape
     L, NP, _, bs = pool.shape
     g = MLA_PREFILL_SLOTS
     max_nb = table_row.shape[0]
     it = jnp.dtype(pool.dtype).itemsize
-    tq = _fit_mla_prefill_tile(c, nh, w, rank, bs, it)
+    tq = _fit_mla_prefill_tile(c, nh, w, rank, bs, it, select is not None)
     n_tiles, rows = c // tq, tq * nh
     # the table padded to whole groups: a slot past a tile's frontier
     # presents the tile's last live block whatever the table holds there
@@ -1837,8 +1884,9 @@ def mla_paged_prefill(q, pool, table_row, start, n_live, layer, *, rank):
     def q_map(i, j, lp_ref, blk_ref, tl_ref):
         return (i, 0)
 
+    masked = select is not None
     kernel = functools.partial(_mla_prefill_kernel, block_size=bs,
-                               rank=rank, nh=nh, slots=g)
+                               rank=rank, nh=nh, slots=g, selected=masked)
     steps = n_tiles * max_nb
     with _mosaic_ctx():
         out = pl.pallas_call(
@@ -1848,7 +1896,10 @@ def mla_paged_prefill(q, pool, table_row, start, n_live, layer, *, rank):
                 grid=(n_tiles, n_groups),
                 in_specs=[pl.BlockSpec((rows, w), q_map)] + [
                     pl.BlockSpec((1, 1, w, bs), c_map(slot))
-                    for slot in range(g)],
+                    for slot in range(g)] + ([pl.BlockSpec(
+                        (tq, g * bs),
+                        lambda i, j, lp_ref, blk_ref, tl_ref: (i, j))]
+                        if masked else []),
                 out_specs=pl.BlockSpec((rows, rank), q_map),
                 scratch_shapes=[
                     pltpu.VMEM((rows, 128), jnp.float32),
@@ -1867,21 +1918,291 @@ def mla_paged_prefill(q, pool, table_row, start, n_live, layer, *, rank):
                                 + c * nh * (w + rank) * q.dtype.itemsize),
                 name="paged.mla_prefill"),
             interpret=_interpret(),
-        )(lp, blk, tiles, q.reshape(c * nh, w), *([pool] * g))
+        )(lp, blk, tiles, q.reshape(c * nh, w), *([pool] * g),
+          *([select] if masked else []))
     return out.reshape(c, nh, rank)
 
 
-def mla_paged_attention_xla(q, pool, tables, lengths, layer, scale, rank):
+def mla_paged_attention_xla(q, pool, tables, lengths, layer, scale, rank,
+                            select=None):
     """Plain-XLA oracle of both latent kernels: q [B, NH, W] UNSCALED, row b
     attends the first lengths[b] columns of its table's blocks (a prefill
-    chunk is B = C rows over one table, lengths = position + 1). Standard
-    e-base softmax in f32; returns [B, NH, rank] f32."""
+    chunk is B = C rows over one table, lengths = position + 1), of them
+    those ``select`` [B, >= max_nb * bs] marks where given. Standard e-base
+    softmax in f32; returns [B, NH, rank] f32."""
     B, max_nb = tables.shape
     w, bs = pool.shape[2], pool.shape[3]
     cc = jnp.transpose(pool[layer][tables], (0, 2, 1, 3)) \
         .reshape(B, w, max_nb * bs).astype(jnp.float32)
     s = jnp.einsum("bhw,bwt->bht", q.astype(jnp.float32), cc) * scale
     t = jnp.arange(max_nb * bs)[None, None, :]
-    s = jnp.where(t < lengths[:, None, None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
+    keep = t < lengths[:, None, None]
+    if select is not None:
+        keep = keep & (select[:, None, :max_nb * bs] > 0)
+    p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
     return jnp.einsum("bht,bct->bhc", p, cc[:, :rank])
+
+
+def dsa_index_xla(qi, wi, ipool, tables, layer):
+    """Plain-XLA oracle of both index kernels: qi [B, HI, DI], wi [B, HI],
+    row b against every column of its table's blocks -> [B, max_nb * bs]
+    f32 (the kernels write the blocks up to a row's own)."""
+    B, max_nb = tables.shape
+    di, bs = ipool.shape[2], ipool.shape[3]
+    kk = jnp.transpose(ipool[layer][tables], (0, 2, 1, 3)) \
+        .reshape(B, di, max_nb * bs).astype(jnp.float32)
+    s = jnp.einsum("bhd,bdt->bht", qi.astype(jnp.float32), kk)
+    return jnp.einsum("bh,bht->bt", wi.astype(jnp.float32),
+                      jnp.maximum(s, 0.0))
+
+
+# Learned sparse attention (a "lightning indexer" beside latent attention).
+# A layer with an indexer keeps ONE more paged pool, of index keys
+# [Li, NP, DI, bs] (one head, time in lanes, under the same block table as
+# the latent pool), scores every cached position of a row,
+#   I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s]),
+# and the row attends the ``k`` positions s <= t of largest I alone
+# (``dsa_select``). The selection is handed to the latent kernels above as a
+# 0/1 array over positions (``select=``): they walk the row's whole live
+# context and mask what was not selected, so the tokens attended to are
+# exactly the selected ones and nothing is gathered. Scores are float32;
+# blocks a walk never reaches hold whatever memory held and are masked by
+# position in ``dsa_select``.
+
+DSA_BLOCK_Q = 128
+
+
+def dsa_width(max_nb, block_size):
+    """Positions a selection spans: the table's, padded to whole groups of
+    the latent prefill kernel's slots."""
+    g = MLA_PREFILL_SLOTS
+    return -(-max_nb // g) * g * block_size
+
+
+def _dsa_scores(q, w, tile, n_heads):
+    """q [n_heads * R, DI] head-major, w [n_heads * R, 1] f32, tile
+    [DI, bs] -> [R, bs] f32."""
+    s = jax.lax.dot_general(q, tile, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    s = jnp.maximum(s, jnp.float32(0.0)) * w
+    r = s.shape[0] // n_heads
+    if r == 1:
+        return jnp.sum(s, axis=0, keepdims=True)
+    out = s[:r]
+    for h in range(1, n_heads):
+        out = out + s[h * r:(h + 1) * r]
+    return out
+
+
+def _dsa_index_prefill_kernel(lp_ref, blk_ref, tl_ref, q_ref, w_ref, k_ref,
+                              o_ref, *, n_heads):
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j < tl_ref[_TNBLK, i])
+    def _live():
+        o_ref[...] = _dsa_scores(q_ref[...], w_ref[...], k_ref[0, 0],
+                                 n_heads)
+
+
+def dsa_index_prefill(qi, wi, ipool, table_row, start, n_live, layer):
+    """Index scores of one sequence's prefill chunk against its cached index
+    keys, read through the block table: qi [C, HI, DI] (roped), wi [C, HI]
+    f32 (scaled), ipool [Li, NP, DI, bs] ALREADY holding the chunk's own
+    keys. Returns I [C, T] f32, T = ``dsa_width``: row t's scores of the
+    positions in the blocks up to its own (the rest is not written). The
+    table axis of the grid ends at the chunk's last live block."""
+    c, hi, di = qi.shape
+    bs = ipool.shape[-1]
+    max_nb = table_row.shape[0]
+    it = jnp.dtype(ipool.dtype).itemsize
+    tq = next((d for d in range(min(c, DSA_BLOCK_Q), 0, -1)
+               if c % d == 0 and d % 16 == 0), c)
+    n_tiles, rows = c // tq, tq * hi
+    blk, tiles = paged_prefill_schedule(table_row, start, n_live, n_tiles,
+                                        tq, bs)
+    n_blocks = jnp.clip((jnp.asarray(start, jnp.int32)
+                         + jnp.asarray(n_live, jnp.int32) + bs - 1) // bs,
+                        1, max_nb).astype(jnp.int32)
+    lp = jnp.asarray([layer], jnp.int32)
+    # a tile's rows head-major, so that the sum over heads is a sum of
+    # whole sublane tiles
+    q = qi.reshape(n_tiles, tq, hi, di).transpose(0, 2, 1, 3) \
+        .reshape(n_tiles * rows, di)
+    w = wi.astype(jnp.float32).reshape(n_tiles, tq, hi).transpose(0, 2, 1) \
+        .reshape(n_tiles * rows, 1)
+
+    def row_map(i, j, lp_ref, blk_ref, tl_ref):
+        return (i, 0)
+
+    steps = n_tiles * max_nb
+    with _mosaic_ctx():
+        return pl.pallas_call(
+            functools.partial(_dsa_index_prefill_kernel, n_heads=hi),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(n_tiles, n_blocks),
+                in_specs=[
+                    pl.BlockSpec((rows, di), row_map),
+                    pl.BlockSpec((rows, 1), row_map),
+                    pl.BlockSpec((1, 1, di, bs),
+                                 lambda i, j, lp_ref, blk_ref, tl_ref: (
+                                     lp_ref[0], blk_ref[i, j], 0, 0)),
+                ],
+                out_specs=pl.BlockSpec(
+                    (tq, bs), lambda i, j, lp_ref, blk_ref, tl_ref: (i, j)),
+            ),
+            out_shape=jax.ShapeDtypeStruct((c, dsa_width(max_nb, bs)),
+                                           jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=MLA_VMEM_LIMIT),
+            cost_estimate=_cost_estimate(
+                flops=2 * rows * di * bs * steps,
+                bytes_accessed=(di * bs * it * steps + c * hi * (di * it + 4)
+                                + c * bs * 4 * max_nb),
+                name="paged.dsa_index_prefill"),
+            interpret=_interpret(),
+        )(lp, blk, tiles, q, w, ipool)
+
+
+def _dsa_index_decode_kernel(lp_ref, sc_ref, q_ref, w_ref, new_ref, k_ref,
+                             o_ref, ko_ref, *, block_size, n_heads):
+    j = pl.program_id(0)
+    col = sc_ref[_WCOL, j]
+    upd = sc_ref[_LAST, j] == np.int32(1)   # the new token's block IS the last
+    di = q_ref.shape[2]
+
+    def score(tile):
+        o_ref[0] = _dsa_scores(q_ref[0], w_ref[0], tile, n_heads)
+
+    @pl.when(upd)
+    def _updated():
+        lane = lax.broadcasted_iota(jnp.int32, (di, block_size), 1)
+        tile = jnp.where(lane == col,
+                         _column_tile(new_ref[0], block_size)[:di],
+                         k_ref[0, 0].astype(jnp.float32)).astype(ko_ref.dtype)
+        ko_ref[0, 0] = tile
+        score(tile)
+
+    @pl.when(jnp.logical_not(upd))
+    def _raw():
+        score(k_ref[0, 0])
+
+
+def dsa_index_decode(qi, wi, new_key, ipool, walk, layer):
+    """Fused index-key write + index scores for one decode layer: qi
+    [B, HI, DI] (roped), wi [B, HI] f32 (scaled), new_key [B, DI] each row's
+    new index key, ipool [Li, NP, DI, bs], ``walk`` the batch's
+    ``mla_update_walk`` (the one the latent kernel walks). Every row
+    writes its key IN PLACE (the pool aliases through the call) and scores
+    its prefix including it. Returns (I [B, 1, T] f32 with T =
+    ``dsa_width``; blocks past a row's own are not written, ipool)."""
+    b, hi, di = qi.shape
+    bs = ipool.shape[-1]
+    it = jnp.dtype(ipool.dtype).itemsize
+    sched, total = walk
+    n_steps = sched.shape[1]
+    max_nb = n_steps // b
+    lp = jnp.asarray([layer], jnp.int32)
+    dp = -(-di // 128) * 128
+    new = jnp.pad(new_key, ((0, 0), (0, dp - di)))[:, None]
+    w = wi.astype(jnp.float32)[:, :, None]
+
+    def k_map(j, lp_ref, sc_ref):
+        return (lp_ref[0], sc_ref[_BLK, j], 0, 0)
+
+    def q_map(j, lp_ref, sc_ref):
+        return (sc_ref[_SEQ, j], 0, 0)
+
+    def upd_map(j, lp_ref, sc_ref):
+        return (lp_ref[0], sc_ref[_WUBLK, j], 0, 0)
+
+    def o_map(j, lp_ref, sc_ref):
+        return (sc_ref[_SEQ, j], 0, sc_ref[_START, j] // bs)
+
+    with _mosaic_ctx():
+        out, ipool = pl.pallas_call(
+            functools.partial(_dsa_index_decode_kernel, block_size=bs,
+                              n_heads=hi),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(total,),
+                in_specs=[
+                    pl.BlockSpec((1, hi, di), q_map),
+                    pl.BlockSpec((1, hi, 1), q_map),
+                    pl.BlockSpec((1, 1, dp), q_map),
+                    pl.BlockSpec((1, 1, di, bs), k_map),
+                ],
+                out_specs=[
+                    pl.BlockSpec((1, 1, bs), o_map),
+                    pl.BlockSpec((1, 1, di, bs), upd_map),
+                ],
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((b, 1, dsa_width(max_nb, bs)),
+                                     jnp.float32),
+                jax.ShapeDtypeStruct(ipool.shape, ipool.dtype),
+            ],
+            # operands count scalar prefetch first: 0=lp, 1=sched, 2=q,
+            # 3=w, 4=new, 5=ipool
+            input_output_aliases={5: 1},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=MLA_VMEM_LIMIT),
+            cost_estimate=_cost_estimate(
+                flops=2 * hi * di * bs * n_steps,
+                bytes_accessed=((di * it + 4) * bs * n_steps
+                                + 2 * b * di * bs * it),
+                name="paged.dsa_index_decode"),
+            interpret=_interpret(),
+        )(lp, sched, qi, w, new, ipool)
+    return out, ipool
+
+
+def _sortable(x):
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    b = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    key = b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
+    return lax.bitcast_convert_type(key, jnp.uint32) ^ jnp.uint32(1 << 31)
+
+
+def dsa_select(scores, positions, k, dtype=jnp.bfloat16):
+    """The ``min(position + 1, k)`` positions s <= position of largest score,
+    a row: scores [R, T] f32 (anything past a row's position), positions [R]
+    i32 -> [R, T] ``dtype``, 1 where selected. EXACT, and ``lax.top_k``'s
+    set (among equal scores the lower position first): the k-th largest
+    score is found by a search over the bits of the scores' order-preserving
+    integer form (32 counting passes over the array, no sort), then among
+    the scores equal to it the first few by position, by a search over the
+    positions' bits; that second search runs only where some row's k-th
+    score is tied."""
+    r, t = scores.shape
+    at = jnp.arange(t, dtype=jnp.int32)[None, :]
+    valid = at <= positions[:, None]
+    # valid keys are >= 1 (no float maps to 0 but -NaN's largest payload)
+    key = jnp.where(valid, jnp.maximum(_sortable(scores), jnp.uint32(1)),
+                    jnp.uint32(0))
+    want = jnp.minimum(positions + 1, k).astype(jnp.int32)[:, None]
+
+    def count(mask):
+        return jnp.sum(mask, axis=1, keepdims=True, dtype=jnp.int32)
+
+    def value_bit(i, v):
+        cand = v | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(count(key >= cand) >= want, cand, v)
+    kth = lax.fori_loop(0, 32, value_bit, jnp.zeros((r, 1), jnp.uint32))
+    above = key > kth
+    tied = key == kth
+    need = want - count(above)          # of the tied, the first ``need``
+
+    def by_position():
+        def pos_bit(i, p):
+            cand = p | (jnp.int32(1) << (bits - 1 - i).astype(jnp.int32))
+            return jnp.where(count(tied & (at < cand)) <= need, cand, p)
+        bits = max(1, int(t).bit_length())
+        upto = lax.fori_loop(0, bits, pos_bit, jnp.zeros((r, 1), jnp.int32))
+        return above | (tied & (at < upto))
+
+    sel = lax.cond(jnp.any(count(tied) != need), by_position,
+                   lambda: above | tied)
+    return (sel & valid).astype(dtype)

@@ -323,7 +323,7 @@ def test_mla_decode_kernel_compiles(sds, batch):
     aliased through the call."""
     out = compile_for_chip(
         jax.jit(lambda q, new, pool, tables, pos: pa.mla_paged_decode(
-            q, new, pool, pa.paged_update_walk(tables, pos, BS), 2,
+            q, new, pool, pa.mla_update_walk(tables, pos, BS), 2,
             rank=MLA_RANK), donate_argnums=(2,)),
         sds((batch, MLA_NH, MLA_W), bf16), sds((batch, MLA_W), bf16),
         sds(MLA_POOL, bf16), sds((batch, MLA_MAX_NB), i32),
@@ -411,3 +411,89 @@ def test_deepseek_chunk_with_decode_compiles_and_fits_one_chip(sds):
     assert gib(held) < 15.0, gib(held)
     assert gib(ma.temp_size_in_bytes) < 1.0, gib(ma.temp_size_in_bytes)
     assert out.as_text().count("tpu_custom_call") == 4 + 7
+
+
+# -- GLM-5.2's learned sparse attention at published widths (PR 35) -------------
+
+DSA_NH, DSA_HI, DSA_DI = 64, 32, 128
+DSA_MAX_NB = 262                 # 33536 tokens of 128
+DSA_POOL = (6, 64, MLA_W, BS)    # the kernels see one block at a time
+DSA_IPOOL = (2, 64, DSA_DI, BS)
+
+
+@pytest.mark.parametrize("batch", [1, 64])
+def test_dsa_decode_kernels_compile(sds, batch):
+    """The indexer's fused key write + scores and the latent decode under a
+    selection, on the walk's 8 rows: at 64 rows x 262 table slots the 9-row
+    schedule (padded to 16) alone is over SMEM's 1 MiB."""
+    t = pa.dsa_width(DSA_MAX_NB, BS)
+    assert t == 264 * BS
+
+    def step(qi, wi, key, ipool, q, new, pool, tables, pos):
+        walk = pa.mla_update_walk(tables, pos, BS)
+        scores, ipool = pa.dsa_index_decode(qi, wi, key, ipool, walk, 1)
+        sel = pa.dsa_select(scores[:, 0], pos, 2048)[:, None]
+        out, pool = pa.mla_paged_decode(q, new, pool, walk, 2,
+                                        rank=MLA_RANK, select=sel)
+        return out, ipool, pool
+    out = compile_for_chip(
+        jax.jit(step, donate_argnums=(3, 6)),
+        sds((batch, DSA_HI, DSA_DI), bf16), sds((batch, DSA_HI), f32),
+        sds((batch, DSA_DI), bf16), sds(DSA_IPOOL, bf16),
+        sds((batch, DSA_NH, MLA_W), bf16), sds((batch, MLA_W), bf16),
+        sds(DSA_POOL, bf16), sds((batch, DSA_MAX_NB), i32),
+        sds((batch,), i32))
+    assert out.as_text().count("tpu_custom_call") == 2
+    assert out.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_dsa_prefill_kernels_compile(sds):
+    """A chunk of 512: index scores in tiles of 128 tokens x 32 heads
+    against one block of keys a step, the exact selection (no sort), the
+    latent prefill in tiles of 32 tokens x 64 heads under it, the selection
+    spread to a token's head rows on the MXU."""
+    assert pa._fit_mla_prefill_tile(512, DSA_NH, MLA_W, MLA_RANK, BS, 2,
+                                    True) == 32
+
+    def step(qi, wi, ipool, q, pool, table, start, n):
+        scores = pa.dsa_index_prefill(qi, wi, ipool, table, start, n, 1)
+        sel = pa.dsa_select(scores, start + jnp.arange(512, dtype=i32), 2048)
+        return pa.mla_paged_prefill(q, pool, table, start, n, 2,
+                                    rank=MLA_RANK, select=sel)
+    out = compile_for_chip(
+        step, sds((512, DSA_HI, DSA_DI), bf16), sds((512, DSA_HI), f32),
+        sds(DSA_IPOOL, bf16), sds((512, DSA_NH, MLA_W), bf16),
+        sds(DSA_POOL, bf16), sds((DSA_MAX_NB,), i32), sds((), i32),
+        sds((), i32))
+    text = out.as_text()
+    assert text.count("tpu_custom_call") == 2 and " sort(" not in text
+
+
+def test_glm_steps_compile_and_fit_one_chip(one_chip, capsys):
+    """The cell's own programs (``chipbench/families/glm_dsa.py``
+    ``aot_programs``): decode at the smallest and largest bucket, the
+    512-token chunk and the chunk that carries 64 rows, 9.38 GB of weights
+    and the two pools (3.62 + 0.27 GB) on one chip, both pools in place
+    (aliased; a copy of either would show among the temporaries). The
+    dense layer and the three runs of expert layers (shared x 3, full,
+    shared) hold 23 kernels, 29 where the rows' index and latent kernels
+    ride beside the chunk's; prints ``memory_analysis()``."""
+    from chipbench import spec
+    cell = spec.load_cell("glm52.serve.longdoc")
+    seen = {}
+    for name, compile_ in cell.family.aot_programs(
+            cell.model, cell.traffic, one_chip, False):
+        out = compile_()
+        ma = out.memory_analysis()
+        held = ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes
+        with capsys.disabled():
+            print(f"\n{name}: {ma}")
+        assert gib(held) < 14.5, (name, gib(held))
+        assert 12.2 < gib(ma.argument_size_in_bytes) < 12.5, name
+        assert 3.6 < gib(ma.alias_size_in_bytes) < 3.7, name
+        assert gib(ma.temp_size_in_bytes) < 1.0, name
+        seen[name] = out.as_text().count("tpu_custom_call")
+    assert seen == {"decode, batch 1": 23, "decode, batch 64": 23,
+                    "prefill chunk 512": 23,
+                    "prefill chunk 512 carrying batch 64": 29}

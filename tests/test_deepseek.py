@@ -93,7 +93,7 @@ def test_expanded_equals_absorbed_equals_reference():
     jnp, and the program's absorbed form through both paged kernels."""
     from paddle_tpu.ops.paged_attention import (mla_paged_decode,
                                                 mla_paged_prefill,
-                                                paged_update_walk)
+                                                mla_update_walk)
     m, c, params = tiny()
     c32 = dataclasses.replace(c, dtype=jnp.float32)
     p = f32(layer0(params))
@@ -119,7 +119,7 @@ def test_expanded_equals_absorbed_equals_reference():
                           0, rank=c.kv_lora_rank)
     outs = [D.latent_out(p, o, c32)]
     for t in range(32, s):
-        walk = paged_update_walk(table[None], jnp.asarray([t], jnp.int32),
+        walk = mla_update_walk(table[None], jnp.asarray([t], jnp.int32),
                                  bs)
         o, pool = mla_paged_decode(q[t:t + 1], lat[t:t + 1], pool, walk, 0,
                                    rank=c.kv_lora_rank)
@@ -148,8 +148,13 @@ def loop_route(y, router, bias, m):
     return np.asarray(idx), np.asarray(w)
 
 
-def test_router_follows_the_equations():
-    m, c, params = tiny()
+GROUPS = {"4 groups, 2 kept": dict(n_group=4, topk_group=2),
+          "one group (GLM-5.2's)": dict(n_group=1, topk_group=1)}
+
+
+@pytest.mark.parametrize("groups", list(GROUPS))
+def test_router_follows_the_equations(groups):
+    m, c, params = tiny(**GROUPS[groups])
     p = f32(layer0(params))
     rng = np.random.default_rng(1)
     y = jnp.asarray(rng.standard_normal((40, c.hidden_size)), jnp.float32)
@@ -167,7 +172,8 @@ def test_router_follows_the_equations():
         np.testing.assert_allclose(w.sum(1), m["routed_scaling_factor"],
                                    rtol=1e-5)
         # at most topk_group groups hold the selected experts
-        assert all(len(set(r // 8)) <= m["topk_group"] for r in idx)
+        assert all(len(set(r // (32 // m["n_group"]))) <= m["topk_group"]
+                   for r in idx)
     # the bias moves the selection and not the weights: with it the choice
     # differs from the unbiased one somewhere, and every weight is still the
     # unbiased score's share
@@ -201,12 +207,14 @@ def uncut_moe(p, x, m):
     return out
 
 
-def test_all_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("groups", list(GROUPS))
+def test_all_shares_add_up_to_the_uncut_layer(groups):
     """Four chips hold 8 of the 32 experts each. Each gives its partial
     result with the shared expert; the routed parts of all four, and the
     shared expert counted once, are the uncut layer: in the reference, and
     in the program's layer told which experts it holds."""
-    m, c, params = tiny(n_routed_experts=32)        # a tree with all 32
+    m, c, params = tiny(n_routed_experts=32,        # a tree with all 32
+                        **GROUPS[groups])
     p = f32({k: (v[0] if not isinstance(v, dict)
                  else {a: b[0] for a, b in v.items()})
              for k, v in params["moe"].items()})

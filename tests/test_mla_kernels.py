@@ -8,11 +8,11 @@ import numpy as np
 import pytest
 
 import paddle_tpu  # noqa: F401
-from paddle_tpu.ops.paged_attention import (_LIVE, _LOG2E,
+from paddle_tpu.ops.paged_attention import (_LIVE, _LOG2E, _W_FIELDS,
                                             mla_paged_attention_xla,
                                             mla_paged_decode,
-                                            mla_paged_prefill, paged_schedule,
-                                            paged_update_walk)
+                                            mla_paged_prefill,
+                                            mla_update_walk, paged_schedule)
 
 L, NP, BS, RANK, ROPE, NH = 2, 12, 16, 32, 8, 4
 W = RANK + ROPE
@@ -42,7 +42,7 @@ def test_decode_matches_oracle_and_writes_the_column(positions):
     new = jnp.asarray(rng.standard_normal((b, W)), jnp.bfloat16)
     qs = (q.astype(jnp.float32) * (SCALE * _LOG2E)).astype(q.dtype)
     out, pool2 = mla_paged_decode(qs, new, pool,
-                                  paged_update_walk(tables, pos, BS), 1,
+                                  mla_update_walk(tables, pos, BS), 1,
                                   rank=RANK)
     # the pool differs from the old one in the new columns of layer 1 only
     want = np.asarray(pool, np.float32).copy()
@@ -95,7 +95,8 @@ def test_one_walk_for_every_layer_is_the_schedule_made_in_each(positions):
 
     def each_layers_own():
         sched = paged_schedule(pos + 1, tables, b * max_nb, BS)
-        return sched, jnp.sum(sched[_LIVE], dtype=jnp.int32)
+        return (sched[jnp.asarray(_W_FIELDS)],
+                jnp.sum(sched[_LIVE], dtype=jnp.int32))
 
     def layers(pool, walk_of):
         outs = []
@@ -106,7 +107,7 @@ def test_one_walk_for_every_layer_is_the_schedule_made_in_each(positions):
         return jnp.stack(outs), pool
 
     def made_once(pool):
-        walk = paged_update_walk(tables, pos, BS)
+        walk = mla_update_walk(tables, pos, BS)
         return layers(pool, lambda: walk)
 
     want_o, want_pool = jax.jit(lambda p: layers(p, each_layers_own))(pool)
