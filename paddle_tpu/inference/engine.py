@@ -24,6 +24,16 @@ compiled step family per bucketed shape —
 Recompiles are therefore bounded by ``len(decode_buckets) + 2`` and
 counted (``serve.compile.*`` counters + StepMetrics.record_compile).
 
+**The engine never holds logits.** Every jitted program ends in the greedy
+head (``ops/sampling.py``): it returns, before the cache, a token (int32,
+the first index of the logits' maximum) and a finite flag (bool, every
+logit of the row finite) for each row it scored: ``decode`` two arrays of
+[bucket], ``prefill`` two scalars, ``prefill+decode`` the chunk's two and
+the rows' two, ``verify`` its (out, commit_len, fin_ok). A step's ``.wait``
+span fetches those few bytes (``_fetch``; counted as ``fetch_bytes`` on the
+span and ``step_fetch_bytes_total`` in the registry), ``_commit_rows`` and
+``_prefill_done`` take tokens, and the NaN screens read the flags.
+
 Scheduling per ``step()`` iteration:
   1. admit waiting requests while the free-block budget covers their
      prompt (plus one decode block of headroom);
@@ -333,6 +343,7 @@ class _Phase:
 # what a phase attempted and what of it was useful, by the argument's name
 # on the span: the registry counter it adds to
 _WORK_TOTALS = {"rows": "decode_rows_total", "bucket": "decode_slots_total",
+                "fetch_bytes": "step_fetch_bytes_total",
                 "n_live": "prefill_tokens_total",
                 "chunk": "prefill_slots_total",
                 "ctx_blocks": "prefill_ctx_blocks_total",
@@ -625,6 +636,8 @@ class InferenceEngine:
                 ("decode_rows_total", "sequences advanced by decode steps"),
                 ("decode_slots_total", "batch slots (the bucket) of the "
                                        "decode steps run"),
+                ("step_fetch_bytes_total", "bytes the steps' waits read "
+                                           "back from the device"),
                 ("prefill_tokens_total", "prompt tokens cached by prefill "
                                          "chunks"),
                 ("prefill_slots_total", "token slots (the chunk) of the "
@@ -890,6 +903,18 @@ class InferenceEngine:
         to ``_compiled`` (the call then traces and compiles)."""
         return _Phase(self, name,
                       {} if key in self._compiled else {"first_call": 1})
+
+    def _fetch(self, sp: _Phase, heads: Sequence, counts: Sequence = ()
+               ) -> List[np.ndarray]:
+        """The one device-to-host read of a step, inside its ``.wait`` span:
+        ``heads`` (tokens, finite flags, accept lengths: a few bytes a row,
+        never logits) as host arrays, every copy started before the first
+        is waited for; the model's ``counts`` come over in the same read
+        (``model.counted`` then finds them on the host). The bytes of both
+        go onto ``sp`` as ``fetch_bytes``."""
+        self._note_work(sp, fetch_bytes=sum(
+            a.nbytes for a in (*heads, *counts)))
+        return jax.device_get([*heads, *counts])[:len(heads)]  # noqa: PTA006 -- step boundary: sampled tokens must reach the scheduler
 
     def _note_work(self, sp: _Phase, **counts: int) -> None:
         """The useful-over-attempted counts of a phase (``rows`` of
@@ -1212,7 +1237,7 @@ class InferenceEngine:
                        carry: bool
                        ) -> Tuple[bool, Optional[List[_Seq]]]:
         """One chunk of ``seq``'s prompt inside its ``serve.prefill`` span
-        ``sp``: plan (blocks, inputs), launch, wait for the logits, commit.
+        ``sp``: plan (blocks, inputs), launch, wait for the token, commit.
         With ``carry`` the running rows are planned beside it and ride the
         chunk's program (``prefill+decode``, always ``max_batch`` rows
         wide): one launch and one wait for both, then the chunk's commit
@@ -1262,7 +1287,7 @@ class InferenceEngine:
         self.work_totals["prefill_chunks_total"] += 1
         key = ("prefill+decode", c, n_rows) if rows else ("prefill", c)
         failure: Optional[Exception] = None
-        row_logits = None
+        row_heads = None
         try:
             faults.inject("serve.prefill.poison", rid=rid)
             with self._launch_span("serve.prefill.launch", key) as launch:
@@ -1273,17 +1298,14 @@ class InferenceEngine:
                         self.params, *self.kv, *chunk_in,
                         jnp.asarray(tables), jnp.asarray(positions),
                         jnp.asarray(toks))
-                    logits, row_logits, out = out[0], out[1], out[2:]
                 else:
                     out = self._step_fn("prefill", self._frozen)(
                         self.params, *self.kv, *chunk_in)
-                    logits, out = out[0], out[1:]
-                n_kv = len(self.kv)
-                self.kv, counts = out[:n_kv], out[n_kv:]
+                n_heads, n_kv = 4 if rows else 2, len(self.kv)
+                heads, self.kv, counts = out[:n_heads], \
+                    out[n_heads:n_heads + n_kv], out[n_heads + n_kv:]
             with self._span("serve.prefill.wait") as wait:
-                logits = np.asarray(logits)  # noqa: PTA006 -- deliberate sync so prefill phase timing is honest
-                if rows:
-                    row_logits = np.asarray(row_logits)  # noqa: PTA006 -- step boundary: sampled tokens must reach the scheduler
+                token, finite, *row_heads = self._fetch(sp, heads, counts)
                 if counts:
                     # a model's own work counts ride the sync just paid:
                     # the chunk's context and, where they rode, the rows'
@@ -1293,14 +1315,13 @@ class InferenceEngine:
                     self._note_work(sp, **self.model.counted(
                         key[0], counts, *ctx))
         except Exception as e:  # noqa: BLE001 -- quarantine boundary
-            failure, row_logits = e, None
+            failure, row_heads = e, None
         with self._span("serve.prefill.commit"):
             if failure is None:
                 try:
                     faults.inject("serve.prefill.logits", rid=rid,
-                                  logits=logits)
-                    if self._nan_check \
-                            and not bool(np.isfinite(logits).all()):
+                                  tokens=token, finite=finite)
+                    if self._nan_check and not bool(finite):
                         raise PoisonError(rid, "non-finite prefill logits")
                 except Exception as e:  # noqa: BLE001 -- quarantine boundary
                     failure = e
@@ -1323,9 +1344,9 @@ class InferenceEngine:
                         recompute=bool(seq.generated))
                 seq.n_cached += n_live
                 if seq.n_cached == seq.prefill_target:
-                    self._prefill_done(seq, logits, done_out)
+                    self._prefill_done(seq, int(token), done_out)
                 faults.inject("serve.prefill.after", rid=rid)
-            if row_logits is None:
+            if not row_heads:
                 return True, None
             # the rows' side, behind the decode batch's hooks in their
             # order (``serve.decode.before`` too: here it fires after the
@@ -1337,19 +1358,21 @@ class InferenceEngine:
             try:
                 faults.inject("serve.decode.poison", rids=rids)
                 faults.inject("serve.decode.logits", rids=rids,
-                              logits=row_logits)
+                              tokens=row_heads[0], finite=row_heads[1])
             except PoisonError as e:
                 return True, self._drop_poisoned(rows, e)
             self._note_work(sp, rows=len(rows), bucket=n_rows)
             self.work_totals["prefill_chunks_with_decode_total"] += 1
-            done_out += self._commit_rows(rows, row_logits, launch.t0,
+            done_out += self._commit_rows(rows, *row_heads, launch.t0,
                                           wait.t1)
         return True, []
 
-    def _prefill_done(self, seq: _Seq, logits: np.ndarray,
+    def _prefill_done(self, seq: _Seq, token: int,
                       done_out: List[_Seq]) -> None:
-        """The prompt's last chunk has landed: register its blocks,
-        sample the first token, start decoding (or finish)."""
+        """The prompt's last chunk has landed: register its blocks, take
+        ``token`` (the greedy head of the chunk's last live position, as
+        the program returned it) for the first new one, start decoding (or
+        finish)."""
         rid = seq.req.request_id
         if self.cache is not None:
             # register the prompt's FULL blocks — wholly below
@@ -1362,9 +1385,8 @@ class InferenceEngine:
                 if added:
                     self._event("prefix_register", rid, added)
         if not seq.generated:
-            # fresh prompt: the final chunk's logits sample the
-            # first new token (greedy)
-            seq.tokens.append(int(logits.argmax(-1)))
+            # fresh prompt: the final chunk's token is the first new one
+            seq.tokens.append(token)
             seq.first_token_t = self._now()
             seq.token_times.append(seq.first_token_t)
             self._last_tokens += 1
@@ -1405,7 +1427,7 @@ class InferenceEngine:
                 self.kv_draft = fn(
                     self.draft_params, *self.kv_draft,
                     table, np.int32(start), jnp.asarray(ids),
-                    np.int32(n_live))[1:]
+                    np.int32(n_live))[2:]
                 start += n_live
         self._mark_compiled(("draft_prefill", c), sp.t1 - sp.t0)
         seq.draft_pos = target
@@ -1466,7 +1488,7 @@ class InferenceEngine:
     def _decode_batch(self, sp: _Phase,
                       redrive: Optional[List[_Seq]] = None) -> List[_Seq]:
         """One token for every RUNNING sequence inside the ``serve.decode``
-        span ``sp``: plan (blocks, inputs), launch, wait for the logits,
+        span ``sp``: plan (blocks, inputs), launch, wait for the tokens,
         commit. ``redrive``: the rows a chunk's program carried beside a
         poisoned one, in place of every RUNNING sequence; their blocks are
         planned and ``serve.decode.before`` has fired for them."""
@@ -1491,15 +1513,15 @@ class InferenceEngine:
                         jnp.asarray(tables), jnp.asarray(positions),
                         jnp.asarray(toks))
                     n_kv = len(self.kv)
-                    logits, self.kv, counts = out[0], out[1:1 + n_kv], \
-                        out[1 + n_kv:]
+                    heads, self.kv, counts = out[:2], out[2:2 + n_kv], \
+                        out[2 + n_kv:]
                 with self._span("serve.decode.wait") as wait:
-                    logits = np.asarray(logits)  # noqa: PTA006 -- step boundary: sampled tokens must reach the scheduler
+                    tokens, finite = self._fetch(sp, heads, counts)
                     if counts:
                         self._note_work(sp, **self.model.counted(
                             "decode", counts, [s.n_cached + 1 for s in rows]))
                 faults.inject("serve.decode.logits", rids=rids,
-                              logits=logits)
+                              tokens=tokens, finite=finite)
             except PoisonError as e:
                 rows = self._drop_poisoned(rows, e)
                 if not rows:
@@ -1511,22 +1533,23 @@ class InferenceEngine:
         self._note_work(sp, rows=len(rows), bucket=bucket)
         with self._span("serve.decode.commit"):
             self._mark_compiled(key, wait.t1 - launch.t0)
-            return self._commit_rows(rows, logits, launch.t0, wait.t1)
+            return self._commit_rows(rows, tokens, finite, launch.t0,
+                                     wait.t1)
 
-    def _commit_rows(self, rows: List[_Seq], logits: np.ndarray,
-                     t0: float, t1: float) -> List[_Seq]:
-        """The decoded rows' bookkeeping from their host logits (row i of
-        ``logits`` is ``rows[i]``'s; rows past them are padding): greedy
-        token, NaN screen, stamps, journal pairs, finish. Returns the rows
-        that finished."""
-        next_tok = logits.argmax(-1)
+    def _commit_rows(self, rows: List[_Seq], next_tok: np.ndarray,
+                     finite: np.ndarray, t0: float, t1: float) -> List[_Seq]:
+        """The decoded rows' bookkeeping from what their program's greedy
+        head returned (``next_tok[i]`` and ``finite[i]`` are ``rows[i]``'s:
+        the first index of its logits' maximum, and whether all of them
+        were finite; entries past the rows are padding's): NaN screen,
+        stamps, journal pairs, finish. The engine never holds logits.
+        Returns the rows that finished."""
         live = list(enumerate(rows))
         if self._nan_check:
             # per-row screen: quarantine rows whose logits went
-            # non-finite; the survivors' already-computed argmax
-            # stands (rows are independent)
-            finite = np.isfinite(
-                logits[:len(rows)].reshape(len(rows), -1)).all(axis=1)
+            # non-finite; the survivors' tokens stand (rows are
+            # independent)
+            finite = finite[:len(rows)]
             if not bool(finite.all()):
                 for i, seq in [p for p in live if not finite[p[0]]]:
                     self._quarantine(seq, "non-finite decode logits")
@@ -1641,11 +1664,10 @@ class InferenceEngine:
                         self.draft_params, *self.kv_draft,
                         jnp.asarray(tables), jnp.asarray(positions),
                         jnp.asarray(toks))
-                    dl, self.kv_draft = res[0], res[1:]
+                    nxt, self.kv_draft = res[0], res[2:]
                 with self._span("serve.draft.wait") as wait:
-                    dl = np.asarray(dl)  # noqa: PTA006 -- host-chained: each draft argmax feeds the next draft step
+                    nxt, = self._fetch(sp, [nxt])  # host-chained: each draft token feeds the next draft step
                 self._mark_compiled(("draft", bucket), wait.t1 - launch.t0)
-                nxt = dl.argmax(-1)
                 for i, seq in stepping:
                     rid = seq.req.request_id
                     seq.draft_pos += 1
@@ -1686,11 +1708,9 @@ class InferenceEngine:
                             jnp.asarray(t_live), jnp.asarray(fed))
                         (out, clen, fin), self.kv = res[:3], res[3:]
                     with self._span("serve.verify.wait") as wait:
-                        out = np.asarray(out)  # noqa: PTA006 -- step boundary: verified tokens must reach the scheduler
-                        clen = np.asarray(clen)  # noqa: PTA006 -- accept lengths gate the host-side commit loop
-                        fin = np.asarray(fin)  # noqa: PTA006 -- per-row finite screen read at the step boundary
+                        out, clen, fin = self._fetch(sp, [out, clen, fin])
                     faults.inject("serve.decode.logits", rids=rids,
-                                  logits=out)
+                                  tokens=out, finite=fin)
                 except PoisonError as e:
                     if not self._pools_alive():
                         raise  # donated pools died mid-kernel: journal path

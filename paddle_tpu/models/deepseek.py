@@ -52,6 +52,7 @@ from jax import lax
 from ..ops.grouped_matmul import grouped_matmul_live, tile_schedule
 from ..ops.rms_norm import fused_rms_norm
 from ..ops.rope import apply_rope
+from ..ops.sampling import sampled
 from .llama import _chunk_window, _pin_pool_layout, _pool_write_chunk
 
 _LOG2E = 1.4426950408889634
@@ -807,12 +808,17 @@ _PAGED_STEPS = {
 def _jitted_paged_step(kind: str, c: DeepSeekConfig):
     """The jitted program of ``kind`` for the frozen config:
     ``fn(params, *pools, *inputs)`` with the cache's arrays donated (one
-    latent pool; with indexers, the index keys' pool after it)."""
+    latent pool; with indexers, the index keys' pool after it). The step's
+    logits do not leave it: the greedy head (``ops/sampling.py``) runs here,
+    so ``decode`` returns (tokens [B] i32, finite [B] bool, *pools,
+    counts...), ``prefill`` (token [], finite [], ...) and
+    ``prefill+decode`` the chunk's pair, then the rows'."""
     step, name = _PAGED_STEPS[kind]
     n = 2 if c.indexer_types else 1
 
     def fn(params, *args):
-        return step(params, args[:n], *args[n:], c)
+        return sampled(step(params, args[:n], *args[n:], c),
+                       len(kind.split("+")))
     fn.__name__ = name
     return jax.jit(fn, donate_argnums=tuple(range(1, 1 + n)))
 
@@ -826,7 +832,9 @@ class DeepSeekServing:
     """What ``InferenceEngine`` asks of a model (``llama.LlamaServing`` is
     Llama's): the frozen config, the cache, the three jitted programs (a
     chunk, a decode step, a chunk that carries the decode batch), which
-    return ``counts`` after the cache, and the registry counters those feed.
+    return a token and a finite flag a row where their steps return logits
+    (``LlamaServing`` states the contract) and ``counts`` after the cache,
+    and the registry counters those feed.
     The cache is ONE latent pool; a model with indexers
     (``DeepSeekConfig.indexer_types``: GLM-5.2's learned sparse attention)
     keeps the index keys of its ``full`` layers in a SECOND pool of its own
@@ -896,7 +904,7 @@ class DeepSeekServing:
         programs together would; ``experts_hit`` and ``busiest_rows`` are of
         the union of its rows (an expert both parts hit streams, and counts,
         once)."""
-        c = np.asarray(counts[0])  # noqa: PTA006 -- read inside the wait the step's logits already pay
+        c = np.asarray(counts[0])  # noqa: PTA006 -- read inside the wait the step's tokens already pay
         pairs, local, hit, busiest = (int(x) for x in c.sum(axis=0))
         out = {"pairs": pairs, "local_pairs": local, "experts_hit": hit,
                "busiest_rows": busiest}

@@ -33,6 +33,7 @@ from ..observability import trace as _obs
 from ..ops.flash_attention import flash_attention_bshd
 from ..ops.rms_norm import fused_rms_norm
 from ..ops.rope import apply_rope, build_rope_cache
+from ..ops.sampling import greedy_head, sampled
 
 
 @dataclasses.dataclass
@@ -562,15 +563,28 @@ def llama_hidden(params, ids, config, parallel, mesh=None, use_flash=True,
     return h
 
 
-def llama_logits(params, h, config, mesh=None, parallel=None):
+def llama_logits(params, h, config, mesh=None, parallel=None,
+                 out_dtype=None):
     """Final norm + lm head. ``mesh``/``parallel`` are the GSPMD training
-    path's (see _per_shard); serving and the manual islands pass none."""
+    path's (see _per_shard); serving and the manual islands pass none.
+    ``out_dtype``: what the head's matmul writes (default: the operands'
+    dtype). The paged steps ask for float32, the accumulator as it is: a
+    bf16 model's logits rounded to bf16 tie at the top (8 bits of mantissa
+    over 32768 columns), and a greedy head then serves the first of the
+    tied columns, not the best. XLA used to grant this unasked (a bf16
+    matmul whose only reader is a convert to float32 keeps its float32
+    result, ``xla_allow_excess_precision``) while the logits left the
+    program; the head inside the program reads the bf16 array."""
     with jax.named_scope("lm_head"):
         x = _rms_norm_on(mesh, parallel, config.rms_norm_eps)(
             h, params["final_norm"])
-        if config.tie_word_embeddings:
-            return x @ params["embed"].T
-        return _mat(x, params["lm_head"])
+        w = (params["embed"].T if config.tie_word_embeddings
+             else params["lm_head"])
+        if out_dtype is None:
+            return _mat(x, w)
+        if isinstance(w, dict):     # weight-only int8: scaled in x's dtype
+            return _mat(x, w).astype(out_dtype)
+        return jnp.matmul(x, w, preferred_element_type=out_dtype)
 
 
 def masked_ce_loss(logits, labels, sep_psum: bool = False, psum_axes=None):
@@ -1038,8 +1052,8 @@ def _tp_down_proj(a, w, tp):
 def _tp_gather_logits(logits, tp):
     """All-gather vocab-sliced logits to the full vocab axis INSIDE the
     island (tiled concat in rank order — exact, no arithmetic), so the
-    verify accept/commit logic computes from identical full logits on
-    every rank."""
+    greedy head of every program, and verify's accept/commit logic after
+    it, compute from identical full logits on every rank."""
     axis, n = tp
     with _obs.comm_span("serve.tp_ring.logits",
                         nbytes=logits.size * (n - 1) * logits.dtype.itemsize,
@@ -1168,8 +1182,8 @@ def llama_paged_decode_step(params, pools, tables, positions, ids,
         return _paged_layer_tail(p, h, ao[:, None], c, tp), pools
 
     h, pools = _scan_paged_layers(layer_step, h, pools, params)
-    logits = llama_logits(params, h, config)[:, 0]
-    return (logits.astype(jnp.float32),) + pools
+    logits = llama_logits(params, h, config, out_dtype=jnp.float32)[:, 0]
+    return (logits,) + pools
 
 
 def _pin_pool_layout(pool):
@@ -1298,8 +1312,9 @@ def llama_paged_prefill_chunk(params, pools, table_row, start, ids, n_live,
 
     h, pools = _scan_paged_layers(layer_step, h, pools, params)
     h_last = lax.dynamic_slice_in_dim(h[0], n_live - 1, 1, 0)[None]
-    logits = llama_logits(params, h_last, config)[0, 0]
-    return (logits.astype(jnp.float32),) + pools
+    logits = llama_logits(params, h_last, config,
+                          out_dtype=jnp.float32)[0, 0]
+    return (logits,) + pools
 
 
 def llama_paged_prefill_chunk_with_decode(params, pools, table_row, start,
@@ -1348,7 +1363,8 @@ def llama_paged_prefill_chunk_with_decode(params, pools, table_row, start,
     h, pools = _scan_paged_layers(layer_step, h, pools, params)
     h_last = lax.dynamic_slice_in_dim(h[0], n_live - 1, 1, 0)
     heads = jnp.concatenate([h_last, h[0, C:]])[:, None]    # [1 + R, 1, H]
-    logits = llama_logits(params, heads, config)[:, 0].astype(jnp.float32)
+    logits = llama_logits(params, heads, config,
+                          out_dtype=jnp.float32)[:, 0]
     return (logits[0], logits[1:]) + pools
 
 
@@ -1496,7 +1512,7 @@ def llama_paged_verify_step(params, pools, tables, qstart, t_live, fed,
     xs = (params["layers"],
           jnp.arange(pools[0].shape[0], dtype=jnp.int32))
     (h,), cols = lax.scan(layer_step, (h,), xs)
-    logits = llama_logits(params, h, config).astype(jnp.float32)
+    logits = llama_logits(params, h, config, out_dtype=jnp.float32)
     if tp is not None:
         # full-vocab logits on every rank (exact concat) so the argmax /
         # accept / commit_len below — and hence the commit kernel each
@@ -1504,8 +1520,8 @@ def llama_paged_verify_step(params, pools, tables, qstart, t_live, fed,
         logits = _tp_gather_logits(logits, tp)
     # per-row finite screen: the engine sees tokens, not logits, so the
     # poison/quarantine contract needs the flag computed here
-    fin_ok = jnp.isfinite(logits).all(axis=(1, 2))             # [B]
-    out = jnp.argmax(logits, axis=-1).astype(jnp.int32)        # [B,T]
+    out, fin_ok = greedy_head(logits)                          # [B,T] each
+    fin_ok = fin_ok.all(axis=1)                                # [B]
     # longest prefix where base argmax == draft proposal (both within
     # the live window), then the base's correction token
     if T > 1:
@@ -1545,52 +1561,58 @@ def _tp_specs(config: LlamaConfig, mesh: Mesh):
 
 
 # kind: (the step ``fn(params, pools, *inputs, config, tp)``, the jitted
-# name's stem, how many inputs follow the cache, and under a mesh the
-# out-specs of what precedes the cache: decode logits leave the island
-# vocab-sharded (the engine's host argmax reads the exact concat), the
-# chunk's too; verify gathers its logits in the island (exact vocab concat),
-# so out / commit_len / fin_ok are rank-identical and leave replicated)
+# name's stem, how many inputs follow the cache, and how many logits arrays
+# lead the step's outputs: the jitted program puts the greedy head on each
+# (``ops/sampling.py``), so a token and a finite flag a row are what it
+# returns; verify holds the head inside itself, its accept rule needs the
+# tokens)
 _PAGED_STEPS = {
-    "decode": (llama_paged_decode_step, "paged_decode_step", 3,
-               (P(None, "mp"),)),
-    "prefill": (llama_paged_prefill_chunk, "paged_prefill_chunk", 4,
-                (P("mp"),)),
+    "decode": (llama_paged_decode_step, "paged_decode_step", 3, 1),
+    "prefill": (llama_paged_prefill_chunk, "paged_prefill_chunk", 4, 1),
     "prefill+decode": (llama_paged_prefill_chunk_with_decode,
-                       "paged_prefill_chunk_with_decode", 7,
-                       (P("mp"), P(None, "mp"))),
-    "verify": (llama_paged_verify_step, "paged_verify_step", 4,
-               (P(), P(), P())),
+                       "paged_prefill_chunk_with_decode", 7, 2),
+    "verify": (llama_paged_verify_step, "paged_verify_step", 4, 0),
 }
 
 
 @functools.lru_cache(maxsize=128)
 def _jitted_paged_step(kind, frozen, quant, mesh):
     """The jitted paged program of ``kind`` (a key of ``_PAGED_STEPS``),
-    ``fn(params, *pools, *inputs) -> (*outputs, *pools)`` with the pools
+    ``fn(params, *pools, *inputs) -> (*heads, *pools)`` with the pools
     donated: two pools, or with ``quant`` the int8 four (``_int8`` in the
-    jitted name). Under a ``mesh`` (``_tp``) the step runs inside one
+    jitted name). ``heads`` are never logits: ``decode`` returns (tokens
+    [B] i32, finite [B] bool), ``prefill`` (token [], finite []),
+    ``prefill+decode`` the chunk's pair and then the rows', ``verify`` (out,
+    commit_len, fin_ok). Under a ``mesh`` (``_tp``) the step runs inside one
     fully-manual shard_map island (the paged Pallas kernels cannot be
-    auto-partitioned under GSPMD), every pool sharded by ``_TP_POOL_SPEC``.
+    auto-partitioned under GSPMD), every pool sharded by ``_TP_POOL_SPEC``;
+    the island gathers its vocab-sharded logits (exact concat, as verify
+    does) before the head, so the tokens are rank-identical, those of one
+    chip's argmax, and leave replicated.
     Call it with all four arguments by position: they are the cache's key."""
-    step, stem, n_inputs, head_specs = _PAGED_STEPS[kind]
+    step, stem, n_inputs, n_logits = _PAGED_STEPS[kind]
     config = LlamaConfig(*frozen)
     n_pools = 4 if quant else 2
     pspecs, tp = (None, None) if mesh is None else _tp_specs(config, mesh)
 
     def run(params, *args):
-        return step(params, args[:n_pools], *args[n_pools:], config, tp)
+        out = step(params, args[:n_pools], *args[n_pools:], config, tp)
+        if tp is not None:
+            out = (*(_tp_gather_logits(x, tp) for x in out[:n_logits]),
+                   *out[n_logits:])
+        out = sampled(out, n_logits)
+        return out[:-n_pools], out[-n_pools:]
 
-    if mesh is None:
-        fn = run
-    else:
+    if mesh is not None:
         pool_specs = (_TP_POOL_SPEC,) * n_pools
-        island = shard_map(
+        run = shard_map(
             run, mesh=mesh,
             in_specs=(pspecs, *pool_specs, *(P(),) * n_inputs),
-            out_specs=(*head_specs, *pool_specs), check_vma=False)
+            out_specs=(P(), pool_specs), check_vma=False)
 
-        def fn(params, *args):
-            return island(params, *args)
+    def fn(params, *args):
+        heads, pools = run(params, *args)
+        return (*heads, *pools)
     fn.__name__ = (stem + ("_int8" if quant else "")
                    + ("_tp" if mesh is not None else ""))
     return jax.jit(fn, donate_argnums=tuple(range(1, 1 + n_pools)))
@@ -1608,12 +1630,19 @@ class LlamaServing:
     """What ``InferenceEngine`` asks of a model, chosen by the config's type
     (``engine._serving_for``): the frozen config its jitted programs are keyed
     by, the cache arrays (a tuple, each indexed by block id on axis 1), and
-    the jitted programs ``fn(params, *cache, ...) -> (..., *cache[, counts])``
-    by ``kind``: ``prefill`` (one chunk of one prompt), ``decode`` (one token
-    a running row), ``verify`` (speculation) and, where the model offers it,
-    ``prefill+decode`` (a chunk with the decode batch riding it:
-    ``fn(params, *cache, <the chunk's inputs>, <the batch's>) -> (chunk
-    logits, row logits, *cache)``). ``step_fn`` returns None for a kind it
+    the jitted programs ``fn(params, *cache, ...) -> (*heads, *cache[,
+    counts])`` by ``kind``. **No program hands the engine logits**: the
+    greedy head (``ops/sampling.py`` ``greedy_head``: first index of the
+    maximum, and whether the row's logits are all finite) runs inside the
+    jitted program, and ``heads`` are, for ``prefill`` (one chunk of one
+    prompt): (token [] i32, finite [] bool) of the last live token;
+    ``decode`` (one token a running row): (tokens [B] i32, finite [B]
+    bool); ``verify`` (speculation): (out, commit_len, fin_ok); and, where
+    the model offers it, ``prefill+decode`` (a chunk with the decode batch
+    riding it: ``fn(params, *cache, <the chunk's inputs>, <the
+    batch's>)``): the chunk's pair, then the rows'. The un-jitted step
+    functions return logits, for the parity tests.
+    ``step_fn`` returns None for a kind it
     does not offer, and the engine then runs the iteration with the
     programs it has. ``work`` names the registry counters a model adds to
     the engine's ``_WORK_TOTALS`` (none here). A model that does not

@@ -23,7 +23,11 @@ Serving-engine points (PR 14; ctx carries ``rid``/``rids``):
     request), ``serve.decode.poison`` (raise
     ``engine.PoisonError(ctx["rids"][i])`` from a corrupt callable to
     poison one batch row), and ``serve.prefill.logits`` /
-    ``serve.decode.logits`` (ctx carries the host logits array).
+    ``serve.decode.logits`` (named for what the step scored; since the
+    programs sample on the device, ctx carries what the engine fetched of
+    it: ``tokens`` int32 and ``finite`` bool, one entry a row slot of the
+    program (scalars for a prefill chunk; [bucket, T] tokens for a verify
+    step), as host arrays. The engine never holds logits).
   control flow: ``serve.preempt`` (graceful stop), ``serve.
   preempt_storm`` (forced eviction).
 

@@ -190,6 +190,15 @@ def test_engine_step_compiles_with_a_pool_half_of_hbm(serve_args, sds, kind,
     fn = L._jitted_paged_step(kind, frozen, quant, None)
     compiled = compile_for_chip(fn, params, *pools[quant], *args)
     assert "tpu_custom_call" in compiled.as_text()
+    # what the engine fetches: a token and a finite flag a row, not the
+    # f32[BATCH, 32000] logits the steps return
+    heads = jax.eval_shape(fn, params, *pools[quant],
+                           *args)[:-len(pools[quant])]
+    assert [(h.shape, h.dtype) for h in heads] == {
+        "decode": [((BATCH,), i32), ((BATCH,), jnp.bool_)],
+        "prefill": [((), i32), ((), jnp.bool_)]}.get(
+            kind, [((), i32), ((), jnp.bool_),
+                   ((BATCH,), i32), ((BATCH,), jnp.bool_)])
     pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize
                      for p in pools[quant])
     ma = compiled.memory_analysis()
@@ -404,6 +413,9 @@ def test_deepseek_chunk_with_decode_compiles_and_fits_one_chip(sds):
     out = compile_for_chip(
         fn, params, pool, sds((max_nb,), i32), sds((), i32), sds((c,), i32),
         sds((), i32), sds((b, max_nb), i32), sds((b,), i32), sds((b,), i32))
+    # outputs: the chunk's token and flag, the rows', the pool, the counts
+    assert [(o.shape, str(o.dtype)) for o in out.out_info[:4]] == [
+        ((), "int32"), ((), "bool"), ((b,), "int32"), ((b,), "bool")]
     ma = out.memory_analysis()
     held = ma.argument_size_in_bytes + ma.temp_size_in_bytes \
         + ma.output_size_in_bytes - ma.alias_size_in_bytes

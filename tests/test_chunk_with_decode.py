@@ -62,11 +62,12 @@ BATCHES = {
 @pytest.mark.parametrize("batch", list(BATCHES))
 @pytest.mark.parametrize("start, n_live", [(0, 16), (8, 11), (13, 1)])
 def test_program_equals_chunk_then_decode(model, start, n_live, batch):
-    """Chunk logits, row logits and both pools of the one program against
+    """Chunk logits, row logits and both pools of the one step against
     ``llama_paged_prefill_chunk`` followed by ``llama_paged_decode_step`` on
     the same inputs. The pools and the rows' logits come out the same to
     the bit here; the chunk's logits to float32 rounding (its head row is
-    one of R + 1 rows of a matmul, not a row alone)."""
+    one of R + 1 rows of a matmul, not a row alone). The jitted program
+    returns the greedy head of those logits and the same pools."""
     cfg, params = model
     rows = BATCHES[batch]
     tables = np.zeros((R, MAX_NB), np.int32)
@@ -83,14 +84,15 @@ def test_program_equals_chunk_then_decode(model, start, n_live, batch):
                 np.int32(n_live))
     rows_in = (jnp.asarray(tables), jnp.asarray(positions),
                jnp.asarray(ids_r))
-    frozen = L._freeze_config(cfg)
 
-    def program(kind):
-        return L._jitted_paged_step(kind, frozen, False, None)
+    def step(kind):
+        """The step function itself, which keeps its logits."""
+        fn = L._PAGED_STEPS[kind][0]
+        return jax.jit(lambda p, k, v, *a: fn(p, (k, v), *a, cfg))
 
-    want_c, kp, vp = program("prefill")(params, *_pools(cfg), *chunk_in)
-    want_r, kp, vp = program("decode")(params, kp, vp, *rows_in)
-    got_c, got_r, got_k, got_v = program("prefill+decode")(
+    want_c, kp, vp = step("prefill")(params, *_pools(cfg), *chunk_in)
+    want_r, kp, vp = step("decode")(params, kp, vp, *rows_in)
+    got_c, got_r, got_k, got_v = step("prefill+decode")(
         params, *_pools(cfg), *chunk_in, *rows_in)
     assert got_c.shape == (cfg.vocab_size,) and got_c.dtype == jnp.float32
     assert got_r.shape == (R, cfg.vocab_size) and got_r.dtype == jnp.float32
@@ -101,6 +103,17 @@ def test_program_equals_chunk_then_decode(model, start, n_live, batch):
     # scribble on in either order
     np.testing.assert_array_equal(got_k[:, 1:], kp[:, 1:])
     np.testing.assert_array_equal(got_v[:, 1:], vp[:, 1:])
+    # the program the engine calls: tokens and flags, never logits
+    tok_c, fin_c, tok_r, fin_r, k2, v2 = L._jitted_paged_step(
+        "prefill+decode", L._freeze_config(cfg), False, None)(
+        params, *_pools(cfg), *chunk_in, *rows_in)
+    assert tok_c.shape == () and tok_c.dtype == jnp.int32
+    assert tok_r.shape == (R,) and fin_r.dtype == jnp.bool_
+    assert int(tok_c) == int(np.argmax(got_c)) and bool(fin_c)
+    np.testing.assert_array_equal(tok_r, np.argmax(got_r, axis=-1))
+    assert bool(np.all(fin_r))
+    np.testing.assert_array_equal(k2, got_k)
+    np.testing.assert_array_equal(v2, got_v)
 
 
 # -- the engine: one launch for an iteration that has both --------------------
@@ -311,9 +324,12 @@ def test_poisoned_row_is_redriven_through_the_decode_program(
     assert {"serve.prefill", "serve.decode"} <= set(eng._phase_ms)
     moved = {k: v - before[k] for k, v in eng.work_totals.items()
              if not k.startswith("prefill_") or "chunks" in k}
+    # fetched: a token (4 B) and a flag (1 B) for the chunk and each of the
+    # four row slots, then for the one row re-driven
     assert moved == {
         "prefill_chunks_total": 1, "prefill_chunks_with_decode_total": 0,
-        "decode_rows_total": 1, "decode_slots_total": 1}
+        "decode_rows_total": 1, "decode_slots_total": 1,
+        "step_fetch_bytes_total": 5 * (1 + 4) + 5}
     assert [s for s in sites if s.startswith("serve.decode.")] == [
         "serve.decode.before", "serve.decode.poison", "serve.decode.poison",
         "serve.decode.logits", "serve.decode.after"]

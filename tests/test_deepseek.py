@@ -313,7 +313,7 @@ BATCHES = {
 @pytest.mark.parametrize("batch", list(BATCHES))
 @pytest.mark.parametrize("start, n_live", [(0, 16), (8, 11), (13, 1)])
 def test_program_equals_chunk_then_decode(start, n_live, batch):
-    """Chunk logits, row logits, the pool and the counts of the one program
+    """Chunk logits, row logits, the pool and the counts of the one step
     against ``deepseek_paged_prefill_chunk`` followed by
     ``deepseek_paged_decode_step`` on the same inputs, over a pool with
     something in every block. Every block but the null one (which padding
@@ -342,13 +342,27 @@ def test_program_equals_chunk_then_decode(start, n_live, batch):
         shape = D.init_latent_pool(c, P_NB, P_BS).shape
         return jax.random.normal(jax.random.PRNGKey(1), shape, c.dtype)
 
-    def program(kind):
-        return D.DeepSeekServing.step_fn(kind, c, False, None)
+    def step(kind):
+        """The step function itself, which keeps its logits."""
+        fn = D._PAGED_STEPS[kind][0]
+        return jax.jit(lambda p, pool, *a: fn(p, (pool,), *a, c))
 
-    want_c, mid, counts_c = program("prefill")(params, pool(), *chunk_in)
-    want_r, want_pool, counts_r = program("decode")(params, mid, *rows_in)
-    got_c, got_r, got_pool, counts = program("prefill+decode")(
+    want_c, mid, counts_c = step("prefill")(params, pool(), *chunk_in)
+    want_r, want_pool, counts_r = step("decode")(params, mid, *rows_in)
+    got_c, got_r, got_pool, counts = step("prefill+decode")(
         params, pool(), *chunk_in, *rows_in)
+    # the program the engine calls: the greedy head of those logits, the
+    # same pool and counts, never logits
+    tok_c, fin_c, tok_r, fin_r, pool2, counts2 = D.DeepSeekServing.step_fn(
+        "prefill+decode", c, False, None)(params, pool(), *chunk_in, *rows_in)
+    assert tok_c.shape == () and tok_r.shape == (P_R,)
+    assert tok_r.dtype == jnp.int32 and fin_r.dtype == jnp.bool_
+    assert int(tok_c) == int(np.argmax(got_c)) and bool(fin_c)
+    np.testing.assert_array_equal(tok_r, np.argmax(got_r, axis=-1))
+    assert bool(np.all(fin_r))
+    np.testing.assert_array_equal(np.asarray(pool2, np.float32),
+                                  np.asarray(got_pool, np.float32))
+    np.testing.assert_array_equal(counts2, counts)
     assert got_c.shape == (c.vocab_size,) and got_c.dtype == jnp.float32
     assert got_r.shape == (P_R, c.vocab_size) and got_r.dtype == jnp.float32
     tol = 1e-5 * float(np.std(np.asarray(want_c)))
@@ -461,14 +475,17 @@ def test_engine_prefill_then_decode_against_the_reference():
     snap = eng.registry.snapshot()
     assert snap["moe_local_pairs_total"] == w["moe_local_pairs_total"]
     # logits, not tokens: one chunk's logits against the reference's row
-    fn = D._jitted_paged_prefill(c)
+    # (the step function's: the jitted program returns their greedy head)
     pool = D.init_latent_pool(c, 4, 128)
     ids = np.zeros(32, np.int32)
     ids[:5] = prompts[0]
-    logits, _, _ = fn(params, pool, jnp.asarray([1, 0, 0], jnp.int32),
-                      np.int32(0), jnp.asarray(ids), np.int32(5))
+    chunk = (jnp.asarray([1, 0, 0], jnp.int32), np.int32(0),
+             jnp.asarray(ids), np.int32(5))
+    logits, _, _ = D.deepseek_paged_prefill_chunk(params, (pool,), *chunk, c)
     ref = fam.logits_after(params, m, prompts[0], 1, 512, 16)[0]
     assert rms_gap(np.asarray(logits), ref) <= LOGIT_RMS_TOL
+    token, finite, _, _ = D._jitted_paged_prefill(c)(params, pool, *chunk)
+    assert int(token) == int(np.argmax(logits)) and bool(finite)
     fp8 = fam.logits_after(params, m, prompts[0], 1, 512, 16, mode="fp8")[0]
     assert rms_gap(fp8, ref) > LOGIT_RMS_TOL
 

@@ -137,15 +137,20 @@ def test_float32_engine_agrees_with_the_reference_through_all_programs():
     assert max(gaps(m, params, p, out[i], mode="fp8").max()
                for i, p in enumerate(prompts)) > LOGIT_TOL
     # logits, not tokens: one chunk's logits against the reference's row
-    fn = D._jitted_paged_step("prefill", c)
+    # (the step function's: the jitted program returns their greedy head)
     ids = np.zeros(32, np.int32)
     ids[:5] = prompts[0]
-    logits = fn(params, *D.DeepSeekServing.init_cache(c, 4, 128, "auto"),
-                jnp.asarray([1, 0, 0], jnp.int32), np.int32(0),
-                jnp.asarray(ids), np.int32(5))[0]
+    chunk = (jnp.asarray([1, 0, 0], jnp.int32), np.int32(0),
+             jnp.asarray(ids), np.int32(5))
+    logits = D.deepseek_paged_prefill_chunk(
+        params, D.DeepSeekServing.init_cache(c, 4, 128, "auto"), *chunk,
+        c)[0]
     ref = fam.logits_after(params, m, prompts[0], 1, 512, 16)[0]
     assert np.sqrt(np.mean((np.asarray(logits) - ref) ** 2)) \
         <= F32_TOL * ref.std()
+    token, finite = D._jitted_paged_step("prefill", c)(
+        params, *D.DeepSeekServing.init_cache(c, 4, 128, "auto"), *chunk)[:2]
+    assert int(token) == int(np.argmax(logits)) and bool(finite)
     # the counters: every row's reach, at most index_topk of it selected in
     # each of the 4 layers, all of it scored by each of the 2 indexers
     reach = [t + 1 for p in prompts for t in range(len(p) + 7)]

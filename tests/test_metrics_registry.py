@@ -279,6 +279,7 @@ def _legacy_engine_dict(eng):
         # the useful-over-attempted counters of the phase spans
         "decode_rows_total": eng.work_totals["decode_rows_total"],
         "decode_slots_total": eng.work_totals["decode_slots_total"],
+        "step_fetch_bytes_total": eng.work_totals["step_fetch_bytes_total"],
         "prefill_tokens_total": eng.work_totals["prefill_tokens_total"],
         "prefill_slots_total": eng.work_totals["prefill_slots_total"],
         "prefill_ctx_blocks_total":
