@@ -253,14 +253,16 @@ __all__ = ["Config", "Predictor", "PredictorPool", "create_predictor",
 
 
 # --- continuous-batching serving engine (paged KV cache) -------------------
-from .kv_cache import BlockPool, BlockPoolError, PrefixCache, pad_table  # noqa: E402
+from .kv_cache import (BlockPool, BlockPoolError, PrefixCache,  # noqa: E402
+                       SlotPool, pad_table)
 from .engine import (Admission, AdmissionController, InferenceEngine,  # noqa: E402
                      PoisonError, Request, ServeConfig)
 from .journal import (EngineJournal, JournalCompatError,  # noqa: E402
                       read_journal)
 from .fleet import FleetRouter  # noqa: E402
 
-__all__ += ["BlockPool", "BlockPoolError", "PrefixCache", "pad_table",
+__all__ += ["BlockPool", "BlockPoolError", "PrefixCache", "SlotPool",
+            "pad_table",
             "InferenceEngine", "Request", "ServeConfig", "Admission",
             "AdmissionController", "PoisonError", "EngineJournal",
             "JournalCompatError", "read_journal", "FleetRouter"]

@@ -105,7 +105,7 @@ from ..observability.metrics import StepMetrics
 from ..observability.request_trace import RequestTracer
 from ..observability.trace import record_counter, span
 from .journal import EngineJournal, JournalCompatError, read_journal
-from .kv_cache import (BlockPool, PrefixCache, pad_table,
+from .kv_cache import (BlockPool, PrefixCache, SlotPool, pad_table,
                        pool_bytes_per_rank)
 
 ENV_TRACE_REQUESTS = "PADDLE_TPU_TRACE_REQUESTS"
@@ -273,6 +273,9 @@ class _Seq:
         self.n_prompt = len(self.tokens)
         self.n_cached = 0
         self.blocks: List[int] = []
+        # a model with recurrent state: the sequence's slot of it, held
+        # exactly while it holds blocks
+        self.slot: Optional[int] = None
         self.state = WAITING
         self.arrival = now
         self.order = 0                 # submission sequence number
@@ -354,9 +357,11 @@ def _serving_for(config):
     """The serving side of ``config``'s model: what a model hands the engine
     lives beside the model (see ``models/llama.py`` ``LlamaServing``), and
     this module imports nothing else of ``paddle_tpu.models``."""
-    from ..models import deepseek, llama
+    from ..models import deepseek, falcon_h1, llama
     if isinstance(config, deepseek.DeepSeekConfig):
         return deepseek.DeepSeekServing
+    if isinstance(config, falcon_h1.FalconH1Config):
+        return falcon_h1.FalconH1Serving
     return llama.LlamaServing
 
 
@@ -407,12 +412,18 @@ class InferenceEngine:
         spec = (self.serve.speculative
                 if self.serve.speculative is not None
                 else envs.get(ENV_SERVE_SPEC))
+        # COW prefix cache: full prompt blocks stay indexed after
+        # release and later identical prompts share them ref-counted
+        prefix_on = (self.serve.prefix_cache
+                     if self.serve.prefix_cache is not None
+                     else envs.get(ENV_SERVE_PREFIX_CACHE))
         # what the model brings (see _serving_for); it refuses loudly, here,
         # what it cannot run
         self.model = _serving_for(config)
         self.model.refuse(mp=self.mp, kv_dtype=self.kv_dtype,
                           speculative=bool(spec),
-                          draft=draft_params is not None)
+                          draft=draft_params is not None,
+                          prefix_cache=bool(prefix_on))
         # the cache: a tuple of arrays, each indexed by block id on axis 1.
         # Copy-on-write, the liveness check and the pool's bytes treat it so;
         # only the model's own programs know what an array holds (Llama:
@@ -421,11 +432,18 @@ class InferenceEngine:
             config, self.serve.num_blocks, self.serve.block_size,
             self.kv_dtype)
         self.kv_draft: Tuple = ()
-        # COW prefix cache: full prompt blocks stay indexed after
-        # release and later identical prompts share them ref-counted
-        prefix_on = (self.serve.prefix_cache
-                     if self.serve.prefix_cache is not None
-                     else envs.get(ENV_SERVE_PREFIX_CACHE))
+        # recurrent state, for a model that has it (``init_state``): a
+        # second tuple of arrays, each indexed by SLOT on axis 1, one slot a
+        # running sequence and slot 0 the null slot. Slots are given and
+        # freed with the blocks; copy-on-write never touches them (nothing
+        # shares a state); the steps take them after the cache, and a slot
+        # a row as their last input
+        init_state = getattr(self.model, "init_state", None)
+        self.slots: Optional[SlotPool] = None
+        self.state: Tuple = ()
+        if init_state is not None:
+            self.slots = SlotPool(self.serve.max_batch + 1)
+            self.state = init_state(config, self.slots.num_slots)
         self.cache: Optional[PrefixCache] = \
             PrefixCache(self.pool) if prefix_on else None
         self._cow_copies = 0
@@ -653,6 +671,9 @@ class InferenceEngine:
         for name in self.model.work.values():       # the model's own counts
             r.gauge(name, fn=lambda n=name: self.work_totals[n],
                     help="counted by the model's jitted steps")
+        if self.slots is not None:
+            r.gauge("state_slots_in_use", fn=lambda: self.slots.used_slots,
+                    help="recurrent-state slots held by sequences")
         # PR 16 capacity gauges, only when the cache is live: the
         # default exposition stays byte-compatible with the pre-PR-15
         # legacy dict (pinned by the metrics-registry golden test)
@@ -702,10 +723,17 @@ class InferenceEngine:
         need = self.pool.blocks_for(n_tokens) - len(seq.blocks)
         if need <= 0:
             return True
+        wants_slot = self.slots is not None and seq.slot is None
+        if wants_slot and not self.slots.free_slots:
+            return False
         got = self.pool.alloc(need)
         if got is None:
             return False
         seq.blocks.extend(got)
+        if wants_slot:
+            # with its first blocks; the slot's old bytes are never read: a
+            # chunk that starts at 0 begins from zeros inside the program
+            seq.slot = self.slots.alloc()
         record_counter("serve.blocks_alloc", need)
         return True
 
@@ -714,6 +742,9 @@ class InferenceEngine:
             record_counter("serve.blocks_free", len(seq.blocks))
             self.pool.free(seq.blocks)
             seq.blocks = []
+        if seq.slot is not None:
+            self.slots.free(seq.slot)
+            seq.slot = None
 
     def _cow_span(self, seq: _Seq, start: int, n_tokens: int) -> bool:
         """Copy-on-write guard: make every block covering positions
@@ -875,7 +906,7 @@ class InferenceEngine:
         """False when an exception killed a kernel AFTER its donated
         k/v pool buffers were invalidated — unrecoverable in-process
         (the journal recovery path owns that failure mode)."""
-        for pool in self.kv + self.kv_draft:
+        for pool in self.kv + self.kv_draft + self.state:
             deleted = getattr(pool, "is_deleted", None)
             if deleted is not None and deleted():
                 return False
@@ -1293,17 +1324,19 @@ class InferenceEngine:
             with self._launch_span("serve.prefill.launch", key) as launch:
                 chunk_in = (jnp.asarray(table), np.int32(seq.n_cached),
                             jnp.asarray(ids), np.int32(n_live))
+                # a model with state: the chunk's slot, then the rows'
+                slot_in = () if self.slots is None else (np.int32(seq.slot),)
                 if rows:
                     out = self._step_fn("prefill+decode", self._frozen)(
-                        self.params, *self.kv, *chunk_in,
+                        self.params, *self.kv, *self.state, *chunk_in,
                         jnp.asarray(tables), jnp.asarray(positions),
-                        jnp.asarray(toks))
+                        jnp.asarray(toks), *slot_in,
+                        *self._row_slots(rows, n_rows))
                 else:
                     out = self._step_fn("prefill", self._frozen)(
-                        self.params, *self.kv, *chunk_in)
-                n_heads, n_kv = 4 if rows else 2, len(self.kv)
-                heads, self.kv, counts = out[:n_heads], \
-                    out[n_heads:n_heads + n_kv], out[n_heads + n_kv:]
+                        self.params, *self.kv, *self.state, *chunk_in,
+                        *slot_in)
+                heads, counts = self._unpack(out, 4 if rows else 2)
             with self._span("serve.prefill.wait") as wait:
                 token, finite, *row_heads = self._fetch(sp, heads, counts)
                 if counts:
@@ -1360,7 +1393,10 @@ class InferenceEngine:
                 faults.inject("serve.decode.logits", rids=rids,
                               tokens=row_heads[0], finite=row_heads[1])
             except PoisonError as e:
-                return True, self._drop_poisoned(rows, e)
+                # the program that carried the rows has run
+                rows, row_heads = self._drop_poisoned(rows, e, row_heads)
+                if row_heads is None:
+                    return True, rows
             self._note_work(sp, rows=len(rows), bucket=n_rows)
             self.work_totals["prefill_chunks_with_decode_total"] += 1
             done_out += self._commit_rows(rows, *row_heads, launch.t0,
@@ -1470,20 +1506,53 @@ class InferenceEngine:
         return ([s.req.request_id for s in rows], bucket, toks, positions,
                 tables)
 
-    def _drop_poisoned(self, rows: List[_Seq], e: PoisonError) -> List[_Seq]:
-        """Quarantine the row ``e`` names and count the re-drive its
-        batchmates are owed; returns them. Rows are independent (disjoint
-        blocks, per-row tables), so survivors' tokens are bit-identical to
-        a batch that never held the poison."""
+    def _row_slots(self, rows: List[_Seq], bucket: int) -> Tuple:
+        """A model with state: (the rows' slots [bucket] i32, padding rows at
+        the null slot 0), the steps' last input; nothing for any other."""
+        if self.slots is None:
+            return ()
+        slots = np.zeros((bucket,), np.int32)
+        slots[:len(rows)] = [s.slot for s in rows]
+        return (jnp.asarray(slots),)
+
+    def _unpack(self, out: Sequence, n_heads: int) -> Tuple[Sequence, Sequence]:
+        """A step's outputs taken apart: the donated caches back into
+        ``self.kv`` and ``self.state``; returns (heads, counts)."""
+        n_kv, n_st = len(self.kv), len(self.state)
+        self.kv = tuple(out[n_heads:n_heads + n_kv])
+        self.state = tuple(out[n_heads + n_kv:n_heads + n_kv + n_st])
+        return out[:n_heads], out[n_heads + n_kv + n_st:]
+
+    def _drop_poisoned(self, rows: List[_Seq], e: PoisonError,
+                       ran: Optional[Tuple[np.ndarray, np.ndarray]] = None
+                       ) -> Tuple[List[_Seq], Optional[Tuple]]:
+        """Quarantine the row ``e`` names; returns (its batchmates, what
+        they are owed). Rows are independent (disjoint blocks and slots,
+        per-row tables), so survivors' tokens are bit-identical to a batch
+        that never held the poison. THE RULE: a fault raised BEFORE the
+        launch has moved nothing, and the batchmates are owed a re-drive
+        through the decode program (None; counted in ``decode_redrives``).
+        A fault raised once the program has RUN (``ran``: the tokens and
+        finite flags it returned, a row each) finds the survivors' KV column
+        written and, for a model with recurrent state, their state advanced
+        by one token: rewriting a KV column is idempotent, advancing a state
+        twice is not. So a model with state is never launched again for
+        them: they are owed the commit of that run's tokens, returned here
+        as (tokens, finite) cut to the survivors. A model without state
+        re-drives in both cases, as it always has."""
         if not self._pools_alive():
             raise e  # donated pools died mid-kernel: journal path
         bad = next((s for s in rows if s.req.request_id == e.rid), None)
         if bad is None:
             raise e  # not attributable to this batch
         self._quarantine(bad, e.cause)
+        keep = [i for i, s in enumerate(rows) if s is not bad]
+        left = [rows[i] for i in keep]
+        if ran is not None and self.slots is not None:
+            return left, tuple(a[keep] for a in ran)
         self._redrives += 1
         record_counter("serve.decode_redrive")
-        return [s for s in rows if s is not bad]
+        return left, None
 
     def _decode_batch(self, sp: _Phase,
                       redrive: Optional[List[_Seq]] = None) -> List[_Seq]:
@@ -1504,31 +1573,32 @@ class InferenceEngine:
         while True:
             rids, bucket, toks, positions, tables = inputs
             key = ("decode", bucket)
+            ran = None          # the program's heads, once it has run
             try:
                 faults.inject("serve.decode.poison", rids=rids)
                 with self._launch_span("serve.decode.launch", key) as launch:
                     fn = self._step_fn("decode", self._frozen)
                     out = fn(
-                        self.params, *self.kv,
+                        self.params, *self.kv, *self.state,
                         jnp.asarray(tables), jnp.asarray(positions),
-                        jnp.asarray(toks))
-                    n_kv = len(self.kv)
-                    heads, self.kv, counts = out[:2], out[2:2 + n_kv], \
-                        out[2 + n_kv:]
+                        jnp.asarray(toks), *self._row_slots(rows, bucket))
+                    heads, counts = self._unpack(out, 2)
                 with self._span("serve.decode.wait") as wait:
-                    tokens, finite = self._fetch(sp, heads, counts)
+                    tokens, finite = ran = self._fetch(sp, heads, counts)
                     if counts:
                         self._note_work(sp, **self.model.counted(
                             "decode", counts, [s.n_cached + 1 for s in rows]))
                 faults.inject("serve.decode.logits", rids=rids,
                               tokens=tokens, finite=finite)
             except PoisonError as e:
-                rows = self._drop_poisoned(rows, e)
+                rows, owed = self._drop_poisoned(rows, e, ran)
                 if not rows:
                     return []
-                with self._span("serve.decode.plan"):
-                    inputs = self._decode_inputs(rows)
-                continue
+                if owed is None:
+                    with self._span("serve.decode.plan"):
+                        inputs = self._decode_inputs(rows)
+                    continue
+                tokens, finite = owed
             break
         self._note_work(sp, rows=len(rows), bucket=bucket)
         with self._span("serve.decode.commit"):
@@ -2199,6 +2269,11 @@ class InferenceEngine:
             "mp": self.mp,
             "pool_bytes_per_rank": pool_bytes_per_rank(
                 self.kv + self.kv_draft, self.mp),
+            # recurrent state (a model that has it): the slot-indexed arrays'
+            # bytes and the slots sequences hold now, beside the pool's
+            "state_bytes": sum(a.nbytes for a in self.state),
+            "state_slots_in_use": (self.slots.used_slots
+                                   if self.slots is not None else 0),
             "rejected": len(self.rejected),
             "shed": len(self.shed),
             "failed": len(self.failed),

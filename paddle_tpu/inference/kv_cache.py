@@ -328,6 +328,48 @@ class PrefixCache:
         }
 
 
+class SlotPool:
+    """Free-list allocator of per-sequence STATE slots, beside ``BlockPool``:
+    a model with recurrent state keeps, for each running sequence, ONE slot
+    of arrays indexed by slot on axis 1 (``models/falcon_h1.py``
+    ``init_state``), where its KV lives in as many blocks as its length
+    asks for. Slot 0 is the reserved NULL slot, as block 0 is the null
+    block: never handed out, and every padding row of a bucketed decode
+    batch points at it. A slot is owned by one sequence (no sharing, so no
+    ref counts); ``free`` validates before it mutates."""
+
+    def __init__(self, num_slots: int):
+        if num_slots < 2:
+            raise ValueError(f"SlotPool needs >= 2 slots (one is the "
+                             f"reserved null slot), got {num_slots}")
+        self.num_slots = num_slots
+        self._free: List[int] = list(range(num_slots - 1, 0, -1))
+        self._live: set = set()
+
+    @property
+    def used_slots(self) -> int:
+        return len(self._live)
+
+    @property
+    def free_slots(self) -> int:
+        return len(self._free)
+
+    def alloc(self) -> Optional[int]:
+        """A slot, or None (and no change) when none is free."""
+        if not self._free:
+            return None
+        slot = self._free.pop()
+        self._live.add(slot)
+        return slot
+
+    def free(self, slot: int) -> None:
+        if slot not in self._live:
+            raise BlockPoolError(f"slot {slot} is not held (double free, "
+                                 f"the null slot or out of range)")
+        self._live.remove(slot)
+        self._free.append(slot)
+
+
 def pad_table(blocks: List[int], max_nb: int) -> np.ndarray:
     """A sequence's block list as a fixed-width table row; unallocated
     slots point at the null block."""

@@ -857,8 +857,10 @@ class DeepSeekServing:
             "dsa_ctx": "dsa_ctx_tokens_total"}
 
     @staticmethod
-    def refuse(*, mp, kv_dtype, speculative, draft) -> None:
-        """Out of scope for this model, refused rather than half-done;
+    def refuse(*, mp, kv_dtype, speculative, draft, prefix_cache=False
+               ) -> None:
+        """Out of scope for this model, refused rather than half-done (the
+        prefix cache is not: nothing here depends on it);
         with indexers as without (a selection across chips, int8 or fp8
         index keys and a drafter that shares the selection are ROADMAP.md
         M5's)."""

@@ -509,3 +509,41 @@ def test_glm_steps_compile_and_fit_one_chip(one_chip, capsys):
     assert seen == {"decode, batch 1": 23, "decode, batch 64": 23,
                     "prefill chunk 512": 23,
                     "prefill chunk 512 carrying batch 64": 29}
+
+
+# -- Falcon-H1's state-space steps at published widths (PR 38) ------------------
+
+def test_falcon_h1_steps_compile_and_fit_one_chip(one_chip, capsys):
+    """The cell's own programs (``chipbench/families/falcon_h1.py``
+    ``aot_programs``): decode at the smallest and largest bucket, the
+    512-token chunk and the chunk that carries 64 rows, 10.5 GB of weights, the paged KV pool (1.13 GiB) and
+    the recurrent state (65 slots, 1.53 GiB) on one chip, BOTH kinds in
+    place (aliased: 2.66 GiB; a copy of the float32 state would show as
+    1.5 GiB among the temporaries). Five kernels a program: the scan or the
+    update, the paged attention, and the three RMSNorms (two in the layer
+    loop, the head's); seven where the rows' update and attention ride
+    beside the chunk's; prints ``memory_analysis()``."""
+    from chipbench import spec
+    cell = spec.load_cell("falconh1.serve.longreply")
+    e = cell.traffic["engine"]
+    state = (e["max_batch"] + 1) * 6 * (32 * 128 * 256 * 4 + 3 * 5120 * 2)
+    kv = e["num_blocks"] * e["block_size"] * 6 * 2 * 512 * 2
+    seen = {}
+    for name, compile_ in cell.family.aot_programs(
+            cell.model, cell.traffic, one_chip, False):
+        out = compile_()
+        ma = out.memory_analysis()
+        held = ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes
+        with capsys.disabled():
+            print(f"\n{name}: {ma}")
+        assert gib(held) < 14.5, (name, gib(held))
+        assert 12.4 < gib(ma.argument_size_in_bytes) < 12.5, name
+        # both caches whole, and a megabyte of the layout's padding
+        assert state + kv <= ma.alias_size_in_bytes \
+            < state + kv + (4 << 20), name
+        assert gib(ma.temp_size_in_bytes) < 1.0, name
+        seen[name] = out.as_text().count("tpu_custom_call")
+    assert seen == {"decode, batch 1": 5, "decode, batch 64": 5,
+                    "prefill chunk 512": 5,
+                    "prefill chunk 512 carrying batch 64": 7}

@@ -138,18 +138,40 @@ def test_fused_update_straddles_into_fresh_block(data):
     assert (kb3 == np.asarray(kcs)[1, 0, :, BS:]).all()
 
 
+def _block_diagonal(q, nkv):
+    """q [B, NH, HD] -> [B, NH, NKV * HD]: head h's query in the columns of
+    its KV head h // (NH / NKV), zeros elsewhere (what ``_paged_attend_rows``
+    hands the kernel)."""
+    b, nh, hd = q.shape
+    out = np.zeros((b, nh, nkv * hd), q.dtype)
+    for h in range(nh):
+        g = h // (nh // nkv)
+        out[:, h, g * hd:(g + 1) * hd] = q[:, h]
+    return out
+
+
+# the query rows a sequence brings: 4 dense rows over the whole width, and
+# 20 heads over 4 KV heads, a group of 5 (Falcon-H1's), block-diagonal
+@pytest.mark.parametrize("heads", [None, (20, 4)], ids=["4", "20over4"])
 @pytest.mark.parametrize("positions", [
     [127, 128, 300, 0], [0, 0, 0, 0], [383, 5, 255, 256]],
     ids=["ragged", "all_padding", "at_block_edges"])
-def test_fused_update_grid_ended_at_the_last_live_block(positions):
+def test_fused_update_grid_ended_at_the_last_live_block(positions, heads):
     """The grid ends where the walk's live steps end. Driven over every
     slot of every table instead (the walk's total set to the schedule's
     length, whose dead steps replay the last live one), the attention of
     every row and both pools come out bitwise the same: the steps left out
-    moved nothing."""
+    moved nothing. And what comes out is the dense softmax (base 2, as the
+    kernel's) over each live row's context, its new column included."""
     rng = np.random.RandomState(4)
     b = len(positions)
-    q = jnp.asarray(rng.randn(b, NH, KVD).astype(np.float32) * 0.1)
+    if heads is None:
+        q = rng.randn(b, NH, KVD).astype(np.float32) * 0.1
+    else:
+        nh, nkv = heads
+        q = _block_diagonal(
+            rng.randn(b, nh, KVD // nkv).astype(np.float32) * 0.3, nkv)
+    q = jnp.asarray(q)
     newk = jnp.asarray(rng.randn(b, KVD).astype(np.float32))
     newv = jnp.asarray(rng.randn(b, KVD).astype(np.float32))
     kp = jnp.asarray(rng.randn(L, 12, KVD, BS).astype(np.float32))
@@ -168,8 +190,22 @@ def test_fused_update_grid_ended_at_the_last_live_block(positions):
             q, k, v, kp, vp, (sched, total), 1))(
                 q, newk, newv, kp, vp, jnp.int32(total))
 
-    for whole, ended in zip(run(sched.shape[1]), run(live)):
-        np.testing.assert_array_equal(np.asarray(whole), np.asarray(ended))
+    ended = run(live)
+    for whole, end in zip(run(sched.shape[1]), ended):
+        np.testing.assert_array_equal(np.asarray(whole), np.asarray(end))
+    out, kp_u, vp_u = (np.asarray(a) for a in ended)
+    for i, pos in enumerate(positions):
+        blocks = [tables[i, j] for j in range(pos // BS + 1) if tables[i, j]]
+        if not blocks:
+            continue                        # a padding row
+        kc = np.concatenate([kp_u[1, j] for j in blocks], -1)[:, :pos + 1]
+        vc = np.concatenate([vp_u[1, j] for j in blocks], -1)[:, :pos + 1]
+        assert (kc[:, pos] == np.asarray(newk)[i]).all()
+        s = np.asarray(q)[i] @ kc                           # [NH, T]
+        p = np.exp2(s - s.max(-1, keepdims=True))
+        np.testing.assert_allclose(
+            out[i], (p / p.sum(-1, keepdims=True)) @ vc.T, atol=2e-5,
+            rtol=2e-5)
 
 
 def test_schedule_dead_steps_replay_last_live():

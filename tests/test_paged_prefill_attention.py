@@ -64,15 +64,15 @@ def dense_reference(q, kp, vp, table_row, start, scales=None):
                       vg).reshape(c, nh, hd)
 
 
-def pools(kind, seed):
+def pools(kind, seed, nkv=NKV):
     """(k_pool, v_pool, scales or None, q dtype) with every block filled:
     a dead slot or the null block holds finite garbage, as on the chip."""
     rng = np.random.default_rng(seed)
-    shape = (L, NP, NKV * HD, BS)
+    shape = (L, NP, nkv * HD, BS)
     if kind == "int8":
         draw = lambda: jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
         scale = lambda: jnp.asarray(
-            rng.uniform(0.002, 0.02, (L, NP, NKV, BS)), jnp.float32)
+            rng.uniform(0.002, 0.02, (L, NP, nkv, BS)), jnp.float32)
         return draw(), draw(), (scale(), scale()), jnp.bfloat16
     dtype = jnp.dtype(kind)
     draw = lambda: jnp.asarray(rng.normal(size=shape), dtype)
@@ -92,15 +92,18 @@ LIVES = {"one": 1, "partial": 19, "full": C}
 
 
 @pytest.mark.parametrize("kind", ["bfloat16", "int8"])
-@pytest.mark.parametrize("rep", [4, 8])
+# (KV heads, query heads a KV head): Mistral's and Yi's groups of 4 and 8,
+# and 20 heads over 4 KV heads, a group of 5 (Falcon-H1's: no power of two)
+@pytest.mark.parametrize("nkv,rep", [(NKV, 4), (NKV, 8), (4, 5)],
+                         ids=["4", "8", "20over4"])
 @pytest.mark.parametrize("n_live", list(LIVES.values()), ids=list(LIVES))
 @pytest.mark.parametrize("start", list(STARTS.values()), ids=list(STARTS))
-def test_matches_the_dense_formula_it_replaced(start, n_live, rep, kind,
+def test_matches_the_dense_formula_it_replaced(start, n_live, nkv, rep, kind,
                                                monkeypatch):
     # two query tiles of 16: at n_live 1 the second holds padding alone
     monkeypatch.setattr(pa, "PREFILL_BLOCK_Q", 16)
-    kp, vp, scales, qdt = pools(kind, seed=start + n_live)
-    nh = NKV * rep
+    kp, vp, scales, qdt = pools(kind, seed=start + n_live, nkv=nkv)
+    nh = nkv * rep
     q = jnp.asarray(np.random.default_rng(rep).normal(size=(C, nh, HD)), qdt)
     table = scattered_table(start, -(-(start + n_live) // BS))
     out = pa.paged_prefill_attention(
