@@ -22,7 +22,7 @@ compiled step family per bucketed shape —
     iteration runs the two programs above one after the other.
 
 Recompiles are therefore bounded by ``len(decode_buckets) + 2`` and
-counted (``serve.compile.*`` counters + StepMetrics.record_compile).
+counted (StepMetrics.record_compile, the flight recorder's compile events).
 
 **At most one step program is in flight between iterations.** An iteration
 plans and launches the next program before it reads the tokens of the one
@@ -67,11 +67,13 @@ Scheduling per ``step()`` iteration:
      (``serve.settle``).
 
 Telemetry: queue depth, batch occupancy, block-pool utilization and
-prefill-vs-decode time share per iteration through StepMetrics, counter
-markers on every scheduling event, and a ``serve.*`` span at every phase
-boundary of an iteration (``_span``): a profiler annotation on the device
-trace's clock while a profiler session runs, and the same name in the
-``RequestTracer`` when that is on.
+prefill-vs-decode time share per iteration through StepMetrics, the event
+log (``record_events``), a ``serve.*`` span at every phase boundary of an
+iteration (``_span``): a profiler annotation on the device trace's clock
+while a profiler session runs, and the same name in the ``RequestTracer``
+when that is on; and, always, where each request's time before its first
+token goes (``_FirstTokenWait``: queued, its prompt waiting, a chunk of its
+prompt on the device) as request-seconds in the registry.
 
 Overload + fault contract (PR 14):
 
@@ -122,7 +124,7 @@ from ..observability.histogram import LogHistogram
 from ..observability.registry import MetricsRegistry
 from ..observability.metrics import StepMetrics
 from ..observability.request_trace import RequestTracer
-from ..observability.trace import record_counter, span
+from ..observability.trace import span
 from .journal import EngineJournal, JournalCompatError, read_journal
 from .kv_cache import (BlockPool, PrefixCache, SlotPool, pad_table,
                        pool_bytes_per_rank)
@@ -144,6 +146,56 @@ ENV_SERVE_MP = "PADDLE_TPU_SERVE_MP"
 WAITING, PREFILL, RUNNING, FINISHED = "waiting", "prefill", "running", \
     "finished"
 SHED, FAILED = "shed", "failed"
+
+# where a request is before its first token (``_Seq.ttft_state``): in the
+# waiting queue; admitted, with no chunk of its prompt launched and unread
+# (another prompt's chunk has the iteration, the pool stalls its chunk, a
+# first call commits the program in flight before its chunk launches); a
+# chunk of its prompt launched and unread (its last chunk until the
+# commit that reads the first token). The registry's names, in that order
+QUEUED, PROMPT_WAIT, PROMPT_LAUNCHED = 0, 1, 2
+_FIRST_WAIT_SECONDS = (
+    ("request_queued_seconds_total",
+     "request-seconds before a first token spent in the waiting queue"),
+    ("request_prompt_wait_seconds_total",
+     "request-seconds before a first token spent admitted with no chunk "
+     "of the prompt launched and unread"),
+    ("request_prompt_launched_seconds_total",
+     "request-seconds before a first token spent with a chunk of the "
+     "prompt launched and unread"))
+
+
+class _FirstTokenWait:
+    """The engine's time from ``submit()`` to each request's first token,
+    split by where the request is (``QUEUED``, ``PROMPT_WAIT``,
+    ``PROMPT_LAUNCHED``): ``seconds[s]`` is the exact integral over time of
+    ``count[s]``, the requests in state ``s``. Both move only at the
+    engine's own transitions (``move``), on ``perf_counter`` stamps the
+    engine reads there anyway, so a scrape reads the integral as of the
+    last transition and adds nothing to it."""
+
+    __slots__ = ("count", "seconds", "t")
+
+    def __init__(self):
+        self.count = [0, 0, 0]
+        self.seconds = [0.0, 0.0, 0.0]
+        self.t = 0.0
+
+    def move(self, seq: "_Seq", to: Optional[int], t: float) -> None:
+        """``seq`` goes to state ``to`` at ``t`` (None: it leaves the three:
+        its first token, or shed, quarantined, discarded)."""
+        dt = t - self.t
+        if dt > 0:
+            n, s = self.count, self.seconds
+            s[0] += n[0] * dt
+            s[1] += n[1] * dt
+            s[2] += n[2] * dt
+            self.t = t
+        if seq.ttft_state is not None:
+            self.count[seq.ttft_state] -= 1
+        if to is not None:
+            self.count[to] += 1
+        seq.ttft_state = to
 
 
 class PoisonError(RuntimeError):
@@ -314,6 +366,9 @@ class _Seq:
         self.ahead = 0
         self.src: Optional[Tuple["_Launched", int]] = None
         self.epoch = 0
+        # before its first token: QUEUED, PROMPT_WAIT or PROMPT_LAUNCHED
+        # (``_FirstTokenWait``); None before submit() and after
+        self.ttft_state: Optional[int] = None
 
     @property
     def generated(self) -> List[int]:
@@ -580,6 +635,7 @@ class InferenceEngine:
             [*self._work_names.values(), "prefill_chunks_total",
              "prefill_chunks_with_decode_total", "steps_launched_total",
              "steps_launched_ahead_total", "rows_discarded_total"], 0)
+        self.first_wait = _FirstTokenWait()
         # unified exposition (PR 15): the SLO histograms register by
         # reference, scheduler gauges as render-time callbacks; the
         # registration order IS the metrics_snapshot() key order
@@ -742,6 +798,10 @@ class InferenceEngine:
         for name in self.model.work.values():       # the model's own counts
             r.gauge(name, fn=lambda n=name: self.work_totals[n],
                     help="counted by the model's jitted steps")
+        # the time before first tokens, by state (_FirstTokenWait). Monotonic
+        for i, (name, what) in enumerate(_FIRST_WAIT_SECONDS):
+            r.gauge(name, fn=lambda i=i: self.first_wait.seconds[i],
+                    help=what)
         if self.slots is not None:
             r.gauge("state_slots_in_use", fn=lambda: self.slots.used_slots,
                     help="recurrent-state slots held by sequences")
@@ -805,7 +865,6 @@ class InferenceEngine:
             # with its first blocks; the slot's old bytes are never read: a
             # chunk that starts at 0 begins from zeros inside the program
             seq.slot = self.slots.alloc()
-        record_counter("serve.blocks_alloc", need)
         return True
 
     def _release(self, seq: _Seq):
@@ -817,7 +876,6 @@ class InferenceEngine:
         seq.src = None
         seq.epoch += 1
         if seq.blocks:
-            record_counter("serve.blocks_free", len(seq.blocks))
             self.pool.free(seq.blocks)
             seq.blocks = []
         if seq.slot is not None:
@@ -857,7 +915,6 @@ class InferenceEngine:
                 self.pool.free([b])
                 seq.blocks[bi] = nb
                 self._cow_copies += 1
-                record_counter("serve.cow_copy")
                 self._event("cow_copy", seq.req.request_id, b, nb)
             elif self.pool.is_registered(b):
                 # sole owner, but the index still maps a prefix to this
@@ -901,7 +958,6 @@ class InferenceEngine:
         victim.n_preempted += 1
         self.waiting.insert(0, victim)
         self.preemptions += 1
-        record_counter("serve.preempt")
         self._event("evict", victim.req.request_id)
         if self.tracer is not None:
             self.tracer.evict(victim.req.request_id, time.perf_counter(),
@@ -916,7 +972,6 @@ class InferenceEngine:
             self.active.remove(seq)
         self._release(seq)
         self.finished.append(seq)
-        record_counter("serve.finish")
         if self.tracer is not None:
             self.tracer.finish(seq.req.request_id, t, len(seq.generated))
 
@@ -926,11 +981,12 @@ class InferenceEngine:
         seq.state = SHED
         seq.fail_cause = cause
         self.shed.append(seq)
-        record_counter("serve.shed")
         self._event("shed", seq.req.request_id, cause)
+        t = time.perf_counter()
+        if seq.ttft_state is not None:
+            self.first_wait.move(seq, None, t)
         if self.tracer is not None:
-            self.tracer.shed(seq.req.request_id, time.perf_counter(),
-                             cause)
+            self.tracer.shed(seq.req.request_id, t, cause)
         if self.recorder is not None:
             self.recorder.record({"iteration": self.iteration,
                                   "event": "shed",
@@ -967,11 +1023,12 @@ class InferenceEngine:
         seq.state = FAILED
         seq.fail_cause = cause
         self.failed.append(seq)
-        record_counter("serve.quarantine")
         self._event("quarantine", seq.req.request_id, cause)
+        t = time.perf_counter()
+        if seq.ttft_state is not None:
+            self.first_wait.move(seq, None, t)
         if self.tracer is not None:
-            self.tracer.quarantine(seq.req.request_id,
-                                   time.perf_counter(), cause)
+            self.tracer.quarantine(seq.req.request_id, t, cause)
         if self.recorder is not None:
             self.recorder.record({"iteration": self.iteration,
                                   "event": "quarantine",
@@ -996,7 +1053,6 @@ class InferenceEngine:
         if key not in self._compiled:
             self._compiled[key] = t_call
             self._compiled_at = self.iteration
-            record_counter(f"serve.compile.{key[0]}")
             if self.metrics is not None:
                 self.metrics.record_compile(compile_s=t_call)
             if self.recorder is not None:
@@ -1084,11 +1140,12 @@ class InferenceEngine:
         if req.request_id is None:
             req.request_id = next(self._rid)
         with self._span("serve.submit", rid=req.request_id) as sp:
-            adm = self._submit(req)
+            adm = self._submit(req, sp.t0)
             sp.note(accepted=int(adm.accepted))
         return adm
 
-    def _submit(self, req: Request) -> Admission:
+    def _submit(self, req: Request, t: float) -> Admission:
+        """``t``: when ``submit()`` was called, the request's first stamp."""
         worst = len(req.prompt) + req.max_new_tokens
         if worst > self.serve.max_seq_len:
             raise ValueError(
@@ -1116,7 +1173,6 @@ class InferenceEngine:
                 now=self._clock)
         if cause is not None:
             self.rejected.append((req, cause))
-            record_counter("serve.reject")
             self._event("reject", req.request_id, cause)
             if self.tracer is not None:
                 self.tracer.reject(req.request_id, time.perf_counter(),
@@ -1132,9 +1188,10 @@ class InferenceEngine:
         seq = _Seq(req, self._clock)
         seq.order = next(self._seqno)
         self.waiting.append(seq)
+        self.first_wait.move(seq, QUEUED, t)
         self._event("submit", req.request_id)
         if self.tracer is not None:
-            self.tracer.submit(req.request_id, time.perf_counter())
+            self.tracer.submit(req.request_id, t)
         if self._journal is not None:
             self._journal.submit(req)
         faults.inject("serve.admit.after", rid=req.request_id)
@@ -1176,8 +1233,9 @@ class InferenceEngine:
                 self._journal.finish(req.request_id)
         else:
             self.waiting.append(seq)
+            if not seq.generated:
+                self.first_wait.move(seq, QUEUED, time.perf_counter())
         self._recovered += 1
-        record_counter("serve.adopt")
         self._event("adopt", req.request_id, len(seq.generated))
 
     def load_signal(self) -> Tuple[float, float, float]:
@@ -1331,8 +1389,9 @@ class InferenceEngine:
 
     def _admit(self) -> int:
         """Admit from the head of the queue while batch and pool allow;
-        returns how many were admitted."""
-        admitted = 0
+        returns how many were admitted. One clock read stamps them all
+        (the request tracer's admissions and the first-token split's)."""
+        admitted, t = 0, None
         while self.waiting and len(self.active) < self.serve.max_batch:
             seq = self.waiting[0]
             # prefix-cache hit (PR 16): the longest chain of cached
@@ -1349,18 +1408,19 @@ class InferenceEngine:
                 break
             self.waiting.pop(0)
             seq.state = PREFILL
+            if t is None:
+                t = time.perf_counter()
+            if seq.ttft_state is not None:
+                self.first_wait.move(seq, PROMPT_WAIT, t)
             if hit:
                 self.pool.acquire(hit)
                 seq.blocks = list(hit)
                 seq.n_cached = len(hit) * self.pool.block_size
-                record_counter("serve.prefix_hit")
-                record_counter("serve.prefix_hit_tokens", seq.n_cached)
                 self._event("prefix_hit", seq.req.request_id, len(hit))
             else:
                 seq.n_cached = 0
             self.active.append(seq)
             admitted += 1
-            record_counter("serve.admit")
             self._event("admit", seq.req.request_id)
             if not seq.generated:
                 # first admission: queue wait from submit to here (a
@@ -1368,8 +1428,7 @@ class InferenceEngine:
                 # requeue span, not the SLO histogram)
                 self.slo["queue_wait"].record(self._clock - seq.arrival)
             if self.tracer is not None:
-                self.tracer.admit(seq.req.request_id, time.perf_counter(),
-                                  seq.n_preempted)
+                self.tracer.admit(seq.req.request_id, t, seq.n_preempted)
         return admitted
 
     def _prefill_chunk(self, seq: _Seq, sp: _Phase, done_out: List[_Seq],
@@ -1402,7 +1461,6 @@ class InferenceEngine:
                         * self.pool.block_size - start)
             if 1 <= headroom < n_live:
                 n_live = headroom
-                record_counter("serve.prefill_shrink")
                 self._event("prefill_shrink", rid, n_live)
             if not (self._alloc_for(seq, start + n_live)
                     and self._cow_span(seq, start, n_live)):
@@ -1482,6 +1540,10 @@ class InferenceEngine:
         if start + n_live == seq.prefill_target and not seq.generated:
             # a fresh prompt's last chunk: its token is the first new one
             seq.src = (rec, self.serve.max_batch)
+        if seq.ttft_state is not None:
+            self.first_wait.move(seq, PROMPT_LAUNCHED, launch.t0)
+        # the prompts this chunk holds back, as it goes
+        sp.note(prompts_waiting=self.first_wait.count[PROMPT_WAIT])
         if rows:
             self._note_work(sp, rows=len(rows), bucket=n_rows)
             self.work_totals["prefill_chunks_with_decode_total"] += 1
@@ -1596,8 +1658,6 @@ class InferenceEngine:
                       and self._cow_span(seq, pos, 1))
             if ok:
                 ready.append(seq)
-            else:
-                record_counter("serve.decode_stall")
         return [s for s in ready if self._decodable(s)]
 
     def _decode_inputs(self, rows: List[_Seq], bucket: Optional[int] = None):
@@ -1659,7 +1719,6 @@ class InferenceEngine:
             raise e  # not attributable to this batch
         self._quarantine(bad, e.cause)
         self._redrives += 1
-        record_counter("serve.decode_redrive")
         return [s for s in rows if s is not bad]
 
     def _decode_batch(self, sp: _Phase,
@@ -1806,6 +1865,12 @@ class InferenceEngine:
             self.tracer.prefill_chunk(rid, t0, t1, n_live,
                                       recompute=bool(seq.generated))
         seq.n_cached += n_live
+        if seq.ttft_state is not None:
+            # the last chunk read gives the first token; before it the
+            # prompt waits, unless a later chunk of it is in flight
+            self.first_wait.move(
+                seq, None if seq.n_cached == seq.prefill_target
+                else PROMPT_LAUNCHED if seq.ahead else PROMPT_WAIT, t1)
         if seq.n_cached == seq.prefill_target:
             _paid(seq, rec)
             self._prefill_done(seq, int(token), done_out)
@@ -1916,7 +1981,6 @@ class InferenceEngine:
                 # never correctness)
                 while not ok and t_cap > 1:
                     t_cap -= 1
-                    record_counter("serve.spec_shrink")
                     ok = (self._alloc_for(seq, seq.n_cached + t_cap)
                           and self._cow_span(seq, seq.n_cached, t_cap))
                 while not ok and self._evict_one(protect=seq):
@@ -1926,8 +1990,6 @@ class InferenceEngine:
                 if ok:
                     ready.append(seq)
                     caps[seq.req.request_id] = t_cap
-                else:
-                    record_counter("serve.decode_stall")
             rows = [s for s in ready if s.state == RUNNING]
             if not rows:
                 return []
@@ -2028,7 +2090,6 @@ class InferenceEngine:
                     self._quarantine(bad, e.cause)
                     rows = [s for s in rows if s is not bad]
                     self._redrives += 1
-                    record_counter("serve.decode_redrive")
                     continue
                 break
             if not rows:
@@ -2230,7 +2291,6 @@ class InferenceEngine:
         else:
             self.params = swap_fill(self.params, new_tree, "")
         self.swaps += 1
-        record_counter("serve.swap")
         stats = {
             "iteration": self.iteration,
             "swap_ms": (time.perf_counter() - t0) * 1e3,
@@ -2278,7 +2338,6 @@ class InferenceEngine:
                     # stays intact for a successor engine to re-drive
                     self.settle()
                     self._was_preempted = True
-                    record_counter("serve.preempted")
                     self._event("preempt_stop")
                     if self.recorder is not None:
                         self.recorder.dump("preemption")
@@ -2319,6 +2378,7 @@ class InferenceEngine:
         its blocks released and the program in flight forgotten, so a
         successor engine — or recover() — inherits clean state."""
         self._inflight = None
+        t = time.perf_counter()
         while self.active:
             seq = self.active.pop()
             self._release(seq)
@@ -2326,6 +2386,8 @@ class InferenceEngine:
             seq.n_cached = 0
             seq.draft_pos = 0
             self.waiting.insert(0, seq)
+            if seq.ttft_state is not None:
+                self.first_wait.move(seq, QUEUED, t)
 
     def drain(self, deterministic: bool = False,
               max_iterations: int = 100000
@@ -2340,7 +2402,6 @@ class InferenceEngine:
         rolling weight swap drains, swaps, then undrains each replica
         in turn)."""
         self._draining = True
-        record_counter("serve.drain")
         self._event("drain")
         self.run([], deterministic=deterministic,
                  max_iterations=max_iterations)
@@ -2403,8 +2464,11 @@ class InferenceEngine:
                     f"this engine's pool ({worst} tokens > "
                     f"{self.serve.num_blocks - 1} usable blocks)")
         self._inflight = None
+        t = time.perf_counter()
         for seq in itertools.chain(self.active, self.waiting):
             self._release(seq)
+            if seq.ttft_state is not None:      # the journal's copy replaces it
+                self.first_wait.move(seq, None, t)
         self.active, self.waiting = [], []
         if self.pool.used_blocks:
             raise RuntimeError(
@@ -2437,6 +2501,8 @@ class InferenceEngine:
                 n_prefinished += 1
             else:
                 self.waiting.append(seq)
+                if not seq.generated:
+                    self.first_wait.move(seq, QUEUED, t)
                 n_replayed += 1
         self._recovered = n_replayed + n_prefinished
         known = list(st.requests) + list(st.rejected)
@@ -2455,7 +2521,6 @@ class InferenceEngine:
         self._journal.recovered(self._recovered, st.torn_lines)
         for seq in self.finished[len(self.finished) - n_prefinished:]:
             self._journal.finish(seq.req.request_id)
-        record_counter("serve.recover")
         self._event("recover", self._recovered)
         return {
             "recovered": self._recovered,

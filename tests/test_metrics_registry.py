@@ -293,6 +293,10 @@ def _legacy_engine_dict(eng):
         "steps_launched_ahead_total":
             eng.work_totals["steps_launched_ahead_total"],
         "rows_discarded_total": eng.work_totals["rows_discarded_total"],
+        # the time before first tokens, by state
+        "request_queued_seconds_total": eng.first_wait.seconds[0],
+        "request_prompt_wait_seconds_total": eng.first_wait.seconds[1],
+        "request_prompt_launched_seconds_total": eng.first_wait.seconds[2],
     }
 
 
