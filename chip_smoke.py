@@ -275,7 +275,7 @@ class Server:
         engine = InferenceEngine(self.params, self.config,
                                  self.serve_config(**features))
         pool_devs = device_spread(engine.k_pool)
-        param_devs = device_spread(engine.params["layers"]["q_proj"])
+        param_devs = device_spread(engine.params["layers"]["o_proj"])
         t0 = time.perf_counter()
         stats = engine.run(requests, deterministic=True)
         wall = time.perf_counter() - t0

@@ -488,7 +488,6 @@ class InferenceEngine:
                  journal: Optional[str] = None,
                  draft_params: Optional[Dict[str, Any]] = None,
                  draft_config: Any = None):
-        self.params = params
         self.config = config
         self.serve = serve or ServeConfig()
         # tensor-parallel serving (PR 19): mp > 1 runs every jitted step
@@ -529,6 +528,12 @@ class InferenceEngine:
                           speculative=bool(spec),
                           draft=draft_params is not None,
                           prefix_cache=bool(prefix_on))
+        # the weights as the model's programs read them, relaid once here
+        # (Llama: the q/k/v stacks fused and transposed) before the cache
+        # takes its memory; under mp the model's ``place`` slices the tree
+        # as given
+        self.params, self.relaid_bytes = (
+            self._prepared(params, config) if self.mp == 1 else (params, 0))
         # the cache: a tuple of arrays, each indexed by block id on axis 1.
         # Copy-on-write, the liveness check and the pool's bytes treat it so;
         # only the model's own programs know what an array holds (Llama:
@@ -573,9 +578,13 @@ class InferenceEngine:
             if draft_params is None:
                 # default draft: the base model truncated to its first
                 # layer, sharing embedding/head weights by reference
-                draft_params, draft_config = self.model.draft(params, config)
+                draft_params, draft_config = self.model.draft(self.params,
+                                                              config)
             elif draft_config is None:
                 raise ValueError("draft_params given without draft_config")
+            elif self.mp == 1:
+                draft_params, n = self._prepared(draft_params, draft_config)
+                self.relaid_bytes += n
             self.draft_params = draft_params
             self.draft_config = draft_config
             self._draft_frozen = self.model.freeze(draft_config)
@@ -696,6 +705,15 @@ class InferenceEngine:
         # router's rolling swap both flip this)
         self._draining = False
 
+    def _prepared(self, params, config) -> Tuple[Any, int]:
+        """``params`` as the model's programs read them (its ``prepare``),
+        and the bytes of the leaves it relaid to get there: a leaf it kept
+        is the caller's own array."""
+        out = self.model.prepare(params, config)
+        kept = {id(a) for a in jax.tree_util.tree_leaves(params)}
+        return out, sum(a.nbytes for a in jax.tree_util.tree_leaves(out)
+                        if id(a) not in kept)
+
     def _step_fn(self, kind: str, frozen, quant: Optional[bool] = None,
                  board: bool = True):
         """The model's jitted program of one kind (prefill, decode, verify,
@@ -802,6 +820,10 @@ class InferenceEngine:
         for i, (name, what) in enumerate(_FIRST_WAIT_SECONDS):
             r.gauge(name, fn=lambda i=i: self.first_wait.seconds[i],
                     help=what)
+        r.gauge("weights_relaid_bytes", fn=lambda: self.relaid_bytes,
+                help="bytes of weight leaves the model relaid into the "
+                     "layout its programs read, at construction or the "
+                     "last swap")
         if self.slots is not None:
             r.gauge("state_slots_in_use", fn=lambda: self.slots.used_slots,
                     help="recurrent-state slots held by sequences")
@@ -2185,12 +2207,14 @@ class InferenceEngine:
 
         `source` is a checkpoint directory (a ``save_state_dict`` dir or a
         CheckpointManager root, whose newest complete checkpoint is used)
-        or an in-memory param pytree. The new tree must match the current
-        one exactly — same structure, shapes, dtypes (same compiled step
-        family, so no recompile). Each leaf is placed onto the CURRENT
-        leaf's sharding and rebound in place, one leaf at a time (peak
-        extra memory = one weight); the KV pools, block tables and all
-        scheduler state are untouched.
+        or an in-memory param pytree. The new tree, once the model has
+        relaid it as at construction (``prepare``: a Llama training-layout
+        tree gets its fused q/k/v stacks, all layers' at once), must match
+        the current one exactly — same structure, shapes, dtypes (same
+        compiled step family, so no recompile). Each leaf is placed onto
+        the CURRENT leaf's sharding and rebound in place, one leaf at a
+        time (peak extra memory = one weight beside the relaid stacks); the
+        KV pools, block tables and all scheduler state are untouched.
 
         With ``at_iteration`` the swap is deferred to that iteration's
         boundary — the safe drain point: the previous decode has synced
@@ -2244,6 +2268,9 @@ class InferenceEngine:
         faults.inject("serve.swap.before", iteration=self.iteration)
         t0 = time.perf_counter()
         new_tree, path = self._resolve_swap_source(source)
+        relaid = 0
+        if self.mp == 1:    # a training-layout tree swaps into the prepared
+            new_tree, relaid = self._prepared(new_tree, self.config)
         n_leaves = [0]
 
         def swap_fill(target, saved, leaf_path):
@@ -2290,6 +2317,7 @@ class InferenceEngine:
             swap_fill(self.params, new_tree, "")
         else:
             self.params = swap_fill(self.params, new_tree, "")
+        self.relaid_bytes = relaid
         self.swaps += 1
         stats = {
             "iteration": self.iteration,

@@ -78,9 +78,10 @@ class FleetRouter:
     ``policy="affinity"`` (default) is the two-level prefix-affinity /
     load dispatch; ``policy="random"`` routes uniformly from a seeded
     RNG — the A/B baseline the bench compares affinity hit rate
-    against. All replicas share one weight tree (at mp=1 the engines
-    hold it by reference); each owns its KV pools, scheduler state and,
-    with ``journal_dir``, its own ``replica_<i>.jsonl`` journal."""
+    against. All replicas share one weight tree (at mp=1 the first
+    replica's, relaid as the programs read it, by reference); each owns
+    its KV pools, scheduler state and, with ``journal_dir``, its own
+    ``replica_<i>.jsonl`` journal."""
 
     def __init__(self, params: Dict[str, Any], config,
                  serve: Optional[ServeConfig] = None,
@@ -109,9 +110,14 @@ class FleetRouter:
         for i in range(self.n):
             jp = (os.path.join(self.journal_dir, f"replica_{i}.jsonl")
                   if self.journal_dir else None)
-            self.engines.append(InferenceEngine(
-                params, config, serve, journal=jp,
-                record_events=record_events, **(engine_kw or {})))
+            eng = InferenceEngine(params, config, serve, journal=jp,
+                                  record_events=record_events,
+                                  **(engine_kw or {}))
+            self.engines.append(eng)
+            if eng.mesh is None:
+                # the weights as the programs read them, relaid by the
+                # first replica alone and held by every one
+                params = eng.params
         self.alive: List[bool] = [True] * self.n
         self.dead: List[int] = []
         # router-level steering (rolling swap): replicas here stay live

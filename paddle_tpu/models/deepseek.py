@@ -888,6 +888,10 @@ class DeepSeekServing:
         return config           # frozen and hashable as it is
 
     @staticmethod
+    def prepare(params, config):
+        return params           # the programs read the leaves as stored
+
+    @staticmethod
     def init_cache(config, num_blocks, block_size, kv_dtype):
         pools = (init_latent_pool(config, num_blocks, block_size),)
         if config.indexer_types:

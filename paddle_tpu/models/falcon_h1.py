@@ -560,6 +560,10 @@ class FalconH1Serving:
         return config           # frozen and hashable as it is
 
     @staticmethod
+    def prepare(params, config):
+        return params           # the programs read the leaves as stored
+
+    @staticmethod
     def init_cache(config, num_blocks, block_size, kv_dtype):
         return init_paged_kv_pool(config, num_blocks, block_size)
 

@@ -228,6 +228,44 @@ def _mat_out_dim(w):
     return w.shape[-1]
 
 
+_QKV = ("q_proj", "k_proj", "v_proj")
+
+
+def _qkv_decode_form(qkv, ratio):
+    """The q/k/v stacks ``{"q_proj", "k_proj", "v_proj"}`` (plain [L, in,
+    out], int8 ``{"w", "s"}`` or already ``{"wT"[, "s"]}``) laid out as the
+    decode matmuls read them: ``{"qkv_proj": {"wT": [L, (nh + 2 nkv) hd,
+    in][, "s"]}}``, or the three as ``{"wT"[, "s"]}`` where the shapes do
+    not split by the config's head ``ratio`` (nh // nkv)."""
+    q_out = _mat_out_dim(qkv["q_proj"])
+    k_out = _mat_out_dim(qkv["k_proj"])
+    # the fused split site (_paged_qkv, llama_decode_step) re-derives
+    # nh/nkv from config, so only fuse when the actual weight shapes agree
+    # with the config's head ratio — mismatched params (e.g. pruned heads)
+    # keep the unfused three-matmul path instead of silently mis-splitting
+    if q_out != k_out * ratio or k_out != _mat_out_dim(qkv["v_proj"]):
+        out = {}
+        for name, w in qkv.items():
+            if isinstance(w, dict):
+                out[name] = w if "wT" in w else {
+                    "wT": jnp.swapaxes(w["w"], -1, -2), "s": w["s"]}
+            else:
+                out[name] = {"wT": jnp.swapaxes(w, -1, -2)}
+        return out
+    ws = [qkv[n] for n in _QKV]
+    if isinstance(ws[0], dict):
+        return {"qkv_proj": {
+            "wT": jnp.concatenate(
+                [jnp.swapaxes(w["w"], -1, -2) for w in ws], axis=-2),
+            "s": jnp.concatenate([w["s"] for w in ws], axis=-1)}}
+    return {"qkv_proj": {"wT": jnp.concatenate(
+        [jnp.swapaxes(w, -1, -2) for w in ws], axis=-2)}}
+
+
+# one jitted relayout, no donation: the caller may still hold the stacks
+_relay_qkv = jax.jit(_qkv_decode_form, static_argnums=(1,))
+
+
 def _decode_weights(params, config):
     """Transpose the stacked q/k/v projections to [L, out, in] ONCE per
     generate call (outside the token scan). XLA's chosen operand layout
@@ -237,6 +275,8 @@ def _decode_weights(params, config):
     b8 — constant_dynamic-slice fusions with transposed output layout).
     The transposed stack slices straight into the wanted layout; the
     one-time transpose cost amortizes over the whole continuation.
+    ``InferenceEngine`` applies the same form itself, once, when it takes
+    the weights (``LlamaServing.prepare``).
 
     HBM note (advisor r4): when this runs INSIDE a generate/sample jit
     the raw q/k/v stacks remain live as jit inputs while the fused copy
@@ -246,39 +286,19 @@ def _decode_weights(params, config):
     layers = dict(params["layers"])
     if "qkv_proj" in layers:
         return params  # already prepared
-    # the fused split site (llama_decode_step) re-derives nh/nkv from
-    # config, so only fuse when the actual weight shapes agree with the
-    # config's head ratio — mismatched params (e.g. pruned heads) keep
-    # the unfused three-matmul path instead of silently mis-splitting
-    q_out = _mat_out_dim(layers["q_proj"])
-    k_out = _mat_out_dim(layers["k_proj"])
     ratio = config.num_attention_heads // config.num_key_value_heads
-    if q_out != k_out * ratio or k_out != _mat_out_dim(layers["v_proj"]):
-        for name in ("q_proj", "k_proj", "v_proj"):
-            w = layers[name]
-            if isinstance(w, dict):
-                if "wT" in w:
-                    continue
-                layers[name] = {"wT": jnp.swapaxes(w["w"], -1, -2),
-                                "s": w["s"]}
-            else:
-                layers[name] = {"wT": jnp.swapaxes(w, -1, -2)}
-        out = dict(params)
-        out["layers"] = layers
-        return out
-    ws = [layers.pop(n) for n in ("q_proj", "k_proj", "v_proj")]
-    if isinstance(ws[0], dict):
-        layers["qkv_proj"] = {
-            "wT": jnp.concatenate(
-                [jnp.swapaxes(w["w"], -1, -2) for w in ws], axis=-2),
-            "s": jnp.concatenate([w["s"] for w in ws], axis=-1),
-        }
-    else:
-        layers["qkv_proj"] = {"wT": jnp.concatenate(
-            [jnp.swapaxes(w, -1, -2) for w in ws], axis=-2)}
+    layers.update(_qkv_decode_form({n: layers.pop(n) for n in _QKV}, ratio))
     out = dict(params)
     out["layers"] = layers
     return out
+
+
+def _in_decode_form(layers) -> bool:
+    """Whether a layer tree is in ``_decode_weights``' form already: the
+    fused ``qkv_proj``, or the unfused ``wT`` leaves that shape-mismatched
+    (e.g. pruned-head) params take."""
+    return "qkv_proj" in layers or (isinstance(layers.get("q_proj"), dict)
+                                    and "wT" in layers["q_proj"])
 
 
 def prepare_decode_params(params, config):
@@ -290,13 +310,12 @@ def prepare_decode_params(params, config):
     (advisor r4). After ``params = prepare_decode_params(params, cfg)``
     only the fused copy is resident (pass-through weights alias via
     donation), and every subsequent generate call skips the re-derive.
-    Idempotent: prepared params return unchanged (both the fused
-    qkv_proj form and the unfused wT form that shape-mismatched — e.g.
-    pruned-head — params take)."""
-    layers = params["layers"]
-    if "qkv_proj" in layers or (
-            isinstance(layers.get("q_proj"), dict)
-            and "wT" in layers["q_proj"]):
+    ``InferenceEngine`` relays a training-layout tree itself and, since the
+    caller keeps the raw tree, holds the q/k/v stacks twice; handing it a
+    tree prepared here avoids that copy. Idempotent: prepared params return
+    unchanged (both the fused qkv_proj form and the unfused wT form that
+    shape-mismatched — e.g. pruned-head — params take)."""
+    if _in_decode_form(params["layers"]):
         return params
     fn = jax.jit(lambda p: _decode_weights(p, config), donate_argnums=(0,))
     return fn(params)
@@ -1388,7 +1407,8 @@ def make_draft_model(params, config: LlamaConfig, num_layers: int = 1):
     dcfg = dataclasses.replace(config, num_hidden_layers=dl)
     dparams = {
         "embed": params["embed"],
-        "layers": {k: v[:dl] for k, v in params["layers"].items()},
+        "layers": jax.tree_util.tree_map(lambda a: a[:dl],
+                                         params["layers"]),
         "final_norm": params["final_norm"],
     }
     if "lm_head" in params:
@@ -1658,8 +1678,10 @@ class LlamaServing:
     programs it has. ``work`` names the registry counters a model adds to
     the engine's ``_WORK_TOTALS`` (none here). A model that does not
     ``refuse`` them also answers ``place`` (``mp > 1``) and ``draft``
-    (speculation). This one is Llama's: every program is
-    ``_jitted_paged_step``'s; an int8 cache is (k, v, k_scale, v_scale)."""
+    (speculation). ``prepare(params, config)`` gives the tree the programs
+    run on (on one chip; ``place`` takes the tree as given). This one is
+    Llama's: every program is ``_jitted_paged_step``'s; an int8 cache is
+    (k, v, k_scale, v_scale)."""
 
     work: Dict[str, str] = {}
 
@@ -1679,6 +1701,25 @@ class LlamaServing:
         if kv_dtype == "int8":
             kv += init_paged_kv_scales(config, num_blocks, block_size)
         return kv
+
+    @staticmethod
+    def prepare(params, config: LlamaConfig):
+        """The tree the paged programs run on, made once when the engine
+        takes the weights: ``_decode_weights``' form, the q/k/v stacks
+        fused and transposed to ``qkv_proj: {"wT": [L, (nh + 2 nkv) hd,
+        H]}`` (three ``wT`` leaves where the head ratio does not split;
+        int8 ``{"w", "s"}`` become ``{"wT", "s"}``) in one jitted call that
+        donates nothing, every other leaf the caller's own array. Sliced out
+        of an [L, in, out] stack, each layer's projection was relaid into
+        the layout its matmul reads in every program. A tree that is
+        prepared already, or lacks one of the three, comes back as is."""
+        layers = params.get("layers", {}) if isinstance(params, dict) else {}
+        if _in_decode_form(layers) or not all(n in layers for n in _QKV):
+            return params
+        ratio = config.num_attention_heads // config.num_key_value_heads
+        rest = {k: v for k, v in layers.items() if k not in _QKV}
+        return dict(params, layers={
+            **rest, **_relay_qkv({n: layers[n] for n in _QKV}, ratio)})
 
     @staticmethod
     def step_fn(kind: str, frozen, quant: bool, mesh, board: bool = True):

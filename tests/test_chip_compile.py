@@ -15,6 +15,7 @@ this file. Keep these tests in this one file for the same reason.
 """
 import dataclasses
 import os
+import re
 import sys
 
 import jax
@@ -240,6 +241,60 @@ def test_tp_prefill_compiles_on_four_chips(topo, quant):
         on((), i32))
     assert "tpu_custom_call" in compiled.as_text()
     assert gib(compiled.memory_analysis().temp_size_in_bytes) < 0.1
+
+
+# -- Llama's programs on the tree the engine runs on (Mistral's GQA widths) ----
+
+MISTRAL = dataclasses.replace(L.llama_7b(), num_hidden_layers=4,
+                              num_key_value_heads=8, intermediate_size=14336,
+                              vocab_size=32768)
+_RELAYOUT = re.compile(
+    r"%(\S+) = bf16\[1,(\d+),(\d+)\]\{[^}]*\} (copy|fusion)\(")
+
+
+def weight_relayouts(text, layers):
+    """The ops of an optimized program that slice a layer's projection
+    weight into a buffer of its own or copy it into another layout: a
+    ``copy`` or a ``constant_dynamic-slice_fusion`` whose result is one
+    layer of a stacked [L, a, b] leaf (either way round)."""
+    shapes = {tuple(a.shape[1:]) for a in jax.tree_util.tree_leaves(layers)
+              if a.ndim == 3}
+    shapes |= {s[::-1] for s in shapes}
+    return [m.group(0) for m in _RELAYOUT.finditer(text)
+            if (int(m.group(2)), int(m.group(3))) in shapes
+            and (m.group(4) == "copy"
+                 or m.group(1).startswith("constant_dynamic-slice_fusion"))]
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill+decode"])
+@pytest.mark.parametrize("tree", ["prepared", "training-layout"])
+def test_llama_programs_relay_no_weight_on_the_engines_tree(sds, kind, tree):
+    """Sliced out of an [L, in, out] stack, q/k/v's layer is copied into
+    the in-dim-minor layout its matmul reads in every program (chat's
+    `copy.23`, `bf16[1,4096,4096]`); the engine's tree
+    (``LlamaServing.prepare``: ``qkv_proj`` as [L, out, in]) slices into
+    it directly. The training-layout tree is the witness that the search
+    sees the copy."""
+    raw = jax.eval_shape(lambda: L.init_llama_params(MISTRAL, 0))
+    params = raw if tree == "training-layout" else jax.eval_shape(
+        lambda p: L.LlamaServing.prepare(p, MISTRAL), raw)
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), params)
+    pool = sds((4, POOL_BLOCKS, 8 * 128, BS), bf16)
+    rows = (sds((BATCH, MAX_NB), i32), sds((BATCH,), i32),
+            sds((BATCH,), i32))
+    chunk = (sds((MAX_NB,), i32), sds((), i32),
+             sds((FULL.prefill_chunk,), i32), sds((), i32))
+    board = (sds((BATCH + 1,), i32), sds((BATCH,), i32))
+    fn = L.LlamaServing.step_fn(kind, L._freeze_config(MISTRAL), False, None)
+    text = compile_for_chip(
+        fn, params, pool, pool,
+        *({"decode": rows}.get(kind, chunk + rows)), *board).as_text()
+    found = weight_relayouts(text, params["layers"])
+    if tree == "prepared":
+        assert found == []
+    else:
+        assert any("bf16[1,4096,4096]" in f and " copy(" in f
+                   for f in found), found
 
 
 # -- dense flash attention and the train step ---------------------------------

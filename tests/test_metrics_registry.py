@@ -297,6 +297,8 @@ def _legacy_engine_dict(eng):
         "request_queued_seconds_total": eng.first_wait.seconds[0],
         "request_prompt_wait_seconds_total": eng.first_wait.seconds[1],
         "request_prompt_launched_seconds_total": eng.first_wait.seconds[2],
+        # the bytes the model relaid when it took the weights
+        "weights_relaid_bytes": eng.relaid_bytes,
     }
 
 

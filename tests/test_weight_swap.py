@@ -138,7 +138,8 @@ def test_swap_from_checkpoint_dir_and_manager_root(model, tmp_path):
     sl.save_state_dict(_copy(params2), ck)
     eng = InferenceEngine(_copy(params), cfg, _serve())
     stats = eng.swap_weights(ck)
-    assert stats["n_leaves"] == len(jax.tree_util.tree_leaves(params2))
+    # the engine's leaves: the model's prepared tree (fused q/k/v)
+    assert stats["n_leaves"] == len(jax.tree_util.tree_leaves(eng.params))
     assert stats["source"] == os.path.abspath(ck)
     eng.run([Request(prompt, max_new_tokens=4, arrival=0.0)],
             deterministic=True)
