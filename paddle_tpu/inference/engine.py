@@ -462,11 +462,13 @@ def _serving_for(config):
     """The serving side of ``config``'s model: what a model hands the engine
     lives beside the model (see ``models/llama.py`` ``LlamaServing``), and
     this module imports nothing else of ``paddle_tpu.models``."""
-    from ..models import deepseek, falcon_h1, llama
+    from ..models import deepseek, falcon_h1, laguna, llama
     if isinstance(config, deepseek.DeepSeekConfig):
         return deepseek.DeepSeekServing
     if isinstance(config, falcon_h1.FalconH1Config):
         return falcon_h1.FalconH1Serving
+    if isinstance(config, laguna.LagunaConfig):
+        return laguna.LagunaServing
     return llama.LlamaServing
 
 
@@ -542,18 +544,19 @@ class InferenceEngine:
             config, self.serve.num_blocks, self.serve.block_size,
             self.kv_dtype)
         self.kv_draft: Tuple = ()
-        # recurrent state, for a model that has it (``init_state``): a
-        # second tuple of arrays, each indexed by SLOT on axis 1, one slot a
-        # running sequence and slot 0 the null slot. Slots are given and
-        # freed with the blocks; copy-on-write never touches them (nothing
-        # shares a state); the steps take them after the cache, and a slot
-        # a row as their last input
+        # per-sequence state, for a model that has it (``init_state``:
+        # recurrent state, window layers' rings): a second tuple of arrays,
+        # each indexed by SLOT on axis 1, one slot a running sequence and
+        # slot 0 the null slot, shaped by the model and the serving geometry.
+        # Slots are given and freed with the blocks; copy-on-write never
+        # touches them (nothing shares a state); the steps take them after
+        # the cache, and a slot a row as their last input
         init_state = getattr(self.model, "init_state", None)
         self.slots: Optional[SlotPool] = None
         self.state: Tuple = ()
         if init_state is not None:
             self.slots = SlotPool(self.serve.max_batch + 1)
-            self.state = init_state(config, self.slots.num_slots)
+            self.state = init_state(config, self.slots.num_slots, self.serve)
         self.cache: Optional[PrefixCache] = \
             PrefixCache(self.pool) if prefix_on else None
         self._cow_copies = 0
@@ -827,6 +830,8 @@ class InferenceEngine:
         if self.slots is not None:
             r.gauge("state_slots_in_use", fn=lambda: self.slots.used_slots,
                     help="recurrent-state slots held by sequences")
+        for name, (what, read) in getattr(self.model, "gauges", {}).items():
+            r.gauge(name, fn=lambda read=read: read(self), help=what)
         # PR 16 capacity gauges, only when the cache is live: the
         # default exposition stays byte-compatible with the pre-PR-15
         # legacy dict (pinned by the metrics-registry golden test)

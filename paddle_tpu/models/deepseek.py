@@ -76,6 +76,7 @@ class DeepSeekConfig:
     n_routed_experts: int = 256             # what the router scores
     n_shared_experts: int = 1
     num_experts_per_tok: int = 8
+    scoring_func: str = "sigmoid"           # route(): "sigmoid" or "softmax"
     n_group: int = 8
     topk_group: int = 4
     routed_scaling_factor: float = 2.5
@@ -399,17 +400,23 @@ def swiglu(x, gate, up, down):
                    preferred_element_type=jnp.float32)
 
 
-def route(y, router, bias, c: DeepSeekConfig):
-    """y [T, H] -> (idx [T, k] i32 over ALL routed experts, w [T, k] f32):
-    sigmoid scores in float32, selection on score + bias limited to the
-    best groups, weights from the scores without the bias, normalised over
-    all selected and scaled."""
-    s = jax.nn.sigmoid(jnp.dot(y.astype(jnp.float32),
-                               router.astype(jnp.float32),
-                               precision=lax.Precision.HIGHEST))
-    sel = s + bias.astype(jnp.float32)
-    _, idx = lax.top_k(_best_groups(sel, c), c.num_experts_per_tok)
-    w = jnp.take_along_axis(s, idx, axis=1)
+def route(y, router, bias, c):
+    """y [T, H] -> (idx [T, k] i32 over ALL routed experts, w [T, k] f32),
+    by ``c.scoring_func``: ``sigmoid`` (DeepSeek's) scores in float32,
+    selection on score + bias limited to the best groups, weights from the
+    scores without the bias; ``softmax`` (Qwen-MoE's; no bias, no groups)
+    the top k of the softmax over all experts. Either way the weights are
+    normalised over all selected and scaled."""
+    logits = jnp.dot(y.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    if c.scoring_func == "softmax":
+        w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1),
+                           c.num_experts_per_tok)
+    else:
+        s = jax.nn.sigmoid(logits)
+        sel = s + bias.astype(jnp.float32)
+        _, idx = lax.top_k(_best_groups(sel, c), c.num_experts_per_tok)
+        w = jnp.take_along_axis(s, idx, axis=1)
     w = w / jnp.sum(w, axis=-1, keepdims=True) * c.routed_scaling_factor
     return idx.astype(jnp.int32), w
 
@@ -438,11 +445,12 @@ def _expert_tile(t: int) -> int:
     return 128 if t >= 128 else 16
 
 
-def moe_ffn(y, live, p, experts, slot, c: DeepSeekConfig):
+def moe_ffn(y, live, p, experts, slot, c):
     """y [T, H] f32, the normed residual stream: the router reads it as it
     is, the experts after rounding to the weights' dtype; live [T] bool
-    (padding tokens route nowhere); p the layer's
-    router, bias and shared expert; ``experts`` the stacked routed experts
+    (padding tokens route nowhere); p the layer's router, bias (none under
+    softmax routing) and shared expert; ``c`` a config with
+    DeepSeekConfig's routing fields; ``experts`` the stacked routed experts
     of ALL expert layers ({gate, up: [n, El, H, I], down: [n, El, I, H]})
     and ``slot`` this layer's index in them (the stack goes to the grouped
     matmul whole, so no layer's experts are sliced out and copied).
@@ -453,7 +461,7 @@ def moe_ffn(y, live, p, experts, slot, c: DeepSeekConfig):
     k, el = c.num_experts_per_tok, c.n_local_experts
     tile = _expert_tile(t)
     with jax.named_scope("moe.route"):
-        idx, w = route(y, p["router"], p["router_bias"], c)
+        idx, w = route(y, p["router"], p.get("router_bias"), c)
         y = y.astype(c.dtype)
         local = idx - c.expert_offset
         held = (local >= 0) & (local < el) & live[:, None]     # [T, k]

@@ -567,7 +567,9 @@ class FalconH1Serving:
     def init_cache(config, num_blocks, block_size, kv_dtype):
         return init_paged_kv_pool(config, num_blocks, block_size)
 
-    init_state = staticmethod(init_state)
+    @staticmethod
+    def init_state(config, num_slots, serve):
+        return init_state(config, num_slots)
 
     @staticmethod
     def step_fn(kind, frozen, quant, mesh, board=True):

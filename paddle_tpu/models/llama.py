@@ -1116,36 +1116,47 @@ def _paged_attend_rows(q, k, v, pools, walk, layer, config):
     and attends over the row's table, updating ``pools`` ((k, v) or the
     int8 (k, v, k_scale, v_scale)) in place through input_output_aliases.
     Returns (rows [B, NH*hd], pools)."""
-    from ..ops.paged_attention import (_LOG2E, kv_quant_columns,
-                                       paged_attend_update,
+    from ..ops.paged_attention import (kv_quant_columns, paged_attend_update,
                                        paged_attend_update_quant)
-    b, nh, hd = q.shape
     nkv = k.shape[1]
-    kvd, rep = nkv * hd, nh // nkv
-    qg = q.reshape(b, nkv, rep, hd)
-    # block-diagonal q (see llama_decode_step): the paged kernel
-    # reads whole [KVD, bs] slab fragments per sequence
-    eye = jnp.eye(nkv, dtype=qg.dtype)
-    q_bd = jnp.einsum("bgrd,ge->bgred", qg, eye).reshape(b, nh, kvd)
-    qs = (q_bd.astype(jnp.float32)
-          * (_LOG2E / (hd ** 0.5))).astype(q_bd.dtype)
-    k, v = k.reshape(b, kvd), v.reshape(b, kvd)
-    if len(pools) == 2:
-        kp, vp = pools
-        attn_full, *pools = paged_attend_update(
-            qs, k.astype(kp.dtype), v.astype(vp.dtype), kp, vp, walk, layer)
-    else:
+
+    def kernel(qs, k, v):
+        if len(pools) == 2:
+            kp, vp = pools
+            return paged_attend_update(
+                qs, k.astype(kp.dtype), v.astype(vp.dtype), kp, vp, walk,
+                layer)
         # each new column is quantized per-kv-head OUTSIDE the kernel (the
         # bytes a prefill of the same tokens writes); the fused update
         # merges bytes + scales in place
         nk_q, nk_s = kv_quant_columns(k, nkv)
         nv_q, nv_s = kv_quant_columns(v, nkv)
-        attn_full, *pools = paged_attend_update_quant(
-            qs, nk_q, nv_q, nk_s, nv_s, *pools, walk, layer)
+        return paged_attend_update_quant(qs, nk_q, nv_q, nk_s, nv_s, *pools,
+                                         walk, layer)
+    return rows_attend(q, k, v, kernel, config.dtype)
+
+
+def rows_attend(q, k, v, kernel, dtype):
+    """One new token a row through a fused update kernel: q [B, NH, hd], k
+    and v [B, NKV, hd] (roped) in the kernel's layout (block-diagonal q
+    [B, NH, NKV*hd] pre-scaled for its base-2 softmax: the kernel reads
+    whole [KVD, bs] slab fragments per sequence, see llama_decode_step;
+    k and v [B, KVD]); ``kernel(qs, k, v) -> (attn [B, NH, KVD] f32,
+    *caches)``. Returns (rows [B, NH*hd] in ``dtype``, caches)."""
+    from ..ops.paged_attention import _LOG2E
+    b, nh, hd = q.shape
+    nkv = k.shape[1]
+    kvd, rep = nkv * hd, nh // nkv
+    qg = q.reshape(b, nkv, rep, hd)
+    eye = jnp.eye(nkv, dtype=qg.dtype)
+    q_bd = jnp.einsum("bgrd,ge->bgred", qg, eye).reshape(b, nh, kvd)
+    qs = (q_bd.astype(jnp.float32)
+          * (_LOG2E / (hd ** 0.5))).astype(q_bd.dtype)
+    attn_full, *caches = kernel(qs, k.reshape(b, kvd), v.reshape(b, kvd))
     attn = jnp.einsum("bgred,ge->bgrd",
                       attn_full.reshape(b, nkv, rep, nkv, hd),
-                      eye.astype(attn_full.dtype)).astype(config.dtype)
-    return attn.reshape(b, nh * hd), tuple(pools)
+                      eye.astype(attn_full.dtype)).astype(dtype)
+    return attn.reshape(b, nh * hd), tuple(caches)
 
 
 def _paged_layer_tail(p, h, ao, config, tp):

@@ -31,6 +31,11 @@ paged_prefill_attention (PR 29) is the chunked prefill's read of the same
 pools: one sequence, a chunk of queries, causal, a grid of (query tile,
 table slot) bounded by the live context; see its section below.
 
+window_attend_update and window_prefill_attention are the same two kernels
+for a sliding-window layer whose keys live in a ring of blocks a sequence:
+the walk starts at the window's first block and the window's lower edge is
+masked; see their section below.
+
 Layouts:
   q_bd    [B, NH, KVD]          pre-scaled block-diagonal queries
   pools   [L, NP, KVD, bs]      k and v block pools, time in lanes
@@ -235,7 +240,8 @@ def paged_attention(q_bd, k_pool, v_pool, tables, lengths, layer, *,
 
 def _paged_update_kernel(lp_ref, sc_ref, q_ref, nk_ref, nv_ref,
                          k_ref, v_ref, o_ref, ko_ref, vo_ref,
-                         l_s, b_s, acc_s, *, block_size, online=False):
+                         l_s, b_s, acc_s, *, block_size, online=False,
+                         window=None):
     j = pl.program_id(0)
     pos = sc_ref[_POS, j]
     start = sc_ref[_START, j]
@@ -265,7 +271,10 @@ def _paged_update_kernel(lp_ref, sc_ref, q_ref, nk_ref, nv_ref,
             q_ref[0], k_at, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)    # [NH, bs]
         t = start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(t <= pos, s, jnp.float32(-1e30))
+        keep = t <= pos
+        if window is not None:      # the lower edge: pos - t < window
+            keep = jnp.logical_and(keep, t > pos - window)
+        s = jnp.where(keep, s, jnp.float32(-1e30))
         alpha = None
         if is_first:
             bvec = s.max(axis=-1, keepdims=True)
@@ -317,7 +326,7 @@ def _paged_update_kernel(lp_ref, sc_ref, q_ref, nk_ref, nv_ref,
         o_ref[0] = acc_s[...] / jnp.maximum(l_s[:, :1], jnp.float32(1e-30))
 
 
-def paged_update_walk(tables, positions, block_size):
+def paged_update_walk(tables, positions, block_size, n_steps=None):
     """What the fused update kernels walk for one decode batch, the same in
     every layer, so a step makes it once before its layer loop: (the flat
     schedule over every slot of every table, its live total as a traced
@@ -327,9 +336,13 @@ def paged_update_walk(tables, positions, block_size):
     walk's last live tile even when it was freshly allocated; the kernels'
     grid ends at the live total (a dynamic grid bound: steps past it would
     replay the last live one and move nothing, at about a quarter of a
-    microsecond each)."""
+    microsecond each). ``n_steps`` bounds the schedule's length where
+    B x max_nb steps would not fit the kernel's scalar memory (1 MiB, a
+    step's 9 fields padded to 16): rows whose tables share no block walk
+    at most the pool's blocks, and a padding row its one null block."""
     B, max_nb = tables.shape
-    sched = paged_schedule(positions + 1, tables, B * max_nb, block_size)
+    sched = paged_schedule(positions + 1, tables, n_steps or B * max_nb,
+                           block_size)
     return sched, jnp.sum(sched[_LIVE], dtype=jnp.int32)
 
 
@@ -342,6 +355,15 @@ def paged_attend_update(q_bd, new_k, new_v, k_pool, v_pool, walk, layer):
     batch's ``paged_update_walk``. Every row writes — padding rows must
     point their tables at the reserved null block 0 with positions 0.
     Returns (attn [B, NH, KVD] f32, k_pool, v_pool)."""
+    return _update_call(q_bd, new_k, new_v, k_pool, v_pool, walk, layer,
+                        None)
+
+
+def _update_call(q_bd, new_k, new_v, k_pool, v_pool, walk, layer, window):
+    """The fused update kernel's call over pools [L, NP, KVD, bs] and a
+    flat walk; ``window`` (None: causal alone) masks keys more than
+    ``window - 1`` positions behind each row's new token. One cost site
+    for both: the window's kernel is this one with a lower mask."""
     b, nh, kvd = q_bd.shape
     bs = k_pool.shape[-1]
     it = jnp.dtype(k_pool.dtype).itemsize
@@ -365,7 +387,8 @@ def paged_attend_update(q_bd, new_k, new_v, k_pool, v_pool, walk, layer):
         return (lp_ref[0], sc_ref[_UBLK, j], 0, 0)
 
     kernel = functools.partial(_paged_update_kernel, block_size=bs,
-                               online=softmax_mode() == "online")
+                               online=softmax_mode() == "online",
+                               window=window)
     with _mosaic_ctx():
         out, kp, vp = pl.pallas_call(
             kernel,
@@ -823,8 +846,9 @@ def paged_attention_xla(q, k_pool, v_pool, tables, lengths, layer,
 # its last live block (no DMA) and skip their compute; a tile of padding
 # queries alone (past n_live) visits nothing and writes zeros.
 
-# tile sched row indices ([2, n_tiles] i32 scalar prefetch)
-_TQ0, _TNBLK = range(2)
+# tile sched row indices ([2, n_tiles] i32 scalar prefetch; a window's
+# walk [3, n_tiles], its first block third)
+_TQ0, _TNBLK, _TLO = range(3)
 
 PREFILL_BLOCK_Q = 128
 PREFILL_VMEM_LIMIT = 32 * 1024 * 1024
@@ -833,21 +857,24 @@ PREFILL_VMEM_LIMIT = 32 * 1024 * 1024
 def _fit_paged_prefill_blocks(c, nh, hd, nkv, bs, itemsize):
     """Query tile of the prefill attention (PTA002 contract): the largest
     divisor of the chunk ``c`` that is at most PREFILL_BLOCK_Q and a whole
-    number of sublane tiles (or the chunk itself), priced with its
-    double-buffered windows (q and out tiles, k/v block tiles, scale tiles)
-    and its scratch (accumulator, running max and sum for every head)
-    against the kernel's VMEM limit; fails at trace time where they could
-    not fit. Under tensor-parallel serving nh/nkv are the rank's own."""
-    tq = next((d for d in range(min(c, PREFILL_BLOCK_Q), 0, -1)
-               if c % d == 0 and d % 16 == 0), c)
+    number of sublane tiles (or the chunk itself) whose double-buffered
+    windows (q and out tiles, k/v block tiles, scale tiles) and scratch
+    (accumulator, running max and sum for every head) fit the kernel's
+    VMEM limit (72 heads take a tile of 64); fails at trace time where
+    none could. Under tensor-parallel serving nh/nkv are the rank's own."""
     kvd = nkv * hd
-    win = (2 * 2 * tq * nh * hd * 4               # q + out tiles (f32-priced)
-           + 2 * 2 * kvd * bs * itemsize          # k/v tiles
-           + 2 * 2 * nkv * bs * 4                 # scale tiles (quant path)
-           + nh * tq * (hd + 2 * 128) * 4)        # acc/m/l scratch
-    if win > PREFILL_VMEM_LIMIT:
+
+    def win(tq):
+        return (2 * 2 * tq * nh * hd * 4          # q + out tiles (f32)
+                + 2 * 2 * kvd * bs * itemsize     # k/v tiles
+                + 2 * 2 * nkv * bs * 4            # scale tiles (quant path)
+                + nh * tq * (hd + 2 * 128) * 4)   # acc/m/l scratch
+    tiles = [d for d in range(min(c, PREFILL_BLOCK_Q), 0, -1)
+             if c % d == 0 and d % 16 == 0] or [c]
+    tq = next((d for d in tiles if win(d) <= PREFILL_VMEM_LIMIT), None)
+    if tq is None:
         raise ValueError(
-            f"paged prefill kernel windows need {win} B VMEM "
+            f"paged prefill kernel windows need {win(tiles[-1])} B VMEM "
             f"(> {PREFILL_VMEM_LIMIT} B): shrink prefill_chunk, block_size "
             f"or heads")
     return tq
@@ -877,15 +904,20 @@ def paged_prefill_schedule(table_row, start, n_live, n_tiles, block_q,
 
 
 def _paged_prefill_kernel(lp_ref, blk_ref, tl_ref, q_ref, k_ref, v_ref,
-                          *rest, block_size, nkv, sm_scale, quant):
+                          *rest, block_size, nkv, sm_scale, quant,
+                          window=None):
     """Online-softmax chain of one (query tile, table slot) step over all
     kv heads. int8 pools differ only in dequantising the head's tile (to the
-    queries' dtype, as the dense path's gather did)."""
+    queries' dtype, as the dense path's gather did). With a ``window`` the
+    tile's walk starts at the sequence's block ``tl_ref[_TLO, i]`` (the
+    first its queries' windows reach) and keys ``window`` or more positions
+    behind a query are masked."""
     ks_ref, vs_ref = rest[:-4] if quant else (None, None)
     o_ref, m_s, l_s, acc_s = rest[-4:]
     i, j = pl.program_id(0), pl.program_id(1)
     q0 = tl_ref[_TQ0, i]
     nblk = tl_ref[_TNBLK, i]
+    blk0 = j if window is None else tl_ref[_TLO, i] + j
     tq = q_ref.shape[0]
     hd = k_ref.shape[2] // nkv
     rep = q_ref.shape[1] // (nkv * hd)
@@ -909,8 +941,11 @@ def _paged_prefill_kernel(lp_ref, blk_ref, tl_ref, q_ref, k_ref, v_ref,
             # row r of a head group is query r % tq of the tile
             qpos = q0 + jnp.concatenate(
                 [lax.broadcasted_iota(jnp.int32, (tq, bs), 0)] * rep, axis=0)
-            t = j * bs + lax.broadcasted_iota(jnp.int32, (rep * tq, bs), 1)
+            t = blk0 * bs + lax.broadcasted_iota(jnp.int32, (rep * tq, bs),
+                                                 1)
             keep = t <= qpos
+            if window is not None:
+                keep = jnp.logical_and(keep, t > qpos - window)
         for g in range(nkv):
             qg = jnp.concatenate(
                 [q_ref[:, (g * rep + h) * hd:(g * rep + h + 1) * hd]
@@ -935,7 +970,9 @@ def _paged_prefill_kernel(lp_ref, blk_ref, tl_ref, q_ref, k_ref, v_ref,
 
     live = j < nblk
     # every column of the block is at or before the tile's first query
-    whole = (j + 1) * bs - 1 <= q0
+    whole = (blk0 + 1) * bs - 1 <= q0
+    if window is not None:      # ... and inside its last query's window
+        whole = jnp.logical_and(whole, blk0 * bs > q0 + tq - 1 - window)
 
     @pl.when(jnp.logical_and(live, whole))
     def _below():
@@ -969,16 +1006,29 @@ def paged_prefill_attention(q, k_pool, v_pool, table_row, start, n_live,
     the values' dtype before PV, and the work is that of the live blocks:
     ceil((start + n_live) / bs) at most, fewer for the earlier tiles."""
     c, nh, hd = q.shape
-    L, NP, kvd, bs = k_pool.shape
-    max_nb = table_row.shape[0]
+    kvd, bs = k_pool.shape[2:]
     nkv = kvd // hd          # from the shapes: under TP the rank's own
+    tq = _fit_paged_prefill_blocks(c, nh, hd, nkv, bs,
+                                   jnp.dtype(k_pool.dtype).itemsize)
+    blk, tiles = paged_prefill_schedule(table_row, start, n_live, c // tq,
+                                        tq, bs)
+    return _prefill_call(q, k_pool, v_pool, blk, tiles, layer, tq,
+                         kv_scales, None)
+
+
+def _prefill_call(q, k_pool, v_pool, blk, tiles, layer, tq, kv_scales,
+                  window):
+    """The chunk kernel's call: a grid of (query tile, step of its walk),
+    ``blk`` [n_tiles, steps] the pool block each step presents, ``tiles``
+    the tiles' bounds (``paged_prefill_schedule``'s, or a window's with its
+    first block third). One cost site for both kernels."""
+    c, nh, hd = q.shape
+    kvd, bs = k_pool.shape[2:]
+    n_tiles, n_walk = blk.shape
+    nkv = kvd // hd
     quant = kv_scales is not None
     it = jnp.dtype(k_pool.dtype).itemsize
-    tq = _fit_paged_prefill_blocks(c, nh, hd, nkv, bs, it)
-    n_tiles = c // tq
     rows = (nh // nkv) * tq
-    blk, tiles = paged_prefill_schedule(table_row, start, n_live, n_tiles,
-                                        tq, bs)
     lp = jnp.asarray([layer], jnp.int32)
 
     def kv_map(i, j, lp_ref, blk_ref, tl_ref):
@@ -992,14 +1042,14 @@ def paged_prefill_attention(q, k_pool, v_pool, table_row, start, n_live,
         pool_specs += [pl.BlockSpec((1, 1, nkv, bs), kv_map)] * 2
     kernel = functools.partial(
         _paged_prefill_kernel, block_size=bs, nkv=nkv,
-        sm_scale=_LOG2E / (hd ** 0.5), quant=quant)
-    steps = n_tiles * max_nb
+        sm_scale=_LOG2E / (hd ** 0.5), quant=quant, window=window)
+    steps = n_tiles * n_walk
     with _mosaic_ctx():
         out = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
-                grid=(n_tiles, max_nb),
+                grid=(n_tiles, n_walk),
                 in_specs=[pl.BlockSpec((tq, nh * hd), q_map)] + pool_specs,
                 out_specs=pl.BlockSpec((tq, nh * hd), q_map),
                 scratch_shapes=[
@@ -1026,6 +1076,171 @@ def paged_prefill_attention(q, k_pool, v_pool, table_row, start, n_live,
         )(lp, blk, tiles, q.reshape(c, nh * hd), k_pool, v_pool,
           *(kv_scales or ()))
     return out.reshape(c, nh, hd)
+
+
+# -- sliding-window attention over a ring per sequence -----------------------
+#
+# A window layer's query at position i sees the keys i - window < j <= i, so
+# what a sequence keeps of such a layer never outgrows window + chunk - 1
+# positions: a RING of ``ring`` blocks a sequence, indexed by its slot
+# (``inference/engine.py`` ``engine.state``), where the layer's logical
+# block b lies in ring block b mod ring. The kernels read the rings of all
+# slots as one pool [L, S * ring, KVD, bs] (``ring_pool``), slot s's logical
+# block b at s * ring + b % ring, slot 0 the null slot. They are the full
+# layers' kernels with two changes: the walk starts at the window's first
+# block, not at block 0, and the keys below the window's lower edge are
+# masked. A ring block is written before it is read, and a block the window
+# has left is never read, so a ring needs no clearing when its slot changes
+# hands.
+
+def ring_blocks(window: int, chunk: int, block_size: int) -> int:
+    """Blocks a ring needs so that a prefill chunk of ``chunk`` queries
+    finds all it attends at once: the window behind its first query and
+    the chunk itself, ``window - 1 + chunk`` positions, one block more for
+    a start inside a block. A decode row needs fewer."""
+    return -(-(window - 1 + chunk) // block_size) + 1
+
+
+def ring_pool(rings):
+    """Rings [L, S, R, KVD, bs] (a slot's on axis 1) as the kernels' pool
+    [L, S * R, KVD, bs]: a bitcast."""
+    L, S, R = rings.shape[:3]
+    return rings.reshape((L, S * R) + rings.shape[3:])
+
+
+def ring_row(slot, ring: int, n: int):
+    """The first ``n`` logical blocks of the sequence in ``slot`` as a block
+    table over ``ring_pool``: [n] i32, entry b = slot * ring + b % ring
+    (``models/llama.py`` ``_chunk_window`` writes a chunk through it)."""
+    return (jnp.asarray(slot, jnp.int32) * ring
+            + jnp.arange(n, dtype=jnp.int32) % ring)
+
+
+def window_update_walk(slots, positions, ring: int, block_size: int,
+                       window: int):
+    """What the windowed update kernel walks for one decode batch, made once
+    before the layer loop (as ``paged_update_walk``): each row's blocks from
+    the one holding position max(0, p - window + 1) to the one its new
+    token p lands in, at most (window - 1) // bs + 2 of them, in ring
+    order. The schedule's positions are relative to the row's first block,
+    which is all the kernel's masks need. slots, positions [B] i32; padding
+    rows at slot 0, position 0. Returns (schedule, its live total)."""
+    b = slots.shape[0]
+    bs = block_size
+    n_walk = (window - 1) // bs + 2
+    lo = jnp.maximum(positions - window + 1, 0) // bs
+    tables = (slots[:, None] * ring
+              + (lo[:, None] + jnp.arange(n_walk, dtype=jnp.int32)) % ring)
+    sched = paged_schedule(positions - lo * bs + 1, tables.astype(jnp.int32),
+                           b * n_walk, bs)
+    return sched, jnp.sum(sched[_LIVE], dtype=jnp.int32)
+
+
+def window_attend_update(q_bd, new_k, new_v, k_pool, v_pool, walk, layer,
+                         window: int):
+    """``paged_attend_update`` for a window layer: writes each row's new
+    k/v column into its slot's ring IN PLACE and attends over the keys
+    ``p - window < j <= p`` of its new token's position p. q_bd [B, NH,
+    KVD] pre-scaled, new_k/new_v [B, KVD], pools ``ring_pool``'s, ``walk``
+    the batch's ``window_update_walk``. Returns (attn [B, NH, KVD] f32,
+    k_pool, v_pool)."""
+    return _update_call(q_bd, new_k, new_v, k_pool, v_pool, walk, layer,
+                        window)
+
+
+def window_prefill_schedule(slot, ring: int, start, n_live, n_tiles: int,
+                            block_q: int, block_size: int, window: int):
+    """(blk [n_tiles, steps], tiles [3, n_tiles]) i32 for one chunk of a
+    window layer: tile i (queries start + i * block_q onward) walks the
+    logical blocks from the one holding its first query's lowest key,
+    max(0, q0 - window + 1), to the one holding its last live query: at
+    most (block_q + window - 2) // bs + 2 steps. blk holds each step's ring
+    block (past the tile's last, its last again: no data moves); tiles
+    holds (q0, blocks walked, the first logical block)."""
+    bs, tq = jnp.int32(block_size), jnp.int32(block_q)
+    start = jnp.asarray(start, jnp.int32)
+    n_live = jnp.asarray(n_live, jnp.int32)
+    i = jnp.arange(n_tiles, dtype=jnp.int32)
+    q0 = start + i * tq
+    last = jnp.minimum(q0 + tq, start + n_live) - 1
+    lo = jnp.maximum(q0 - window + 1, 0) // bs
+    nblk = jnp.where(i * tq < n_live, last // bs - lo + 1, jnp.int32(0))
+    steps = (block_q + window - 2) // block_size + 2
+    j = jnp.arange(steps, dtype=jnp.int32)
+    logical = lo[:, None] + jnp.maximum(
+        jnp.minimum(j[None, :], nblk[:, None] - 1), 0)
+    blk = jnp.asarray(slot, jnp.int32) * ring + logical % ring
+    return blk.astype(jnp.int32), jnp.stack([q0, nblk, lo])
+
+
+def window_prefill_attention(q, k_pool, v_pool, slot, ring: int, start,
+                             n_live, layer, window: int):
+    """A prefill chunk's attention in a window layer: the queries at
+    positions start .. start + C of the sequence in ``slot`` over the keys
+    ``i - window < j <= i``, read from the slot's ring of ``ring`` blocks
+    in ``ring_pool``'s pools, which ALREADY hold the chunk's own columns
+    (``ring_blocks`` makes room for them beside the window behind the
+    chunk). q [C, NH, HD] unscaled. Returns [C, NH, HD] in q's dtype, as
+    ``paged_prefill_attention``."""
+    c, nh, hd = q.shape
+    kvd, bs = k_pool.shape[2:]
+    tq = _fit_paged_prefill_blocks(c, nh, hd, kvd // hd, bs,
+                                   jnp.dtype(k_pool.dtype).itemsize)
+    blk, tiles = window_prefill_schedule(slot, ring, start, n_live, c // tq,
+                                         tq, bs, window)
+    return _prefill_call(q, k_pool, v_pool, blk, tiles, layer, tq, None,
+                         window)
+
+
+def _ring_keys(pool, layer, slot, ring, positions):
+    """The columns of ``ring_pool``'s ``pool`` at ``positions`` [..., T] of
+    the slot each row of ``slot`` [...] holds: [..., T, KVD] f32."""
+    bs = pool.shape[-1]
+    pos = jnp.maximum(positions, 0)
+    cols = pool[layer][slot[..., None] * ring + (pos // bs) % ring, :,
+                       pos % bs]
+    return cols.astype(jnp.float32)
+
+
+def window_attention_xla(q, new_k, new_v, k_pool, v_pool, slots, ring,
+                         positions, layer, window: int, scale):
+    """Plain-XLA oracle of ``window_attend_update``: q [B, NH, KVD]
+    UNSCALED (block-diagonal), new columns [B, KVD] at ``positions`` [B] of
+    ``slots`` [B]; the pools after the write, and the e-base softmax over
+    each row's ``window`` positions up to its own, gathered position by
+    position. Returns (attn [B, NH, KVD] f32, k_pool, v_pool)."""
+    bs = k_pool.shape[-1]
+    at = (layer, slots * ring + (positions // bs) % ring, slice(None),
+          positions % bs)
+    k_pool = k_pool.at[at].set(new_k.astype(k_pool.dtype))
+    v_pool = v_pool.at[at].set(new_v.astype(v_pool.dtype))
+    t = positions[:, None] - window + 1 + jnp.arange(window)[None, :]
+    kc = _ring_keys(k_pool, layer, slots, ring, t)
+    vc = _ring_keys(v_pool, layer, slots, ring, t)
+    s = jnp.einsum("bhc,btc->bht", q.astype(jnp.float32), kc) * scale
+    s = jnp.where((t >= 0)[:, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bht,btc->bhc", p, vc), k_pool, v_pool
+
+
+def window_prefill_attention_xla(q, k_pool, v_pool, slot, ring, start, layer,
+                                 window: int):
+    """Plain-XLA oracle of ``window_prefill_attention``: q [C, NH, HD]
+    unscaled at positions start .. start + C, grouped queries (head h reads
+    KV head h // (NH / NKV)); every query's ``window`` keys gathered from
+    the ring, e-base softmax in f32. Returns [C, NH, HD] f32."""
+    c, nh, hd = q.shape
+    nkv = k_pool.shape[2] // hd
+    t = (start + jnp.arange(c))[:, None] - window + 1 \
+        + jnp.arange(window)[None, :]                          # [C, W]
+    sl = jnp.full((c,), slot, jnp.int32)
+    kc = _ring_keys(k_pool, layer, sl, ring, t).reshape(c, window, nkv, hd)
+    vc = _ring_keys(v_pool, layer, sl, ring, t).reshape(c, window, nkv, hd)
+    qg = q.astype(jnp.float32).reshape(c, nkv, nh // nkv, hd)
+    s = jnp.einsum("cgrd,ctgd->cgrt", qg, kc) / hd ** 0.5
+    s = jnp.where((t >= 0)[:, None, None, :], s, -jnp.inf)
+    o = jnp.einsum("cgrt,ctgd->cgrd", jax.nn.softmax(s, axis=-1), vc)
+    return o.reshape(c, nh, hd)
 
 
 # -- speculative verification (PR 18) -----------------------------------------

@@ -604,3 +604,70 @@ def test_falcon_h1_steps_compile_and_fit_one_chip(one_chip, capsys):
     assert seen == {"decode, batch 1": 5, "decode, batch 64": 5,
                     "prefill chunk 512": 5,
                     "prefill chunk 512 carrying batch 64": 7}
+
+
+# -- Laguna's window rings and its steps at published widths ------------------
+
+LAGUNA_RINGS = (3, 65 * 9, 1024, BS)     # 3 window layers, 65 slots x 9
+
+
+@pytest.mark.parametrize("batch", [1, 64])
+def test_window_update_kernel_compiles(sds, batch):
+    """72 heads over 8 (a group of 9) against the rings of 65 slots, a walk
+    of at most five blocks a row, both rings aliased through the call."""
+    out = compile_for_chip(
+        jax.jit(lambda q, k, v, kp, vp, slots, pos: pa.window_attend_update(
+            q, k, v, kp, vp, pa.window_update_walk(slots, pos, 9, BS, 512), 1,
+            512), donate_argnums=(3, 4)),
+        sds((batch, 72, 1024), bf16), sds((batch, 1024), bf16),
+        sds((batch, 1024), bf16), sds(LAGUNA_RINGS, bf16),
+        sds(LAGUNA_RINGS, bf16), sds((batch,), i32), sds((batch,), i32))
+    assert "tpu_custom_call" in out.as_text()
+    assert out.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_window_chunk_kernel_compiles(sds):
+    """A chunk of 512 queries at 72 heads: the query tile shrinks to 64 to
+    fit the kernel's 32 MiB of VMEM (at 128 it needs 34.1 MB), six ring
+    blocks a tile."""
+    assert pa._fit_paged_prefill_blocks(512, 72, 128, 8, BS, 2) == 64
+    assert pa._fit_paged_prefill_blocks(512, 48, 128, 8, BS, 2) == 128
+    out = compile_for_chip(
+        lambda q, kp, vp, slot, start, n: pa.window_prefill_attention(
+            q, kp, vp, slot, 9, start, n, 2, 512),
+        sds((512, 72, 128), bf16), sds(LAGUNA_RINGS, bf16),
+        sds(LAGUNA_RINGS, bf16), sds((), i32), sds((), i32), sds((), i32))
+    assert "tpu_custom_call" in out.as_text()
+
+
+def test_laguna_steps_compile_and_fit_one_chip(one_chip, capsys):
+    """The cell's own programs (``chipbench/families/laguna.py``
+    ``aot_programs``, with the token board, as the engine launches them):
+    decode at the smallest and largest bucket, the 512-token chunk and the
+    chunk that carries 48 rows; 6.0 GB of weights, the full layers' pool
+    (7680 blocks, 7.5 GiB) and the window layers' rings (49 slots x 9
+    blocks, 0.65 GiB) on one chip, both in place. Nine kernels a program
+    (a run's scan holds one of each: two runs of full attention, one of
+    window attention, two of three grouped matmuls), twelve where the
+    rows' attention rides beside the chunk's; prints
+    ``memory_analysis()``."""
+    from chipbench import spec
+    cell = spec.load_cell("laguna.serve.repopatch")
+    e = cell.traffic["engine"]
+    kv = e["num_blocks"] * e["block_size"] * 2 * 2 * 1024 * 2
+    rings = (e["max_batch"] + 1) * 3 * 9 * e["block_size"] * 2 * 1024 * 2
+    seen = {}
+    for name, compile_ in cell.family.aot_programs(
+            cell.model, cell.traffic, one_chip, False):
+        out = compile_()
+        ma = out.memory_analysis()
+        with capsys.disabled():
+            print(f"\n{name}: {ma}")
+        assert 13.7 < gib(ma.argument_size_in_bytes) < 13.8, name
+        assert kv + rings <= ma.alias_size_in_bytes \
+            < kv + rings + (4 << 20), name
+        assert gib(ma.temp_size_in_bytes) < 0.5, name
+        seen[name] = out.as_text().count("tpu_custom_call")
+    assert seen == {"decode, batch 1": 9, "decode, batch 48": 9,
+                    "prefill chunk 512": 9,
+                    "prefill chunk 512 carrying batch 48": 12}

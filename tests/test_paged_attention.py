@@ -151,8 +151,10 @@ def _block_diagonal(q, nkv):
 
 
 # the query rows a sequence brings: 4 dense rows over the whole width, and
-# 20 heads over 4 KV heads, a group of 5 (Falcon-H1's), block-diagonal
-@pytest.mark.parametrize("heads", [None, (20, 4)], ids=["4", "20over4"])
+# 20 heads over 4 KV heads, a group of 5 (Falcon-H1's), block-diagonal; 24
+# and 36 over 4, groups of 6 and 9 (Laguna's full and window layers)
+@pytest.mark.parametrize("heads", [None, (20, 4), (24, 4), (36, 4)],
+                         ids=["4", "20over4", "24over4", "36over4"])
 @pytest.mark.parametrize("positions", [
     [127, 128, 300, 0], [0, 0, 0, 0], [383, 5, 255, 256]],
     ids=["ragged", "all_padding", "at_block_edges"])

@@ -93,9 +93,11 @@ LIVES = {"one": 1, "partial": 19, "full": C}
 
 @pytest.mark.parametrize("kind", ["bfloat16", "int8"])
 # (KV heads, query heads a KV head): Mistral's and Yi's groups of 4 and 8,
-# and 20 heads over 4 KV heads, a group of 5 (Falcon-H1's: no power of two)
-@pytest.mark.parametrize("nkv,rep", [(NKV, 4), (NKV, 8), (4, 5)],
-                         ids=["4", "8", "20over4"])
+# 20 heads over 4 KV heads, a group of 5 (Falcon-H1's: no power of two), and
+# Laguna's groups of 6 and 9 (48 and 72 heads over 8)
+@pytest.mark.parametrize("nkv,rep", [(NKV, 4), (NKV, 8), (4, 5), (NKV, 6),
+                                     (NKV, 9)],
+                         ids=["4", "8", "20over4", "6", "9"])
 @pytest.mark.parametrize("n_live", list(LIVES.values()), ids=list(LIVES))
 @pytest.mark.parametrize("start", list(STARTS.values()), ids=list(STARTS))
 def test_matches_the_dense_formula_it_replaced(start, n_live, nkv, rep, kind,
